@@ -8,7 +8,7 @@ Machine-enforces the engine's cross-cutting contracts:
 - **ESTPU-PAIR**  breaker-task-span pairing on all paths
 - **ESTPU-DET**   injectable clocks + seeded rng + ordered iteration
 - **ESTPU-SHAPE** bucketed shapes at jit launch surfaces
-- **ESTPU-ERR**   typed-error taxonomy at raise sites
+- **ESTPU-ERR**   typed-error hierarchy at raise sites
 
 Run ``python -m elasticsearch_tpu.lint`` (exit 0 clean, 1 violations,
 2 stale baseline / parse errors), or call :func:`run_lint`. Tier-1 CI

@@ -6,7 +6,7 @@ PAPER.md `buildSrc/`): it walks the package's own AST (stdlib ``ast``,
 no dependencies) and machine-enforces the cross-cutting contracts the
 first ten PRs established by hand — trace-safety (ESTPU-JIT),
 resource pairing (ESTPU-PAIR), determinism (ESTPU-DET), recompile
-hazards (ESTPU-SHAPE), and the typed-error taxonomy (ESTPU-ERR).
+hazards (ESTPU-SHAPE), and the typed-error hierarchy (ESTPU-ERR).
 
 Suppression surfaces, in precedence order:
 

@@ -1,6 +1,6 @@
 """Cross-module facts the rules share: which functions are traced
 (jit/shard_map), which ops/ kernels exist and under what names, the
-KERNEL_ATTRIBUTION key set, and the typed-error taxonomy.
+KERNEL_ATTRIBUTION key set, and the typed-error hierarchy.
 
 Everything here is STATIC — derived from the AST, never from imports —
 so the linter runs offline with no jax (and flags code that would not
@@ -88,7 +88,7 @@ class ProjectIndex:
         # points + the ops/ host wrappers that call one directly)
         self.launch_surfaces: Set[str] = set()
         # exception classes reachable from ElasticsearchTpuException
-        self.taxonomy: Set[str] = set()
+        self.hierarchy: Set[str] = set()
 
     # -- construction -----------------------------------------------------
 
@@ -174,7 +174,7 @@ class ProjectIndex:
                         self.attribution_keys.add(k.value)
                 self.attribution_source = mod.rel
 
-    def build_taxonomy(self, modules: List[LintModule],
+    def build_hierarchy(self, modules: List[LintModule],
                        extra_bases: Dict[str, List[str]]) -> None:
         """Transitive by-name subclass closure of
         ElasticsearchTpuException across every scanned module (plus the
@@ -195,7 +195,7 @@ class ProjectIndex:
                 if cls not in known and any(b in known for b in bs):
                     known.add(cls)
                     changed = True
-        self.taxonomy = known
+        self.hierarchy = known
 
 
 def _assign_name(node: ast.Assign) -> Optional[str]:
@@ -236,7 +236,7 @@ def build_index(modules: List[LintModule]) -> ProjectIndex:
         idx.scan_module(mod)
 
     # fixture corpora fall back to the REAL package's attribution table
-    # and error taxonomy when they don't ship their own
+    # and error hierarchy when they don't ship their own
     if idx.attribution_source is None \
             and "search/profile.py" not in rels:
         real = _real_package_module("search/profile.py")
@@ -252,5 +252,5 @@ def build_index(modules: List[LintModule]) -> ProjectIndex:
                     extra_bases.setdefault(node.name, []).extend(
                         b.id for b in node.bases
                         if isinstance(b, ast.Name))
-    idx.build_taxonomy(modules, extra_bases)
+    idx.build_hierarchy(modules, extra_bases)
     return idx

@@ -1,4 +1,4 @@
-"""ESTPU-ERR — typed-error taxonomy.
+"""ESTPU-ERR — typed-error hierarchy.
 
 ``failure_type_of`` / the PR-1/PR-4 retryability matrix classify by
 exception type. A ``raise ValueError`` in ``cluster/`` or ``rest/``
@@ -19,7 +19,7 @@ from elasticsearch_tpu.lint.core import LintModule, Violation
 from elasticsearch_tpu.lint.registry import ProjectIndex
 
 RULES = {
-    "ESTPU-ERR01": "raise outside the common/errors.py taxonomy in "
+    "ESTPU-ERR01": "raise outside the common/errors.py hierarchy in "
                    "cluster//rest/",
 }
 
@@ -51,7 +51,7 @@ def _raised_class(exc: ast.expr) -> Optional[str]:
 def run(modules: List[LintModule],
         index: ProjectIndex) -> Tuple[List[Violation], int]:
     vs: List[Violation] = []
-    taxonomy = index.taxonomy
+    hierarchy = index.hierarchy
     for mod in modules:
         if not mod.rel.startswith(SCOPED_DIRS):
             continue
@@ -60,7 +60,7 @@ def run(modules: List[LintModule],
                 continue
             cls = _raised_class(node.exc)
             if cls is None or cls in _CONTROL_FLOW_OK \
-                    or cls in taxonomy:
+                    or cls in hierarchy:
                 continue
             vs.append(Violation(
                 "ESTPU-ERR01", mod.rel, node.lineno, node.col_offset,

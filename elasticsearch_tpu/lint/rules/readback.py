@@ -47,7 +47,8 @@ _NP_READBACK_CALLS = {"asarray", "array"}
 # the sync IS the point.
 READBACK_ALLOWLIST: List[Tuple[str, Optional[str], str, str]] = [
     ("search/fastpath.py", "probe_regime", "ESTPU-RB01",
-     "one-shot attached-vs-tunnel probe at boot; result discarded"),
+     "one-shot attached-vs-slow-launch probe at boot; result "
+     "discarded"),
     ("search/fastpath.py", None, "ESTPU-RB02",
      "warmup compiles sync on purpose (block_until_ready measures "
      "readiness, results discarded); the serving loop reads back "

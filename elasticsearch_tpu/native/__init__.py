@@ -1,6 +1,7 @@
 """Native host runtime: ctypes bindings for the C++ components.
 
-Builds lazily with g++ on first import (cached .so); everything degrades
+Builds lazily with g++ on first use (a .so keyed by a hash of its
+sources, see ``build_keyed``); everything degrades
 gracefully to the pure-Python implementations when the toolchain or the
 library is unavailable, so the framework never hard-depends on the native
 layer (ref: the reference treats its native pieces — JNA, ml-cpp — as
@@ -10,6 +11,7 @@ optional accelerators/sidecars too).
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,26 +20,38 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 _HERE = os.path.dirname(__file__)
-_SRC = os.path.join(_HERE, "src", "estpu_native.cpp")
-_SO = os.path.join(_HERE, "libestpu_native.so")
+_SRC_DIR = os.path.join(_HERE, "src")
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_failed = False
 
 
-def _build() -> Optional[str]:
+def build_keyed(name: str, sources: List[str], flags: List[str]
+                ) -> Optional[str]:
+    """Path of ``lib<name>-<digest>.so`` built from ``sources`` (files
+    under native/src; the first is compiled, the rest are headers it
+    includes). The digest covers every source byte, so a library built
+    from other sources (an untracked .so copied in with the checkout)
+    is never loaded: a changed source means a new name and a rebuild.
+    None when a source is missing or g++ fails."""
+    paths = [os.path.join(_SRC_DIR, s) for s in sources]
     try:
-        if os.path.exists(_SO) and (
-                not os.path.exists(_SRC)
-                or os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-            return _SO
-        if not os.path.exists(_SRC):
-            return None
-        subprocess.run(
-            ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", _SO],
-            check=True, capture_output=True, timeout=120)
-        return _SO
+        h = hashlib.sha256()
+        for p in paths:
+            with open(p, "rb") as fh:
+                h.update(fh.read())
+        so = os.path.join(_HERE, f"lib{name}-{h.hexdigest()[:16]}.so")
+        if os.path.exists(so):
+            return so
+        # build beside the target and rename: concurrent builders (test
+        # workers) never load a half-written library
+        tmp = f"{so}.{os.getpid()}.tmp"
+        subprocess.run(["g++", *flags, "-shared", "-fPIC", "-std=c++17",
+                        paths[0], "-o", tmp],
+                       check=True, capture_output=True, timeout=180)
+        os.replace(tmp, so)
+        return so
     except (OSError, subprocess.SubprocessError):
         return None
 
@@ -49,43 +63,19 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        so = _build()
+        so = build_keyed("estpu_native",
+                         ["estpu_native.cpp", "estpu_tokenize.h"], ["-O3"])
         if so is None:
             _build_failed = True
             return None
         lib = ctypes.CDLL(so)
-        try:
-            lib.tokenize_ascii.restype = ctypes.c_int
-            lib.tokenize_ascii.argtypes = [
-                ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
-                ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_char_p]
-            lib.murmur3_hash_utf16le.restype = ctypes.c_int32
-            lib.murmur3_hash_utf16le.argtypes = [ctypes.c_char_p,
-                                                 ctypes.c_int]
-        except AttributeError:
-            # stale cached .so missing a symbol (mtime-preserving copy):
-            # rebuild once from source, else degrade to pure Python
-            try:
-                os.remove(_SO)
-            except OSError:
-                pass
-            so = _build()
-            if so is None:
-                _build_failed = True
-                return None
-            lib = ctypes.CDLL(so)
-            try:
-                lib.tokenize_ascii.restype = ctypes.c_int
-                lib.tokenize_ascii.argtypes = [
-                    ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
-                    ctypes.POINTER(ctypes.c_int), ctypes.c_int,
-                    ctypes.c_char_p]
-                lib.murmur3_hash_utf16le.restype = ctypes.c_int32
-                lib.murmur3_hash_utf16le.argtypes = [ctypes.c_char_p,
-                                                     ctypes.c_int]
-            except AttributeError:
-                _build_failed = True
-                return None
+        lib.tokenize_ascii.restype = ctypes.c_int
+        lib.tokenize_ascii.argtypes = [
+            ctypes.c_char_p, ctypes.c_int, ctypes.c_int,
+            ctypes.POINTER(ctypes.c_int), ctypes.c_int, ctypes.c_char_p]
+        lib.murmur3_hash_utf16le.restype = ctypes.c_int32
+        lib.murmur3_hash_utf16le.argtypes = [ctypes.c_char_p,
+                                             ctypes.c_int]
         lib.varint_delta_encode.restype = ctypes.c_int
         lib.varint_delta_encode.argtypes = [
             ctypes.POINTER(ctypes.c_int32), ctypes.c_int,
@@ -122,10 +112,7 @@ def try_mlockall() -> Optional[int]:
     lib = get_lib()
     if lib is None:
         return None
-    try:
-        lib.es_mlockall.restype = ctypes.c_int
-    except AttributeError:
-        return None          # stale cached .so without the symbol
+    lib.es_mlockall.restype = ctypes.c_int
     return int(lib.es_mlockall())
 
 
@@ -139,10 +126,7 @@ def install_system_call_filter() -> Optional[int]:
     lib = get_lib()
     if lib is None:
         return None
-    try:
-        lib.es_install_syscall_filter.restype = ctypes.c_int
-    except AttributeError:
-        return None          # stale cached .so without the symbol
+    lib.es_install_syscall_filter.restype = ctypes.c_int
     return int(lib.es_install_syscall_filter())
 
 

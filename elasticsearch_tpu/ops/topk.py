@@ -7,9 +7,9 @@ approximate variant via lax.approx_max_k (recall-targeted, MIPS-style
 partial reduction) for latency-critical paths; and a pairwise merge used
 both host-side across segments and inside collectives across shards.
 
-Tie-breaking matches Lucene: equal scores order by ascending docid.
-lax.top_k already returns the smallest index among equals, so per-segment
-results agree with the reference; the merge re-sorts by (-score, docid).
+Tie-breaking: Lucene orders equal scores by ascending docid. lax.top_k
+does so on the CPU backend but not on TPU, where the serving kernels
+use ops/plan._stable_topk instead; the merge re-sorts by (-score, docid).
 """
 
 from __future__ import annotations

@@ -54,7 +54,7 @@ from elasticsearch_tpu.ops import plan as plan_ops
 from elasticsearch_tpu.ops.device import block_bucket, readback
 from elasticsearch_tpu.search.plan import LogicalPlan, compile_plan
 from elasticsearch_tpu.telemetry.engine import tracked_jit
-from elasticsearch_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 DOC_PAD = 1024
 

@@ -34,7 +34,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from elasticsearch_tpu.index.segment import BLOCK_SIZE
-from elasticsearch_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 
 # int32 global-id ceiling: with x64 off, `ids + shard * nd` computes in

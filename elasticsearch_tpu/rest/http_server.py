@@ -28,8 +28,8 @@ class _Handler(BaseHTTPRequestHandler):
         content_type = (self.headers.get("Content-Type") or "").lower()
         body = None
         if raw:
-            if "x-ndjson" in content_type or url.path.rstrip("/").endswith(
-                    ("_bulk", "_msearch")):
+            if "x-ndjson" in content_type or url.path.rstrip("/").rsplit(
+                    "/", 1)[-1] in ("_bulk", "_msearch"):
                 body = raw.decode("utf-8")
             elif "cbor" in content_type:
                 # binary XContent (ref: CborXContent — the JDBC/ODBC

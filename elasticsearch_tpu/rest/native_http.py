@@ -19,15 +19,9 @@ from __future__ import annotations
 
 import ctypes
 import json
-import os
-import subprocess
 import threading
 from typing import Optional
 from urllib.parse import parse_qsl, urlsplit
-
-_HERE = os.path.dirname(os.path.dirname(__file__))
-_SRC = os.path.join(_HERE, "native", "src", "estpu_http.cpp")
-_SO = os.path.join(_HERE, "native", "libestpu_http.so")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -44,20 +38,14 @@ def get_lib() -> Optional[ctypes.CDLL]:
     with _lib_lock:
         if _lib is not None or _build_failed:
             return _lib
-        try:
-            hdr = os.path.join(_HERE, "native", "src", "estpu_tokenize.h")
-            if not os.path.exists(_SO) or any(
-                    os.path.exists(src) and
-                    os.path.getmtime(_SO) < os.path.getmtime(src)
-                    for src in (_SRC, hdr)):
-                subprocess.run(
-                    ["g++", "-O2", "-shared", "-fPIC", "-pthread",
-                     "-std=c++17", _SRC, "-o", _SO],
-                    check=True, capture_output=True, timeout=180)
-        except (OSError, subprocess.SubprocessError):
+        from elasticsearch_tpu.native import build_keyed
+        so = build_keyed("estpu_http",
+                         ["estpu_http.cpp", "estpu_tokenize.h"],
+                         ["-O2", "-pthread"])
+        if so is None:
             _build_failed = True
             return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         c = ctypes
         H = c.c_int64
         lib.es_http_start.restype = c.c_int
@@ -226,7 +214,8 @@ class NativeHttpFront:
         body = None
         if raw_body:
             if ("x-ndjson" in content_type
-                    or url.path.rstrip("/").endswith(("_bulk", "_msearch"))):
+                    or url.path.rstrip("/").rsplit("/", 1)[-1]
+                    in ("_bulk", "_msearch")):
                 body = raw_body.decode("utf-8")
             elif "cbor" in content_type:
                 # binary XContent, same negotiation as the stdlib front

@@ -157,7 +157,7 @@ def activate_tenant(value: Optional[str]):
 def current_workload_class() -> Optional[str]:
     """The request-class label the current work runs under —
     ``interactive`` / ``bulk`` / ``aggs`` / ``scroll`` / ``async``
-    (telemetry/workload.py's taxonomy, derived at the request boundary
+    (telemetry/workload.py's class set, derived at the request boundary
     or carried in via the ``X-Workload-Class`` header). The dimension
     WorkloadAccounting charges latency, cohort slots, and indexing
     bytes against. None for unclassified work (accounting folds it

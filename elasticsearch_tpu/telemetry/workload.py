@@ -1,7 +1,7 @@
 """Workload-class accounting: the request-class half of ROADMAP item 5b.
 
 Tenants answer *who* a request belongs to; workload classes answer
-*what kind* of work it is. The taxonomy is the Rally-style macro
+*what kind* of work it is. The class set is the Rally-style macro
 harness's request mix — ``interactive`` search (bm25/bool/knn),
 ``bulk`` indexing, ``aggs``, ``scroll``/PIT drains, and ``async``
 search — and the label rides the same ambient context rail as
@@ -14,7 +14,7 @@ The table is the TenantAccounting pattern verbatim: one bounded
 ``WorkloadAccounting`` per node over the shared ``MetricsRegistry``
 (``workload=<class>`` labels, so the history ring windows per-class
 rates for free), a reserved ``_default`` bucket for unclassified work,
-an ``_other`` fold past the LRU cap (the taxonomy is small, but a
+an ``_other`` fold past the LRU cap (the class set is small, but a
 caller-supplied header can mint arbitrary classes — cardinality stays
 a hard invariant, not a hope), fold-on-evict with registry AND
 history-ring pruning, and deterministic bucket-bound p50/p99 through
@@ -52,7 +52,7 @@ DEFAULT_CLASS = "_default"         # unclassified requests
 OVERFLOW_CLASS = "_other"          # folded evictions past the LRU cap
 RESERVED_CLASSES = (DEFAULT_CLASS, OVERFLOW_CLASS)
 
-# the macro-harness taxonomy (callers may mint others via the header;
+# the macro-harness class set (callers may mint others via the header;
 # the LRU cap bounds them)
 CLASS_INTERACTIVE = "interactive"
 CLASS_BULK = "bulk"
@@ -99,7 +99,7 @@ _FOLD_COUNTERS = (
 def classify_search_request(body: Optional[Dict[str, Any]],
                             scroll: Optional[Any] = None) -> str:
     """Derive the workload class of a search request from its shape —
-    the boundary-side half of the taxonomy (an explicit
+    the boundary-side half of the class set (an explicit
     ``X-Workload-Class`` header always wins upstream of this):
     cursor-plane work (scroll open, PIT search) is ``scroll``,
     aggregation-bearing bodies are ``aggs``, everything else —

@@ -1,0 +1,78 @@
+"""Main-path kernels compile for a TPU v5e that is described, not
+attached (on-chip-measurement guide §2): what the chip's compiler would
+refuse fails here at no chip time. Shapes are the serving widths of the
+2M-doc corpus (``chip_smoke.py``): ~600k postings blocks, 2,000,896
+padded docs, cohorts of 32, top-1000, 768-d bf16 vectors.
+
+Nothing runs, so these say nothing about results or speed.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+TB, B, ND, Q, K = 600_001, 128, 2_000_896, 32, 1000
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent
+    # cache but cannot be read back without one: keep the cache off
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def test_v2m_merge_kernel_compiles_to_a_tpu_custom_call(one_chip,
+                                                        monkeypatch):
+    from elasticsearch_tpu.ops import fastpath, merge
+    # _interpret() reads jax.devices(), which is the CPU here
+    monkeypatch.setattr(merge, "_interpret", lambda: False)
+    S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    nb = 1024
+    compiled = fastpath.bm25_topk_total_merge_batch.__wrapped_jit__.lower(
+        S((TB, B), jnp.int32), S((TB, B), jnp.float32),
+        S((Q, nb), jnp.int32), S((Q, nb), jnp.float32),
+        S((ND,), jnp.float32), S((fastpath.F_SLOTS, ND), jnp.bool_),
+        S((Q,), jnp.int32), S((), jnp.float32),
+        n_slots=16, k1=1.2, b=0.75, k=K).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_knn_nominate_compiles_over_a_bf16_slab(one_chip):
+    from elasticsearch_tpu.ops import vector
+    S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    compiled = vector.knn_nominate_batch.__wrapped_jit__.lower(
+        S((Q, 768), jnp.float32), S((ND, 768), jnp.bfloat16),
+        S((ND,), jnp.float32), S((ND,), jnp.bool_), S((ND,), jnp.bool_),
+        similarity="cosine", cut=128).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_plan_segsum_compiles_under_vmap(one_chip):
+    """The plan kernel's segmented sum, batched as the PlanBatcher runs
+    it: a segmented associative_scan here took ~80 s per call site on
+    the TPU compiler; the compensated cumsum takes seconds."""
+    from elasticsearch_tpu.ops import plan
+    S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    p = 4096 * B
+    jax.jit(jax.vmap(plan._segsum)).lower(
+        S((8, p), jnp.float32), S((8, p), jnp.bool_)).compile()

@@ -16,6 +16,7 @@ import pytest
 
 from elasticsearch_tpu.ops.bm25 import _SENTINEL, bm25_sorted_topk
 from elasticsearch_tpu.ops.fastpath import F_SLOTS, bm25_topk_total_batch
+from elasticsearch_tpu.ops.plan import unpack_ids
 
 ND = 4096
 TB = 120
@@ -40,8 +41,8 @@ def run_batch(bd, bt, sels, wss, lens, masks, mask_ids, k=K):
     out = []
     for q in range(len(sels)):
         vals = packed[q, :k]
-        ids = packed[q, k:2 * k].astype(np.int32)
-        total = int(packed[q, 2 * k:].astype(np.int32)[0])
+        ids = unpack_ids(packed[q, k:2 * k])
+        total = int(unpack_ids(packed[q, 2 * k:])[0])
         out.append((vals, ids, total))
     return out
 

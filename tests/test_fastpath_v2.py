@@ -7,6 +7,7 @@ import pytest
 import jax.numpy as jnp
 
 from elasticsearch_tpu.ops import fastpath as fp
+from elasticsearch_tpu.ops.plan import unpack_ids
 
 BLOCK = 128
 
@@ -115,8 +116,7 @@ def run_both(seg, queries, n_docs=2000, k=50,
 
 
 def unpack1(row, k):
-    return (row[:k], row[k:2 * k].astype(np.int32),
-            int(row[2 * k:].astype(np.int32)[0]))
+    return row[:k], unpack_ids(row[k:2 * k]), int(row[2 * k])
 
 
 def _norm_hits(vals, ids, k):
@@ -142,7 +142,7 @@ def test_v2_matches_v1(seed):
     for qi in range(len(queries)):
         v1, d1, t1 = unpack1(out1[qi], k)
         v2 = out2[qi][:k]
-        d2 = out2[qi][k:2 * k].astype(np.int32)
+        d2 = unpack_ids(out2[qi][k:2 * k])
         t2 = int(out2[qi][2 * k])
         ok = int(np.asarray(out2[qi][2 * k + 1],
                             np.float32).astype(np.int32))
@@ -163,7 +163,7 @@ def test_v2_duplicate_term_instances():
     for qi in range(2):
         v1, d1, _ = unpack1(out1[qi], k)
         v2 = out2[qi][:k]
-        d2 = out2[qi][k:2 * k].astype(np.int32)
+        d2 = unpack_ids(out2[qi][k:2 * k])
         nv1, nd1 = _norm_hits(v1, d1, k)
         nv2, nd2 = _norm_hits(v2, d2, k)
         np.testing.assert_array_equal(nd1, nd2)

@@ -198,6 +198,22 @@ def test_fallback_routes_work(served):
     assert ei.value.code == 404
 
 
+def test_index_named_like_an_ndjson_route_gets_a_json_body(served):
+    """Only the `_bulk` / `_msearch` ROUTES take ndjson: an index whose
+    name ends in `_bulk` still parses its JSON body."""
+    node, port = served
+    req(port, "PUT", "/passages_bulk",
+        {"mappings": {"properties": {"title": {"type": "text"}}}})
+    body = ('{"index": {"_index": "passages_bulk", "_id": "1"}}\n'
+            '{"title": "fox"}\n')
+    assert not req(port, "POST", "/passages_bulk/_bulk", body,
+                   ndjson=True)["errors"]
+    req(port, "POST", "/passages_bulk/_refresh")
+    hits = req(port, "POST", "/passages_bulk/_search",
+               {"query": {"match": {"title": "fox"}}})["hits"]["hits"]
+    assert [h["_id"] for h in hits] == ["1"]
+
+
 def test_keepalive_and_concurrency(served):
     node, port = served
     bodies = [{"query": {"match": {"title": w}}, "size": 10,

@@ -358,7 +358,8 @@ def test_coordinator_slowlog_fires_from_index_settings(
     assert {"index", "took_ms", "level", "source"} <= set(entry)
     assert set(entry) <= {"index", "took_ms", "level", "source",
                           "trace.id", "slowest_stage", "x_opaque_id",
-                          "cohort_fill_pct", "readbacks", "regime"}
+                          "cohort_fill_pct", "readbacks", "regime",
+                          "search.class"}
     assert entry["trace.id"].startswith(coord.local_node.name)
     assert entry["index"] == "logs" and entry["level"] == "warn"
     assert "fox" in entry["source"]
