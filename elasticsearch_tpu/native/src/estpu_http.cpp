@@ -247,8 +247,17 @@ struct FastIndex {
     int32_t default_k = 10;
 };
 
+// Arrival stamps: CLOCK_MONOTONIC nanoseconds (steady_clock on Linux),
+// the clock of Python's time.monotonic_ns, so the serving threads time
+// a request's queue wait from the moment the front queued it.
+static int64_t mono_ns() {
+    return std::chrono::duration_cast<std::chrono::nanoseconds>(
+        std::chrono::steady_clock::now().time_since_epoch()).count();
+}
+
 struct FastReq {
     uint64_t token;
+    int64_t stamp;        // mono_ns() when queued on fast_q
     int32_t gen;
     int32_t k;
     int32_t from;
@@ -266,6 +275,7 @@ struct Pending {
     std::string headers;  // raw header block (after the request line)
     std::string body;
     bool fast = false;
+    int64_t stamp = 0;    // mono_ns() when its token was pushed on fb_q
 };
 
 struct Conn {
@@ -658,6 +668,7 @@ void dispatch_request(Server* s, Conn* c) {
     if (fast_route(s, method, path, &index) &&
         parse_fast(s, p.body, &fr)) {
         fr.token = token;
+        fr.stamp = mono_ns();
         p.fast = true;
         {
             std::lock_guard<std::mutex> lk(s->pending_mu);
@@ -671,6 +682,7 @@ void dispatch_request(Server* s, Conn* c) {
         s->fast_cv.notify_one();
         return;
     }
+    p.stamp = mono_ns();
     {
         std::lock_guard<std::mutex> lk(s->pending_mu);
         s->pending.emplace(token, std::move(p));
@@ -969,11 +981,13 @@ void es_fast_unregister(int64_t h) {
     s->fast_cfg = nullptr;
 }
 
-// Drain up to max_n parsed fast requests. Returns count (0 on timeout).
+// Drain up to max_n parsed fast requests, each with the mono_ns() stamp
+// of its queueing. Returns count (0 on timeout).
 int es_fast_poll(int64_t h, uint64_t* tokens, int32_t* gens,
                  int32_t* ks, int32_t* ntermss,
                  int32_t* term_ids, int32_t* nfilterss,
-                 int32_t* filter_tids, int max_n, int timeout_ms) {
+                 int32_t* filter_tids, int64_t* stamps, int max_n,
+                 int timeout_ms) {
     Server* s = (Server*)h;
     if (!s) return 0;
     std::unique_lock<std::mutex> lk(s->fast_mu);
@@ -984,6 +998,7 @@ int es_fast_poll(int64_t h, uint64_t* tokens, int32_t* gens,
     while (n < max_n && !s->fast_q.empty()) {
         FastReq& fr = s->fast_q.front();
         tokens[n] = fr.token;
+        stamps[n] = fr.stamp;
         gens[n] = fr.gen;
         ks[n] = fr.k;
         ntermss[n] = fr.n_terms;
@@ -1092,7 +1107,9 @@ int es_fast_bounce(int64_t h, uint64_t token) {
     if (!s) return -1;
     {
         std::lock_guard<std::mutex> lk(s->pending_mu);
-        if (s->pending.find(token) == s->pending.end()) return -1;
+        auto it = s->pending.find(token);
+        if (it == s->pending.end()) return -1;
+        it->second.stamp = mono_ns();
     }
     {
         std::lock_guard<std::mutex> lk(s->fb_mu);
@@ -1104,11 +1121,11 @@ int es_fast_bounce(int64_t h, uint64_t token) {
 
 // Pull the next fallback request. Buffers must hold method(16) and the
 // returned pointers stay valid until es_respond(token). Returns 1, or 0
-// on timeout.
+// on timeout. *stamp is when the request was queued (mono_ns).
 int es_fallback_next(int64_t h, uint64_t* token, char* method, const char** path,
                      int64_t* path_len, const char** headers,
                      int64_t* headers_len, const char** body,
-                     int64_t* body_len, int timeout_ms) {
+                     int64_t* body_len, int64_t* stamp, int timeout_ms) {
     Server* s = (Server*)h;
     if (!s) return 0;
     uint64_t tok;
@@ -1133,6 +1150,7 @@ int es_fallback_next(int64_t h, uint64_t* token, char* method, const char** path
     *headers_len = (int64_t)it->second.headers.size();
     *body = it->second.body.data();
     *body_len = (int64_t)it->second.body.size();
+    *stamp = it->second.stamp;
     return 1;
 }
 

@@ -124,6 +124,8 @@ class Node:
             self.telemetry.workload
         self.search_service.knn_batcher.workloads = \
             self.telemetry.workload
+        # queue-wait and re-rank histograms (`knn.*`, `GET /_nodes/stats`)
+        self.search_service.knn_batcher.metrics = self.telemetry.metrics
         # mesh serving backend: dispatch/fallback counters mirror into
         # the node registry (search.mesh.dispatch{axis} /
         # search.mesh.fallback{reason}) next to its own stats surface
@@ -408,7 +410,8 @@ class Node:
                     "http.native.fast_max_k", 1000))
                 from elasticsearch_tpu.rest.native_http import (
                     NativeHttpFront)
-                front = NativeHttpFront.try_acquire(self.rest_controller)
+                front = NativeHttpFront.try_acquire(
+                    self.rest_controller, metrics=self.telemetry.metrics)
                 if front is not None:
                     front.start(http_port)
                     from elasticsearch_tpu.search.fastpath import (

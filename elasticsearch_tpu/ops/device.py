@@ -27,6 +27,7 @@ import numpy as np
 
 from elasticsearch_tpu.index.segment import BLOCK_SIZE, Segment
 from elasticsearch_tpu.ops.vector import prepare_vectors
+from elasticsearch_tpu.telemetry.tracing import host_span
 
 DOC_PAD = 1024
 MIN_BLOCK_BUCKET = 8
@@ -43,6 +44,9 @@ FILTER_MASK_CACHE_MAX = 64
 # so `sum(hbm_bytes_by_class().values()) == hbm_bytes()` by construction.
 HBM_SLAB_CLASSES = ("postings", "norms", "live_mask", "vectors",
                     "doc_values", "ordinals", "filter_masks")
+
+# readback site -> its span name ("readback:<site>"), built once per site
+_READBACK_SPANS: Dict[str, str] = {}
 
 
 def readback(site: str, *arrays, profile: bool = True):
@@ -69,7 +73,12 @@ def readback(site: str, *arrays, profile: bool = True):
     prof_on = profile and _prof.recording()
     t_prof = _prof.now_ns() if prof_on else 0
     t_fr = fr.clock() if fr is not None else 0.0
-    out = tuple(np.asarray(a) for a in arrays)
+    name = _READBACK_SPANS.get(site)
+    if name is None:
+        name = _READBACK_SPANS.setdefault(site, "readback:" + site)
+    # the host's wait for the device, as a span on the profiler's clock
+    with host_span(name):
+        out = tuple(np.asarray(a) for a in arrays)
     if fr is not None:
         fr.record_readback(
             site, sum(int(a.nbytes) for a in out),

@@ -29,6 +29,7 @@ from elasticsearch_tpu.common.errors import (
 from elasticsearch_tpu.search.rank_eval import rank_eval
 from elasticsearch_tpu.telemetry import context as _telectx
 from elasticsearch_tpu.telemetry import flightrecorder as _flightrec
+from elasticsearch_tpu.telemetry.tracing import host_span
 from elasticsearch_tpu.transport.tasks import CancellableTask, TaskId
 
 Response = Tuple[int, Dict[str, Any]]
@@ -792,8 +793,11 @@ def get_kernels(node, params, body):
         # per-bucket dispatch counts + cohort histogram of the native
         # serving front — which warmed shapes actually earn their keep
         out["serving"] = fp.serving_stats()
-    mesh = getattr(getattr(node, "search_service", None),
-                   "mesh_executor", None)
+    svc = getattr(node, "search_service", None)
+    if svc is not None:
+        # kNN cohort launches and the queries they carried (fill)
+        out["knn"] = svc.knn_batcher.stats()
+    mesh = getattr(svc, "mesh_executor", None)
     if mesh is not None:
         # multi-chip serving surface: dispatch counts per mesh axis,
         # typed fallback reasons, and per-DEVICE HBM residency of every
@@ -888,18 +892,19 @@ from contextlib import contextmanager
 def _rest_trace(node, name, **tags):
     """Root a trace at the REST boundary: the span is ambient for the
     handler body (service-level spans parent to it) and its trace id is
-    echoed back in the `trace.id` response header."""
-    tele = getattr(node, "telemetry", None)
-    if tele is None:
-        yield None
-        return
-    from elasticsearch_tpu.telemetry import context as _telectx
-    span = tele.tracer.start_span(name, tags=tags)
-    try:
-        with _telectx.activate_span(span):
-            yield span
-    finally:
-        span.finish()
+    echoed back in the `trace.id` response header. The same name is a
+    host span in a profiler trace."""
+    with host_span(name):
+        tele = getattr(node, "telemetry", None)
+        if tele is None:
+            yield None
+            return
+        span = tele.tracer.start_span(name, tags=tags)
+        try:
+            with _telectx.activate_span(span):
+                yield span
+        finally:
+            span.finish()
 
 
 def indices_stats(node, params, body):

@@ -20,8 +20,11 @@ from __future__ import annotations
 import ctypes
 import json
 import threading
+import time
 from typing import Optional
 from urllib.parse import parse_qsl, urlsplit
+
+from elasticsearch_tpu.telemetry.tracing import host_span
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -64,7 +67,7 @@ def get_lib() -> Optional[ctypes.CDLL]:
             H, c.POINTER(c.c_uint64), c.POINTER(c.c_int32),
             c.POINTER(c.c_int32), c.POINTER(c.c_int32),
             c.POINTER(c.c_int32), c.POINTER(c.c_int32),
-            c.POINTER(c.c_int32), c.c_int, c.c_int]
+            c.POINTER(c.c_int32), c.POINTER(c.c_int64), c.c_int, c.c_int]
         lib.es_fast_pending.restype = c.c_int
         lib.es_fast_pending.argtypes = [H]
         lib.es_fast_respond.restype = c.c_int
@@ -78,7 +81,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
             H, c.POINTER(c.c_uint64), c.c_char_p,
             c.POINTER(c.c_char_p), c.POINTER(c.c_int64),
             c.POINTER(c.c_char_p), c.POINTER(c.c_int64),
-            c.POINTER(c.c_char_p), c.POINTER(c.c_int64), c.c_int]
+            c.POINTER(c.c_char_p), c.POINTER(c.c_int64),
+            c.POINTER(c.c_int64), c.c_int]
         lib.es_respond.restype = c.c_int
         lib.es_respond.argtypes = [H, c.c_uint64, c.c_int, c.c_char_p,
                                    c.c_char_p, c.c_int64, c.c_int,
@@ -105,8 +109,11 @@ class NativeHttpFront:
     nodes per process run their own front) + the Python fallback
     workers."""
 
-    def __init__(self, controller, n_fallback_threads: int = 2):
+    def __init__(self, controller, n_fallback_threads: int = 2,
+                 metrics=None):
         self.controller = controller
+        # the node's MetricsRegistry (`http.fallback.queue_wait`)
+        self.metrics = metrics
         self.lib = get_lib()
         self.h = None           # C++ Server* handle
         self.port = None
@@ -116,8 +123,9 @@ class NativeHttpFront:
         self.fastpath = None   # attached by Node.start
 
     @classmethod
-    def try_acquire(cls, controller):
-        return cls(controller) if get_lib() is not None else None
+    def try_acquire(cls, controller, metrics=None):
+        return (cls(controller, metrics=metrics) if get_lib() is not None
+                else None)
 
     def start(self, port: int) -> int:
         h = ctypes.c_int64()
@@ -176,13 +184,19 @@ class NativeHttpFront:
         hdr_len = c.c_int64()
         body_p = c.c_char_p()
         body_len = c.c_int64()
+        stamp = c.c_int64()
+        waits = (self.metrics.histogram("http.fallback.queue_wait")
+                 if self.metrics is not None else None)
         while self._running:
             got = self.lib.es_fallback_next(
                 self.h, c.byref(token), method, c.byref(path_p),
                 c.byref(path_len), c.byref(hdr_p), c.byref(hdr_len),
-                c.byref(body_p), c.byref(body_len), 200)
+                c.byref(body_p), c.byref(body_len), c.byref(stamp), 200)
             if not got:
                 continue
+            if waits is not None:
+                # from the front queueing the request to this worker
+                waits.observe((time.monotonic_ns() - stamp.value) / 1e6)
             try:
                 self._serve_one(token.value,
                                 method.value.decode("latin-1"),
@@ -202,81 +216,90 @@ class NativeHttpFront:
 
     def _serve_one(self, token: int, method: str, raw_path: bytes,
                    raw_headers: bytes, raw_body: bytes):
-        url = urlsplit(raw_path.decode("utf-8", "replace"))
-        params = dict(parse_qsl(url.query))
-        headers = {}
-        for line in raw_headers.decode("latin-1").split("\r\n"):
-            name, sep, val = line.partition(":")
-            if sep:
-                headers[name.strip()] = val.strip()
-        lower = {k.lower(): v for k, v in headers.items()}
-        content_type = lower.get("content-type", "").lower()
-        body = None
-        if raw_body:
-            if ("x-ndjson" in content_type
-                    or url.path.rstrip("/").rsplit("/", 1)[-1]
-                    in ("_bulk", "_msearch")):
-                body = raw_body.decode("utf-8")
-            elif "cbor" in content_type:
-                # binary XContent, same negotiation as the stdlib front
-                # (rest/http_server.py — JDBC/ODBC binary_format)
-                from elasticsearch_tpu.common import cbor
-                try:
-                    body = cbor.loads(raw_body)
-                except (ValueError, TypeError) as e:
-                    self._send(token, 400, {"error": {
-                        "type": "parsing_exception",
-                        "reason": f"Failed to parse request body: {e}"},
-                        "status": 400}, method)
-                    return
+        with host_span("http.serve"):
+            with host_span("http.parse"):
+                url, params, headers, lower, body, err = _parse(
+                    raw_path, raw_headers, raw_body)
+            if err is not None:
+                self._send(token, 400, {"error": {
+                    "type": "parsing_exception",
+                    "reason": f"Failed to parse request body: {err}"},
+                    "status": 400}, method)
+                return
+            if "trace.id" in lower:
+                # an externally-propagated trace context (another node's
+                # coordinator, a client-side tracer) joins this request's
+                # spans to the caller's trace — the REST-boundary root
+                # span parents to it via the ambient context, so
+                # cross-process profile ↔ trace navigation works through
+                # the native front too (fast-path requests never reach
+                # Python and stay untraced by design)
+                from elasticsearch_tpu.telemetry import context as _telectx
+                cm = _telectx.incoming({"trace.id": lower["trace.id"],
+                                        "span.id": lower.get("span.id")})
             else:
-                try:
-                    body = json.loads(raw_body)
-                except json.JSONDecodeError as e:
-                    self._send(token, 400, {"error": {
-                        "type": "parsing_exception",
-                        "reason": f"Failed to parse request body: {e}"},
-                        "status": 400}, method)
-                    return
-        if "trace.id" in lower:
-            # an externally-propagated trace context (another node's
-            # coordinator, a client-side tracer) joins this request's
-            # spans to the caller's trace — the REST-boundary root span
-            # parents to it via the ambient context, so cross-process
-            # profile ↔ trace navigation works through the native front
-            # too (fast-path requests never reach Python and stay
-            # untraced by design)
-            from elasticsearch_tpu.telemetry import context as _telectx
-            cm = _telectx.incoming({"trace.id": lower["trace.id"],
-                                    "span.id": lower.get("span.id")})
-        else:
-            from contextlib import nullcontext
-            cm = nullcontext()
-        with cm:
-            status, payload = self.controller.dispatch(
-                method, url.path, params, body, headers=headers)
-        self._send(token, status, payload, method,
-                   cbor_ok="cbor" in lower.get("accept", "").lower())
+                from contextlib import nullcontext
+                cm = nullcontext()
+            with cm:
+                status, payload = self.controller.dispatch(
+                    method, url.path, params, body, headers=headers)
+            self._send(token, status, payload, method,
+                       cbor_ok="cbor" in lower.get("accept", "").lower())
 
     def _send(self, token: int, status: int, payload, method: str,
               cbor_ok: bool = False):
         # mirrors rest/http_server.py _Handler._send
-        extra = b""
-        if isinstance(payload, dict) and "_headers" in payload:
-            payload = dict(payload)
-            extra = "".join(f"{k}: {v}\r\n" for k, v in
-                            payload.pop("_headers").items()).encode()
-        if isinstance(payload, dict) and "_cat" in payload \
-                and len(payload) == 1:
-            data = (payload["_cat"] + "\n").encode()
-            ctype = b"text/plain; charset=UTF-8"
-        elif cbor_ok:
+        with host_span("http.encode"):
+            extra = b""
+            if isinstance(payload, dict) and "_headers" in payload:
+                payload = dict(payload)
+                extra = "".join(f"{k}: {v}\r\n" for k, v in
+                                payload.pop("_headers").items()).encode()
+            if isinstance(payload, dict) and "_cat" in payload \
+                    and len(payload) == 1:
+                data = (payload["_cat"] + "\n").encode()
+                ctype = b"text/plain; charset=UTF-8"
+            elif cbor_ok:
+                from elasticsearch_tpu.common import cbor
+                data = cbor.dumps(payload)
+                ctype = b"application/cbor"
+            else:
+                data = json.dumps(payload).encode()
+                ctype = b"application/json; charset=UTF-8"
+            self.lib.es_respond(self.h, token, status, ctype, data,
+                                len(data), 1 if method == "HEAD" else 0,
+                                extra)
+
+
+def _parse(raw_path: bytes, raw_headers: bytes, raw_body: bytes):
+    """(url, query params, headers, lower-cased headers, decoded body,
+    None), or the body's decode error in the last place."""
+    url = urlsplit(raw_path.decode("utf-8", "replace"))
+    params = dict(parse_qsl(url.query))
+    headers = {}
+    for line in raw_headers.decode("latin-1").split("\r\n"):
+        name, sep, val = line.partition(":")
+        if sep:
+            headers[name.strip()] = val.strip()
+    lower = {k.lower(): v for k, v in headers.items()}
+    content_type = lower.get("content-type", "").lower()
+    body = None
+    if raw_body:
+        if ("x-ndjson" in content_type
+                or url.path.rstrip("/").rsplit("/", 1)[-1]
+                in ("_bulk", "_msearch")):
+            body = raw_body.decode("utf-8")
+        elif "cbor" in content_type:
+            # binary XContent, same negotiation as the stdlib front
+            # (rest/http_server.py — JDBC/ODBC binary_format)
             from elasticsearch_tpu.common import cbor
-            data = cbor.dumps(payload)
-            ctype = b"application/cbor"
+            try:
+                body = cbor.loads(raw_body)
+            except (ValueError, TypeError) as e:
+                return url, params, headers, lower, None, e
         else:
-            data = json.dumps(payload).encode()
-            ctype = b"application/json; charset=UTF-8"
-        self.lib.es_respond(self.h, token, status, ctype, data,
-                            len(data), 1 if method == "HEAD" else 0,
-                            extra)
+            try:
+                body = json.loads(raw_body)
+            except json.JSONDecodeError as e:
+                return url, params, headers, lower, None, e
+    return url, params, headers, lower, body, None

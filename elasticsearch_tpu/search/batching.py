@@ -32,6 +32,7 @@ from elasticsearch_tpu.ops import device as device_ops
 from elasticsearch_tpu.ops import plan as plan_ops
 from elasticsearch_tpu.search.plan import BoundPlan, execute_bound
 from elasticsearch_tpu.telemetry import flightrecorder as _flight
+from elasticsearch_tpu.telemetry.tracing import host_span
 
 _Q_BUCKETS = (1, 2, 4, 8, 16, 32)
 
@@ -92,7 +93,111 @@ class _Entry:
         self.meta: Optional[Dict[str, object]] = None
 
 
-class PlanBatcher:
+class _Cohorts:
+    """The leader/follower protocol both batchers share. ``SPANS``
+    names its three waits as host spans: the leader's adaptive flush
+    window, its wait for a launch slot, and a follower's wait for the
+    leader's launch."""
+
+    SPANS = ("", "", "")
+
+    def __init__(self, max_batch: int, max_concurrent: int,
+                 adaptive_flush_s: float):
+        self.max_batch = max_batch
+        self._lock = threading.Lock()
+        # Launches used to serialize behind one lock; under a device
+        # with a high per-sync latency floor that caps throughput at
+        # batch/floor. Syncs OVERLAP
+        # across threads, so a bounded semaphore lets several batched
+        # launches ride the floor concurrently — the wait in acquire()
+        # is still the batching window that grows cohorts under load.
+        self._launch_slots = threading.BoundedSemaphore(max_concurrent)
+        self._pending: Dict[tuple, list] = {}
+        self.launches = 0          # stats: total device launches
+        self.batched_queries = 0   # stats: queries served via batches
+        # EMA of launch+readback latency: when the device round-trip is
+        # slow (tens of ms or more), leaders WAIT a fraction
+        # of it before popping the queue so cohorts grow — the classic
+        # continuous-batching window, sized from measurement instead of
+        # a fixed knob. Fast devices (real local TPU: sub-ms) never wait.
+        self._lat_ema = 0.0
+        # adaptive flush: even on a fast device, a leader that sees
+        # OTHER work pending holds the pop for up to this long so the
+        # cohort fills — trading ≤~2 ms of p50 for materially larger
+        # batches under load (0 disables)
+        self.adaptive_flush_s = float(adaptive_flush_s)
+        # optional TenantAccounting sink: one cohort slot per entry
+        self.tenants = None
+        # optional WorkloadAccounting sink: same per-slot charge keyed
+        # by request class
+        self.workloads = None
+
+    def _ride(self, sig: tuple, entry, run) -> None:
+        """Queue ``entry`` under ``sig`` and return once a launch has
+        set its result (raising its error). The first request to
+        arrive for a shape leads: it lets the cohort grow, waits for a
+        launch slot, takes the whole queue and hands it to ``run`` in
+        chunks of ``max_batch``. Non-leader entries are always popped
+        by a leader that appended before them, so nothing is
+        orphaned."""
+        with self._lock:
+            q = self._pending.setdefault(sig, [])
+            q.append(entry)
+            leader = len(q) == 1
+        if not leader:
+            with host_span(self.SPANS[2]):
+                entry.event.wait()
+        else:
+            self._lead(sig, entry, run)
+        if entry.error is not None:
+            raise entry.error
+
+    def _lead(self, sig: tuple, entry, run) -> None:
+        # The wait engages only when concurrency is actually present
+        # (other work pending) and is STAGED: stop as soon as this
+        # signature's cohort fills a max batch — when a launch costs
+        # seconds, padding a 3-query cohort to the batch shape wastes
+        # ~10x device time, so waiting a fraction of the measured
+        # round-trip to fill the cohort is strictly cheaper. On a FAST
+        # device the adaptive flush window still holds the pop for
+        # ≤~2 ms when other work is pending, so loaded traffic
+        # coalesces instead of racing out in cohorts of one.
+        window = (min(0.75 * self._lat_ema, 1.5)
+                  if self._lat_ema > 0.03 else self.adaptive_flush_s)
+        if window > 0.0:
+            with host_span(self.SPANS[0]):
+                deadline = time.monotonic() + window
+                step = min(0.02, max(window / 4.0, 0.0005))
+                while time.monotonic() < deadline:
+                    with self._lock:
+                        mine = len(self._pending.get(sig, ()))
+                        busy = (mine > 1 or len(self._pending) > 1
+                                or any(len(q) > 1
+                                       for q in self._pending.values()))
+                    if mine >= self.max_batch or not busy:
+                        break
+                    time.sleep(step)
+        with host_span(self.SPANS[1]):
+            self._launch_slots.acquire()
+        try:
+            with self._lock:
+                batch = self._pending.pop(sig, [])
+            if not batch:
+                batch = [entry]
+            try:
+                for start in range(0, len(batch), self.max_batch):
+                    run(batch[start:start + self.max_batch])
+            except BaseException as exc:
+                for e in batch:
+                    if not e.event.is_set():
+                        e.error = exc
+                        e.event.set()
+                raise
+        finally:
+            self._launch_slots.release()
+
+
+class PlanBatcher(_Cohorts):
     """Shape-bucketed batcher for fused plan launches.
 
     Eligible: everything but search_after cursors and ad-hoc dense
@@ -106,32 +211,13 @@ class PlanBatcher:
     are already pending — so cohorts grow without taxing idle queries.
     """
 
+    SPANS = ("plan.flush_wait", "plan.slot_wait", "plan.follower_wait")
+
     def __init__(self, max_batch: int = 64, max_concurrent: int = 8,
                  adaptive_flush_s: float = 0.002):
-        self.max_batch = min(max_batch, _Q_BUCKETS[-1])
-        self._lock = threading.Lock()
-        # Launches used to serialize behind one lock; under a device
-        # with a high per-sync latency floor that caps throughput at
-        # batch/floor. Syncs OVERLAP
-        # across threads, so a bounded semaphore lets several batched
-        # launches ride the floor concurrently — the wait in acquire()
-        # is still the batching window that grows cohorts under load.
-        self._launch_slots = threading.BoundedSemaphore(max_concurrent)
-        self._pending: Dict[tuple, List[_Entry]] = {}
-        self.launches = 0          # stats: total device launches
-        self.batched_queries = 0   # stats: queries served via batches
+        super().__init__(min(max_batch, _Q_BUCKETS[-1]), max_concurrent,
+                         adaptive_flush_s)
         self.batch_hist: Dict[int, int] = {}   # pow2 batch-size counts
-        # EMA of launch+readback latency: when the device round-trip is
-        # slow (tens of ms or more), leaders WAIT a fraction
-        # of it before popping the queue so cohorts grow — the classic
-        # continuous-batching window, sized from measurement instead of
-        # a fixed knob. Fast devices (real local TPU: sub-ms) never wait.
-        self._lat_ema = 0.0
-        # adaptive flush: even on a fast device, a leader that sees
-        # OTHER work pending holds the pop for up to this long so the
-        # cohort fills — trading ≤~2 ms of p50 for materially larger
-        # batches under load (0 disables)
-        self.adaptive_flush_s = float(adaptive_flush_s)
         # replica-axis fan-out (opt-in; a MeshSearchBackend wired by the
         # service): cohorts split their query axis over a ("replica",)
         # device mesh — corpus replicated, per-query rows sharded — and
@@ -139,11 +225,6 @@ class PlanBatcher:
         # results stay byte-identical to the single-device launch
         self.mesh = None
         self.mesh_cohorts = 0     # stats: cohorts launched replica-sharded
-        # optional TenantAccounting sink: one cohort slot per entry
-        self.tenants = None
-        # optional WorkloadAccounting sink: same per-slot charge keyed
-        # by request class
-        self.workloads = None
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -186,60 +267,8 @@ class PlanBatcher:
                        t_fr=fr.clock() if fr is not None else 0.0,
                        tenant=_telectx.current_tenant(),
                        wclass=_telectx.current_workload_class())
-        with self._lock:
-            q = self._pending.setdefault(sig, [])
-            q.append(entry)
-            leader = len(q) == 1
-        if not leader:
-            entry.event.wait()
-            if entry.error is not None:
-                raise entry.error
-            if profiled:
-                self._record_attribution(entry)
-            return entry.result
-        # leader: let the cohort grow while the device is slow, then wait
-        # for a launch slot and take the whole queue. Non-leader entries
-        # are always popped by a leader that appended before them, so
-        # nothing is orphaned. The wait engages only when concurrency is
-        # actually present (other work pending) and is STAGED: stop as
-        # soon as this signature's cohort fills a max batch — when a
-        # launch costs seconds, padding a 3-query cohort to the batch
-        # shape wastes ~10x device time, so waiting a fraction of the
-        # measured round-trip to fill the cohort is strictly cheaper.
-        # On a FAST device the adaptive flush window still holds the pop
-        # for ≤~2 ms when other work is pending, so loaded traffic
-        # coalesces instead of racing out in cohorts of one.
-        window = (min(0.75 * self._lat_ema, 1.5)
-                  if self._lat_ema > 0.03 else self.adaptive_flush_s)
-        if window > 0.0:
-            deadline = time.monotonic() + window
-            step = min(0.02, max(window / 4.0, 0.0005))
-            while time.monotonic() < deadline:
-                with self._lock:
-                    mine = len(self._pending.get(sig, ()))
-                    busy = (mine > 1 or len(self._pending) > 1
-                            or any(len(q) > 1
-                                   for q in self._pending.values()))
-                if mine >= self.max_batch or not busy:
-                    break
-                time.sleep(step)
-        with self._launch_slots:
-            with self._lock:
-                batch = self._pending.pop(sig, [])
-            if not batch:
-                batch = [entry]
-            try:
-                for start in range(0, len(batch), self.max_batch):
-                    chunk = batch[start:start + self.max_batch]
-                    self._run(chunk, ctx, k, k1, b)
-            except BaseException as exc:
-                for e in batch:
-                    if not e.event.is_set():
-                        e.error = exc
-                        e.event.set()
-                raise
-        if entry.error is not None:
-            raise entry.error
+        self._ride(sig, entry,
+                   lambda chunk: self._run(chunk, ctx, k, k1, b))
         if profiled:
             self._record_attribution(entry)
         return entry.result
@@ -445,13 +474,16 @@ def _cut_bucket(n: int) -> int:
 
 class _KnnEntry:
     __slots__ = ("qvec", "cut", "event", "result", "error", "profiled",
-                 "t_enq", "meta", "t_fr", "tenant", "wclass")
+                 "t_enq", "meta", "t_fr", "tenant", "wclass", "t_mono")
 
     def __init__(self, qvec: np.ndarray, cut: int,
                  profiled: bool = False, t_enq: int = 0,
                  t_fr: float = 0.0, tenant: Optional[str] = None,
-                 wclass: Optional[str] = None):
+                 wclass: Optional[str] = None, t_mono: int = 0):
         self.qvec = qvec
+        # enqueue time (monotonic ns; 0: not timed) — the start of the
+        # query's `knn.queue_wait`
+        self.t_mono = t_mono
         self.cut = cut
         self.tenant = tenant
         self.wclass = wclass
@@ -464,7 +496,7 @@ class _KnnEntry:
         self.meta: Optional[Dict[str, object]] = None
 
 
-class KnnBatcher:
+class KnnBatcher(_Cohorts):
     """Continuous batching for kNN branch launches — the vector
     analogue of :class:`PlanBatcher`. Concurrent kNN queries against
     the same device slab coalesce into ONE
@@ -475,18 +507,14 @@ class KnnBatcher:
     into one float32 buffer (bitcast) so the cohort syncs exactly once.
     """
 
+    SPANS = ("knn.flush_wait", "knn.slot_wait", "knn.follower_wait")
+
     def __init__(self, max_batch: int = 64, max_concurrent: int = 8,
                  adaptive_flush_s: float = 0.002):
-        self.max_batch = max_batch
-        self._lock = threading.Lock()
-        self._launch_slots = threading.BoundedSemaphore(max_concurrent)
-        self._pending: Dict[tuple, List[_KnnEntry]] = {}
-        self.launches = 0
-        self.batched_queries = 0
-        self._lat_ema = 0.0
-        self.adaptive_flush_s = float(adaptive_flush_s)
-        self.tenants = None    # optional TenantAccounting sink
-        self.workloads = None  # optional WorkloadAccounting sink
+        super().__init__(max_batch, max_concurrent, adaptive_flush_s)
+        # optional MetricsRegistry: `knn.queue_wait` (enqueue to the
+        # cohort's launch) and `knn.rerank` (the host re-rank), in ms
+        self.metrics = None
 
     def topk(self, dv, live, qvec: np.ndarray, cut: int,
              host_vectors=None) -> Tuple[np.ndarray, np.ndarray]:
@@ -509,52 +537,19 @@ class KnnBatcher:
                           t_enq=_prof.now_ns() if profiled else 0,
                           t_fr=fr.clock() if fr is not None else 0.0,
                           tenant=_telectx.current_tenant(),
-                          wclass=_telectx.current_workload_class())
-        with self._lock:
-            q = self._pending.setdefault(sig, [])
-            q.append(entry)
-            leader = len(q) == 1
-        if not leader:
-            entry.event.wait()
-            if entry.error is not None:
-                raise entry.error
-            if profiled:
-                PlanBatcher._record_attribution(entry)
-            return self._finish(entry, dv, host_vectors)
-        window = (min(0.75 * self._lat_ema, 1.5)
-                  if self._lat_ema > 0.03 else self.adaptive_flush_s)
-        if window > 0.0:
-            deadline = time.monotonic() + window
-            step = min(0.02, max(window / 4.0, 0.0005))
-            while time.monotonic() < deadline:
-                with self._lock:
-                    mine = len(self._pending.get(sig, ()))
-                    busy = (mine > 1 or len(self._pending) > 1
-                            or any(len(qq) > 1
-                                   for qq in self._pending.values()))
-                if mine >= self.max_batch or not busy:
-                    break
-                time.sleep(step)
-        with self._launch_slots:
-            with self._lock:
-                batch = self._pending.pop(sig, [])
-            if not batch:
-                batch = [entry]
-            try:
-                for start in range(0, len(batch), self.max_batch):
-                    self._run(batch[start:start + self.max_batch], dv,
-                              live, bucket_cut)
-            except BaseException as exc:
-                for e in batch:
-                    if not e.event.is_set():
-                        e.error = exc
-                        e.event.set()
-                raise
-        if entry.error is not None:
-            raise entry.error
+                          wclass=_telectx.current_workload_class(),
+                          t_mono=time.monotonic_ns())
+        self._ride(sig, entry,
+                   lambda chunk: self._run(chunk, dv, live, bucket_cut))
         if profiled:
             PlanBatcher._record_attribution(entry)
-        return self._finish(entry, dv, host_vectors)
+        t0 = time.monotonic_ns()
+        with host_span("knn.rerank"):
+            out = self._finish(entry, dv, host_vectors)
+        if self.metrics is not None:
+            self.metrics.observe("knn.rerank",
+                                 (time.monotonic_ns() - t0) / 1e6)
+        return out
 
     # ------------------------------------------------------------------
     def _run(self, batch: List[_KnnEntry], dv, live, cut: int):
@@ -578,6 +573,12 @@ class KnnBatcher:
                 from elasticsearch_tpu.search import profile as _prof
                 t0p = _prof.now_ns()
             t0 = time.monotonic()
+            if self.metrics is not None:
+                waits = self.metrics.histogram("knn.queue_wait")
+                t_launch = time.monotonic_ns()
+                for e in chunk:
+                    if e.t_mono:
+                        waits.observe((t_launch - e.t_mono) / 1e6)
             fr = _flight.current()
             enq = [e.t_fr for e in chunk if e.t_fr]
             qw_ns = (int(max(0.0, fr.clock() - min(enq)) * 1e9)

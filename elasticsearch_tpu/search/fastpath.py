@@ -35,6 +35,8 @@ import numpy as np
 
 from elasticsearch_tpu.ops.device import readback as _readback
 from elasticsearch_tpu.ops.plan import unpack_ids as _unpack_ids
+from elasticsearch_tpu.telemetry import flightrecorder as _flight
+from elasticsearch_tpu.telemetry.tracing import host_span
 
 logger = logging.getLogger("elasticsearch_tpu.fastpath")
 
@@ -208,6 +210,15 @@ class FastPathServer:
         # search/batching.py device records)
         self.pad_rows = 0
         self.used_rows = 0
+        # the node's flight recorder, ambient on every launch stream, and
+        # its registry's histograms: per request, parse stamp to its
+        # cohort's launch; per cohort, launch to the end of the readback
+        tele = getattr(node, "telemetry", None)
+        self.flight = tele.flight if tele is not None else None
+        self._queue_wait = self._inflight = None
+        if tele is not None:
+            self._queue_wait = tele.metrics.histogram("fastpath.queue_wait")
+            self._inflight = tele.metrics.histogram("fastpath.inflight")
 
     def _count_dispatch(self, lane: str, bucket: int, n: int):
         key = f"{lane}:{bucket}"
@@ -689,6 +700,7 @@ class FastPathServer:
         tids = (c.c_int32 * (max_n * MAX_TERMS))()
         nfilt = (c.c_int32 * max_n)()
         ftids = (c.c_int32 * (max_n * MAX_FILTERS))()
+        stamps = (c.c_int64 * max_n)()
         last_reg_check = 0.0
         while self._running:
             now = time.time()
@@ -703,12 +715,13 @@ class FastPathServer:
             if h is None:
                 break
             n = self.lib.es_fast_poll(h, tokens, gens, ks, nterms, tids,
-                                      nfilt, ftids, max_n, 50)
+                                      nfilt, ftids, stamps, max_n, 50)
             if n == 0:
                 continue
             try:
-                self._route_cohort(h, n, tokens, gens, ks, nterms, tids,
-                                   nfilt, ftids)
+                with host_span("fastpath.route"):
+                    self._route_cohort(h, n, tokens, gens, ks, nterms,
+                                       tids, nfilt, ftids, stamps)
             except Exception:
                 # the drain thread must NEVER die: C++ keeps routing to
                 # the fast queue and every client would hang
@@ -721,8 +734,10 @@ class FastPathServer:
                         pass
 
     def _route_cohort(self, h, n, tokens, gens, ks, nterms, tids, nfilt,
-                      ftids):
-        t_arrive = time.time()
+                      ftids, stamps):
+        # token -> when the front queued it (monotonic ns): each
+        # request's queue wait and `took` start there
+        arrived = {tokens[i]: stamps[i] for i in range(n)}
         reqs = []
         for i in range(n):
             reqs.append((
@@ -835,48 +850,70 @@ class FastPathServer:
         # (r5 full-bench measured avg cohort 16.3/32 before this fold)
         for bucket, items in merge_up(ess_by_bucket).items():
             for chunk in self._chunk_by_slots(items):
-                stack, rows = self._resolve_mask_rows(
-                    reg, {it[3] for it in chunk})
-                self._count_dispatch("ess", bucket, len(chunk))
-                self._count_cohort(len(chunk))
-                self._sem.acquire()
-                self._pool.submit(self._launch_essential, reg, bucket,
-                                  chunk, t_arrive, stack, rows)
-
+                self._submit(self._launch_essential, "ess", reg, bucket,
+                             chunk, arrived)
         for bucket, items in merge_up(v2_by_bucket).items():
             for chunk in self._chunk_by_slots(items):
-                stack, rows = self._resolve_mask_rows(
-                    reg, {it[3] for it in chunk})
-                self._count_dispatch(self.kernel_mode, bucket,
-                                     len(chunk))
-                self._count_cohort(len(chunk))
-                self._sem.acquire()
-                self._pool.submit(self._launch_group_v2, reg, bucket,
-                                  chunk, t_arrive, stack, rows)
+                self._submit(self._launch_group_v2, self.kernel_mode, reg,
+                             bucket, chunk, arrived)
         for bucket, items in merge_up(by_bucket).items():
             for chunk in self._chunk_by_slots(items):
-                stack, rows = self._resolve_mask_rows(
-                    reg, {it[3] for it in chunk})
-                self._count_dispatch("v1", bucket, len(chunk))
-                self._count_cohort(len(chunk))
-                # backpressure: wait for a free stream — requests keep
-                # queueing in C++ meanwhile and drain in wider cohorts
-                self._sem.acquire()
-                self._pool.submit(self._launch_group, reg, bucket,
-                                  chunk, t_arrive, stack, rows)
+                self._submit(self._launch_group, "v1", reg, bucket, chunk,
+                             arrived)
         if trunc_items:
             # the truncated lane runs on the largest warm v1 shape
             # (order-agnostic kernel: the impact-chosen subset needs no
             # slot layout)
             bucket = self.nb_buckets[-1]
             for chunk in self._chunk_by_slots(trunc_items):
-                stack, rows = self._resolve_mask_rows(
-                    reg, {it[3] for it in chunk})
-                self._count_dispatch("trunc", bucket, len(chunk))
-                self._count_cohort(len(chunk))
-                self._sem.acquire()
-                self._pool.submit(self._launch_truncated, reg, bucket,
-                                  chunk, t_arrive, stack, rows)
+                self._submit(self._launch_truncated, "trunc", reg, bucket,
+                             chunk, arrived)
+
+    def _submit(self, launch, lane, reg, bucket, chunk, arrived):
+        """Hand one cohort to a launch stream: resolve its filter rows,
+        count it, and wait for a free stream — backpressure: requests
+        keep queueing in C++ meanwhile and drain in wider cohorts."""
+        stack, rows = self._resolve_mask_rows(reg, {it[3] for it in chunk})
+        self._count_dispatch(lane, bucket, len(chunk))
+        self._count_cohort(len(chunk))
+        with host_span("fastpath.stream_wait"):
+            self._sem.acquire()
+        self._pool.submit(self._stream_task, launch, reg, bucket, chunk,
+                          arrived, stack, rows)
+
+    def _stream_task(self, launch, reg, bucket, items, arrived, stack,
+                     rows):
+        """One launch on a stream, with the node's flight recorder
+        ambient; a launch that raises bounces its cohort."""
+        try:
+            with _flight.activate(self.flight):
+                launch(reg, bucket, items, arrived, stack, rows)
+        except Exception:
+            self._launch_failed(items)
+        finally:
+            self._sem.release()
+
+    def _launch_cohort(self, site, items, arrived, first, kernel, *args):
+        """Launch a cohort's kernel and read its packed result back: ONE
+        device→host sync per cohort, through the tracked funnel. On a
+        cohort's ``first`` launch (a refire's riders were counted at
+        theirs) each rider's wait from its parse stamp to this launch
+        feeds ``fastpath.queue_wait``; the launch to the end of the
+        readback feeds ``fastpath.inflight``. Returns the host array and
+        the monotonic ns at the end of the readback."""
+        t_launch = time.monotonic_ns()
+        waits = [t_launch - arrived[it[0]] for it in items]
+        if first and self._queue_wait is not None:
+            for w in waits:
+                self._queue_wait.observe(w / 1e6)
+        with _flight.annotate_launch(len(items), self.q_batch,
+                                     queue_wait_ns=max(waits)):
+            packed = kernel(*args)
+        out = _readback(site, packed)
+        t_done = time.monotonic_ns()
+        if self._inflight is not None:
+            self._inflight.observe((t_done - t_launch) / 1e6)
+        return out, t_done
 
     def _v2_bucket(self, reg, term_ids) -> Optional[int]:
         """Smallest bucket whose slot layout fits: each term INSTANCE
@@ -894,18 +931,7 @@ class FastPathServer:
                 return bucket
         return None
 
-    def _launch_group_v2(self, reg, bucket, items, t_arrive, stack,
-                         rows):
-        try:
-            self._launch_group_v2_inner(reg, bucket, items, t_arrive,
-                                        stack, rows)
-        except Exception:
-            self._launch_failed(items)
-        finally:
-            self._sem.release()
-
-    def _launch_group_v2_inner(self, reg, bucket, items, t_arrive,
-                               stack, rows):
+    def _launch_group_v2(self, reg, bucket, items, arrived, stack, rows):
         from elasticsearch_tpu.ops.fastpath import (
             MAX_T, bm25_candidates_rerank_batch,
             bm25_topk_total_merge_batch)
@@ -913,84 +939,87 @@ class FastPathServer:
         slot = bucket // self.N_SLOTS
         v2m = self.kernel_mode == "v2m"
         q = len(items)
-        sel = np.full((self.q_batch, bucket), dp.zero_block, np.int32)
-        ws = np.zeros((self.q_batch, bucket),
-                      self._weight_dtype() if v2m else np.float32)
-        ts = np.zeros((self.q_batch, MAX_T), np.int32)
-        tl = np.zeros((self.q_batch, MAX_T), np.int32)
-        ti = np.zeros((self.q_batch, MAX_T), self._weight_dtype())
-        mask_ids = np.zeros(self.q_batch, np.int32)
-        starts, nbs = reg["starts"], reg["nb"]
-        idf32, idf = reg["idf32"], reg["idf"]
-        wsrc = idf if v2m else idf32
-        no_match: list = []
-        for qi, (tok, k, term_ids, filt) in enumerate(items):
-            pos = 0
-            ninst = 0
-            for t in term_ids:
-                if t < 0:
-                    continue
-                cnt = int(nbs[t])
-                s = int(starts[t])
-                sel[qi, pos:pos + cnt] = np.arange(s, s + cnt,
-                                                   dtype=np.int32)
-                ws[qi, pos:pos + cnt] = wsrc[t]
-                ts[qi, ninst] = reg["post_start"][t]
-                tl[qi, ninst] = reg["post_len"][t]
-                ti[qi, ninst] = idf[t]
-                ninst += 1
-                pos += -(-cnt // slot) * slot
-            if filt:
-                row = rows.get(filt)
-                if row is None:          # unknown filter term ⇒ no hits
-                    no_match.append(tok)
-                    sel[qi, :] = dp.zero_block
-                    ws[qi, :] = 0.0
-                    tl[qi, :] = 0
-                    continue
-                mask_ids[qi] = row
+        with host_span("fastpath.pack"):
+            sel = np.full((self.q_batch, bucket), dp.zero_block, np.int32)
+            ws = np.zeros((self.q_batch, bucket),
+                          self._weight_dtype() if v2m else np.float32)
+            ts = np.zeros((self.q_batch, MAX_T), np.int32)
+            tl = np.zeros((self.q_batch, MAX_T), np.int32)
+            ti = np.zeros((self.q_batch, MAX_T), self._weight_dtype())
+            mask_ids = np.zeros(self.q_batch, np.int32)
+            starts, nbs = reg["starts"], reg["nb"]
+            idf32, idf = reg["idf32"], reg["idf"]
+            wsrc = idf if v2m else idf32
+            no_match: list = []
+            for qi, (tok, k, term_ids, filt) in enumerate(items):
+                pos = 0
+                ninst = 0
+                for t in term_ids:
+                    if t < 0:
+                        continue
+                    cnt = int(nbs[t])
+                    s = int(starts[t])
+                    sel[qi, pos:pos + cnt] = np.arange(s, s + cnt,
+                                                       dtype=np.int32)
+                    ws[qi, pos:pos + cnt] = wsrc[t]
+                    ts[qi, ninst] = reg["post_start"][t]
+                    tl[qi, ninst] = reg["post_len"][t]
+                    ti[qi, ninst] = idf[t]
+                    ninst += 1
+                    pos += -(-cnt // slot) * slot
+                if filt:
+                    row = rows.get(filt)
+                    if row is None:          # unknown filter term ⇒ no hits
+                        no_match.append(tok)
+                        sel[qi, :] = dp.zero_block
+                        ws[qi, :] = 0.0
+                        tl[qi, :] = 0
+                        continue
+                    mask_ids[qi] = row
         masks = stack
         k_static = self.max_k
         if v2m:
-            packed = bm25_topk_total_merge_batch(
+            kernel, args = bm25_topk_total_merge_batch, (
                 dp.block_docids, dp.block_tfs, sel, ws, dp.doc_lens,
                 masks, mask_ids, self._weight_dtype()(dp.avg_len),
                 self.N_SLOTS, reg["k1"], reg["b"], k_static)
         else:
-            packed = bm25_candidates_rerank_batch(
+            kernel, args = bm25_candidates_rerank_batch, (
                 dp.block_docids, dp.block_tfs, reg["flat_docids"],
                 reg["flat_tfs"], sel, ws, dp.doc_lens, masks, mask_ids,
                 ts, tl, ti, self._weight_dtype()(dp.avg_len),
                 self.N_SLOTS, reg["k1"], reg["b"], k_static)
-        # ONE device→host sync per cohort, through the tracked funnel
-        out = _readback("search.fastpath.v2_cohort", packed)
-        took_ms = int((time.time() - t_arrive) * 1000)
+        out, t_done = self._launch_cohort(
+            "search.fastpath.v2_cohort", items, arrived, True, kernel,
+            *args)
         self.stats["cohorts"] += 1
         self.stats["v2_queries"] = self.stats.get("v2_queries", 0) + q
         no_match_set = set(no_match)
         refire: list = []
-        for qi, (tok, k, term_ids, filt) in enumerate(items):
-            if tok in no_match_set:
-                self._respond_empty(tok, reg)
-                continue
-            tail = out[qi, 2 * k_static:]
-            total = int(tail[0])
-            if not v2m and not int(tail[1]):
-                refire.append((tok, k, term_ids, filt))
-                continue
-            vals = out[qi, :k_static]
-            ids = _unpack_ids(out[qi, k_static:2 * k_static])
-            nhit = int(min(k, np.isfinite(vals).sum()))
-            v = vals[:nhit]
-            d = ids[:nhit]
-            if v2m:
-                # v2m's device top_k tie order is arbitrary (v1
-                # contract): re-sort (score desc, docid asc) host-side
-                order = np.lexsort((d, -v))
-                v, d = v[order], d[order]
-            self._respond_hits(reg, tok, np.ascontiguousarray(v),
-                               np.ascontiguousarray(d),
-                               k, total, took_ms, term_ids, filt)
+        with host_span("fastpath.respond"):
+            for qi, (tok, k, term_ids, filt) in enumerate(items):
+                if tok in no_match_set:
+                    self._respond_empty(tok, reg)
+                    continue
+                tail = out[qi, 2 * k_static:]
+                total = int(tail[0])
+                if not v2m and not int(tail[1]):
+                    refire.append((tok, k, term_ids, filt))
+                    continue
+                vals = out[qi, :k_static]
+                ids = _unpack_ids(out[qi, k_static:2 * k_static])
+                nhit = int(min(k, np.isfinite(vals).sum()))
+                v = vals[:nhit]
+                d = ids[:nhit]
+                if v2m:
+                    # v2m's device top_k tie order is arbitrary (v1
+                    # contract): re-sort (score desc, docid asc) host-side
+                    order = np.lexsort((d, -v))
+                    v, d = v[order], d[order]
+                took_ms = (t_done - arrived[tok]) // 1_000_000
+                self._respond_hits(reg, tok, np.ascontiguousarray(v),
+                                   np.ascontiguousarray(d),
+                                   k, total, took_ms, term_ids, filt)
         self.stats["fast_queries"] += q - len(refire)
         if refire:
             # uncertified (score-tie mass wider than the candidate set)
@@ -998,8 +1027,8 @@ class FastPathServer:
             # stream permit, run inline at the v1-warm bucket
             self.stats["v2_refires"] = self.stats.get("v2_refires", 0) \
                 + len(refire)
-            self._launch_group_inner(reg, self.nb_buckets[-1], refire,
-                                     t_arrive, stack, rows)
+            self._launch_group(reg, self.nb_buckets[-1], refire, arrived,
+                               stack, rows, first=False)
 
     def _respond_empty(self, tok, reg):
         empty = np.zeros(0, np.int32)
@@ -1025,16 +1054,6 @@ class FastPathServer:
                     self.lib.es_fast_bounce(h, tok)
             except Exception:
                 pass
-
-    def _launch_group(self, reg, bucket, items, t_arrive, stack,
-                      rows):
-        try:
-            self._launch_group_inner(reg, bucket, items, t_arrive,
-                                     stack, rows)
-        except Exception:
-            self._launch_failed(items)
-        finally:
-            self._sem.release()
 
     # ------------------------------------------------- impact truncation
     # adaptive back-off: a registration whose certificate NEVER fires
@@ -1080,18 +1099,7 @@ class FastPathServer:
                 return None
         return known, per_term, miss
 
-    def _launch_truncated(self, reg, bucket, items, t_arrive, stack,
-                          rows):
-        try:
-            self._launch_truncated_inner(reg, bucket, items, t_arrive,
-                                         stack, rows)
-        except Exception:
-            self._launch_failed(items)
-        finally:
-            self._sem.release()
-
-    def _launch_truncated_inner(self, reg, bucket, items, t_arrive,
-                                stack, rows):
+    def _launch_truncated(self, reg, bucket, items, arrived, stack, rows):
         """Impact-truncated cohort on the exact v1 kernel: scores are
         exact over the SELECTED blocks, so every observed score is a
         lower bound of the true score and no doc can gain more than the
@@ -1102,37 +1110,36 @@ class FastPathServer:
         from elasticsearch_tpu.ops.fastpath import bm25_topk_total_batch
         from elasticsearch_tpu.ops.plan import impact_safe_termination
         dp = reg["dp"]
-        sel = np.full((self.q_batch, bucket), dp.zero_block, np.int32)
-        ws = np.zeros((self.q_batch, bucket), self._weight_dtype())
-        mask_ids = np.zeros(self.q_batch, np.int32)
-        idf = reg["idf"]
-        no_match: list = []
-        for qi, (tok, k, term_ids, filt, trunc) in enumerate(items):
-            known, per_term, _miss = trunc
-            pos = 0
-            for t, blocks in zip(known, per_term):
-                cnt = len(blocks)
-                sel[qi, pos:pos + cnt] = blocks
-                ws[qi, pos:pos + cnt] = idf[t]
-                pos += cnt
-            if filt:
-                row = rows.get(filt)
-                if row is None:          # unknown filter term ⇒ no hits
-                    no_match.append(tok)
-                    sel[qi, :] = dp.zero_block
-                    ws[qi, :] = 0.0
-                    continue
-                mask_ids[qi] = row
+        with host_span("fastpath.pack"):
+            sel = np.full((self.q_batch, bucket), dp.zero_block, np.int32)
+            ws = np.zeros((self.q_batch, bucket), self._weight_dtype())
+            mask_ids = np.zeros(self.q_batch, np.int32)
+            idf = reg["idf"]
+            no_match: list = []
+            for qi, (tok, k, term_ids, filt, trunc) in enumerate(items):
+                known, per_term, _miss = trunc
+                pos = 0
+                for t, blocks in zip(known, per_term):
+                    cnt = len(blocks)
+                    sel[qi, pos:pos + cnt] = blocks
+                    ws[qi, pos:pos + cnt] = idf[t]
+                    pos += cnt
+                if filt:
+                    row = rows.get(filt)
+                    if row is None:          # unknown filter term ⇒ no hits
+                        no_match.append(tok)
+                        sel[qi, :] = dp.zero_block
+                        ws[qi, :] = 0.0
+                        continue
+                    mask_ids[qi] = row
         k_static = self.max_k
         bd, bt, sel_m, ws_m, dl, mk, mi = self._v1_inputs(
             reg, sel, ws, stack, mask_ids)
-        packed = bm25_topk_total_batch(
-            bd, bt, sel_m, ws_m, dl, mk, mi,
-            self._weight_dtype()(dp.avg_len), reg["k1"],
-            reg["b"], k_static)
-        # ONE device→host sync per cohort, through the tracked funnel
-        out = _readback("search.fastpath.truncated_cohort", packed)
-        took_ms = int((time.time() - t_arrive) * 1000)
+        out, t_done = self._launch_cohort(
+            "search.fastpath.truncated_cohort", items, arrived, True,
+            bm25_topk_total_batch, bd, bt, sel_m, ws_m, dl, mk, mi,
+            self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
+            k_static)
         self.stats["cohorts"] += 1
         if self._mesh_active(reg):
             self.stats["mesh_cohorts"] = \
@@ -1142,65 +1149,67 @@ class FastPathServer:
         idx_b = reg["index"].encode()
         no_match_set = set(no_match)
         served = 0
-        for qi, (tok, k, term_ids, filt, trunc) in enumerate(items):
-            if tok in no_match_set:
-                self._respond_empty(tok, reg)
+        with host_span("fastpath.respond"):
+            for qi, (tok, k, term_ids, filt, trunc) in enumerate(items):
+                if tok in no_match_set:
+                    self._respond_empty(tok, reg)
+                    served += 1
+                    continue
+                miss = float(trunc[2])
+                vals = out[qi, :k_static]
+                ids = _unpack_ids(out[qi, k_static:2 * k_static])
+                total = int(out[qi, 2 * k_static:][0])
+                nhit = int(min(k, np.isfinite(vals).sum()))
+                certified = False
+                if nhit >= k:
+                    kth = float(vals[k - 1])
+                    if k < k_static:
+                        # the (k+1)-th observed score bounds the best
+                        # excluded candidate
+                        nxt = (float(vals[k])
+                               if np.isfinite(vals[k]) else 0.0)
+                    elif total <= k:
+                        # every matching doc is in the result: only
+                        # entirely-unseen docs (observed 0) could displace
+                        nxt = 0.0
+                    else:
+                        nxt = None   # k == kernel k: no (k+1)-th to bound by
+                    certified = (nxt is not None
+                                 and impact_safe_termination(kth, nxt, miss))
+                # per-registration certificate track record (feeds the
+                # _trunc_hopeless back-off; refresh resets with the reg)
+                reg["trunc_attempts"] = reg.get("trunc_attempts", 0) + 1
+                if certified:
+                    reg["trunc_certified"] = \
+                        reg.get("trunc_certified", 0) + 1
+                if not certified and self.impact_mode != "always":
+                    # can't prove the truncated set exact — the full Python
+                    # path serves it (the pre-impact behavior for oversize)
+                    self.stats["trunc_refused"] = \
+                        self.stats.get("trunc_refused", 0) + 1
+                    self.stats["bounced"] += 1
+                    if h is not None:
+                        self.lib.es_fast_bounce(h, tok)
+                    continue
+                v = vals[:nhit]
+                d = ids[:nhit]
+                order = np.lexsort((d, -v))
+                v = np.ascontiguousarray(v[order])
+                d = np.ascontiguousarray(d[order])
+                self.stats["trunc_served"] = \
+                    self.stats.get("trunc_served", 0) + 1
+                if certified:
+                    self.stats["trunc_certified"] = \
+                        self.stats.get("trunc_certified", 0) + 1
                 served += 1
-                continue
-            miss = float(trunc[2])
-            vals = out[qi, :k_static]
-            ids = _unpack_ids(out[qi, k_static:2 * k_static])
-            total = int(out[qi, 2 * k_static:][0])
-            nhit = int(min(k, np.isfinite(vals).sum()))
-            certified = False
-            if nhit >= k:
-                kth = float(vals[k - 1])
-                if k < k_static:
-                    # the (k+1)-th observed score bounds the best
-                    # excluded candidate
-                    nxt = (float(vals[k])
-                           if np.isfinite(vals[k]) else 0.0)
-                elif total <= k:
-                    # every matching doc is in the result: only
-                    # entirely-unseen docs (observed 0) could displace
-                    nxt = 0.0
-                else:
-                    nxt = None   # k == kernel k: no (k+1)-th to bound by
-                certified = (nxt is not None
-                             and impact_safe_termination(kth, nxt, miss))
-            # per-registration certificate track record (feeds the
-            # _trunc_hopeless back-off; refresh resets with the reg)
-            reg["trunc_attempts"] = reg.get("trunc_attempts", 0) + 1
-            if certified:
-                reg["trunc_certified"] = \
-                    reg.get("trunc_certified", 0) + 1
-            if not certified and self.impact_mode != "always":
-                # can't prove the truncated set exact — the full Python
-                # path serves it (the pre-impact behavior for oversize)
-                self.stats["trunc_refused"] = \
-                    self.stats.get("trunc_refused", 0) + 1
-                self.stats["bounced"] += 1
-                if h is not None:
-                    self.lib.es_fast_bounce(h, tok)
-                continue
-            v = vals[:nhit]
-            d = ids[:nhit]
-            order = np.lexsort((d, -v))
-            v = np.ascontiguousarray(v[order])
-            d = np.ascontiguousarray(d[order])
-            self.stats["trunc_served"] = \
-                self.stats.get("trunc_served", 0) + 1
-            if certified:
-                self.stats["trunc_certified"] = \
-                    self.stats.get("trunc_certified", 0) + 1
-            served += 1
-            if h is None:
-                return
-            self.lib.es_fast_respond(
-                h, tok, idx_b,
-                d.ctypes.data_as(ctypes.c_void_p),
-                v.ctypes.data_as(ctypes.c_void_p),
-                nhit, total, b"gte", took_ms)
+                if h is None:
+                    return
+                self.lib.es_fast_respond(
+                    h, tok, idx_b,
+                    d.ctypes.data_as(ctypes.c_void_p),
+                    v.ctypes.data_as(ctypes.c_void_p),
+                    nhit, total, b"gte",
+                    (t_done - arrived[tok]) // 1_000_000)
         self.stats["fast_queries"] += served
 
     # binary-search depth contract of the patch kernel (ops/fastpath)
@@ -1330,11 +1339,10 @@ class FastPathServer:
                 return (bkt, ess, ne, bound, float(theta), int(total))
         return None
 
-    def _launch_essential(self, reg, bucket, items, t_arrive, stack,
-                          rows):
+    def _launch_essential(self, reg, bucket, items, arrived, stack, rows):
         responded: set = set()
         try:
-            self._launch_essential_inner(reg, bucket, items, t_arrive,
+            self._launch_essential_inner(reg, bucket, items, arrived,
                                          stack, rows, responded)
         except Exception:
             logger.exception("essential launch failed; full-kernel "
@@ -1345,7 +1353,7 @@ class FastPathServer:
             left = [it for it in items if it[0] not in responded]
             try:
                 if left:
-                    self._refire_full(reg, left, t_arrive, stack, rows)
+                    self._refire_full(reg, left, arrived, stack, rows)
             except Exception:
                 h = self.front.h
                 for tok, *_ in left:
@@ -1354,10 +1362,8 @@ class FastPathServer:
                             self.lib.es_fast_bounce(h, tok)
                     except Exception:
                         pass
-        finally:
-            self._sem.release()
 
-    def _refire_full(self, reg, items, t_arrive, stack, rows):
+    def _refire_full(self, reg, items, arrived, stack, rows):
         """Uncertified/failed essential queries re-run on the exact full
         kernel (already holding a stream permit — run inline)."""
         full_items = [(tok, k, term_ids, filt)
@@ -1376,77 +1382,78 @@ class FastPathServer:
                     break
         self.stats["ess_refires"] = self.stats.get("ess_refires", 0) \
             + len(full_items)
-        self._launch_group_inner(reg, bucket, full_items, t_arrive,
-                                 stack, rows)
+        self._launch_group(reg, bucket, full_items, arrived, stack, rows,
+                           first=False)
 
-    def _launch_essential_inner(self, reg, bucket, items, t_arrive,
+    def _launch_essential_inner(self, reg, bucket, items, arrived,
                                 stack, rows, responded=None):
         from elasticsearch_tpu.ops.fastpath import (
             NE_SLOTS, bm25_essential_dense_topk_batch,
             bm25_essential_topk_batch)
         dp = reg["dp"]
         use_dense = reg.get("dense_tf") is not None
-        sel = np.full((self.q_batch, bucket), dp.zero_block,
-                      np.int32)
-        ws = np.zeros((self.q_batch, bucket), self._weight_dtype())
-        mask_ids = np.zeros(self.q_batch, np.int32)
-        ne_start = np.zeros((self.q_batch, NE_SLOTS), np.int32)
-        ne_len = np.zeros((self.q_batch, NE_SLOTS), np.int32)
-        ne_row = np.full((self.q_batch, NE_SLOTS), -1, np.int32)
-        ne_idf = np.zeros((self.q_batch, NE_SLOTS), self._weight_dtype())
-        ne_bound = np.zeros(self.q_batch, self._weight_dtype())
-        starts, nbs, idf = reg["starts"], reg["nb"], reg["idf"]
-        dense_rows = reg.get("dense_rows") or {}
-        bad: list = []
-        for qi, (tok, k, term_ids, filt, essd) in enumerate(items):
-            _bkt, ess_terms, ne_terms, bound, theta, total = essd
-            pos = 0
-            for t in ess_terms:
-                cnt = int(nbs[t])
-                st = int(starts[t])
-                sel[qi, pos:pos + cnt] = np.arange(st, st + cnt,
-                                                   dtype=np.int32)
-                ws[qi, pos:pos + cnt] = idf[t]
-                pos += cnt
-            for ti, t in enumerate(ne_terms):
-                # fill BOTH patch descriptors; the cohort upgrades to
-                # the dense kernel only when EVERY NE term resolved a
-                # row (attached-mode splits admit binary-only terms)
-                row = dense_rows.get(t, -1)
-                ne_row[qi, ti] = row
-                if row < 0:
-                    use_dense = False
-                ne_start[qi, ti] = reg["post_start"][t]
-                ne_len[qi, ti] = reg["post_len"][t]
-                ne_idf[qi, ti] = idf[t]
-            ne_bound[qi] = bound
-            if filt:
-                row = rows.get(filt)
-                if row is None:
-                    bad.append(tok)
-                    sel[qi, :] = dp.zero_block
-                    ws[qi, :] = 0.0
-                    continue
-                mask_ids[qi] = row
+        with host_span("fastpath.pack"):
+            sel = np.full((self.q_batch, bucket), dp.zero_block,
+                          np.int32)
+            ws = np.zeros((self.q_batch, bucket), self._weight_dtype())
+            mask_ids = np.zeros(self.q_batch, np.int32)
+            ne_start = np.zeros((self.q_batch, NE_SLOTS), np.int32)
+            ne_len = np.zeros((self.q_batch, NE_SLOTS), np.int32)
+            ne_row = np.full((self.q_batch, NE_SLOTS), -1, np.int32)
+            ne_idf = np.zeros((self.q_batch, NE_SLOTS), self._weight_dtype())
+            ne_bound = np.zeros(self.q_batch, self._weight_dtype())
+            starts, nbs, idf = reg["starts"], reg["nb"], reg["idf"]
+            dense_rows = reg.get("dense_rows") or {}
+            bad: list = []
+            for qi, (tok, k, term_ids, filt, essd) in enumerate(items):
+                _bkt, ess_terms, ne_terms, bound, theta, total = essd
+                pos = 0
+                for t in ess_terms:
+                    cnt = int(nbs[t])
+                    st = int(starts[t])
+                    sel[qi, pos:pos + cnt] = np.arange(st, st + cnt,
+                                                       dtype=np.int32)
+                    ws[qi, pos:pos + cnt] = idf[t]
+                    pos += cnt
+                for ti, t in enumerate(ne_terms):
+                    # fill BOTH patch descriptors; the cohort upgrades to
+                    # the dense kernel only when EVERY NE term resolved a
+                    # row (attached-mode splits admit binary-only terms)
+                    row = dense_rows.get(t, -1)
+                    ne_row[qi, ti] = row
+                    if row < 0:
+                        use_dense = False
+                    ne_start[qi, ti] = reg["post_start"][t]
+                    ne_len[qi, ti] = reg["post_len"][t]
+                    ne_idf[qi, ti] = idf[t]
+                ne_bound[qi] = bound
+                if filt:
+                    row = rows.get(filt)
+                    if row is None:
+                        bad.append(tok)
+                        sel[qi, :] = dp.zero_block
+                        ws[qi, :] = 0.0
+                        continue
+                    mask_ids[qi] = row
         masks = stack
         k_static = self.max_k
         if use_dense:
-            packed = bm25_essential_dense_topk_batch(
+            kernel, args = bm25_essential_dense_topk_batch, (
                 dp.block_docids, dp.block_tfs, reg["dense_tf"],
                 sel, ws, dp.doc_lens, masks, mask_ids,
                 ne_row, ne_idf, ne_bound,
                 self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
                 k_static)
         else:
-            packed = bm25_essential_topk_batch(
+            kernel, args = bm25_essential_topk_batch, (
                 dp.block_docids, dp.block_tfs, reg["flat_docids"],
                 reg["flat_tfs"], sel, ws, dp.doc_lens, masks, mask_ids,
                 ne_start, ne_len, ne_idf, ne_bound,
                 self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
                 k_static)
-        # ONE device→host sync per cohort, through the tracked funnel
-        out = _readback("search.fastpath.essential_cohort", packed)
-        took_ms = int((time.time() - t_arrive) * 1000)
+        out, t_done = self._launch_cohort(
+            "search.fastpath.essential_cohort", items, arrived, True,
+            kernel, *args)
         idx_b = reg["index"].encode()
         h = self.front.h
         self.stats["cohorts"] += 1
@@ -1456,35 +1463,36 @@ class FastPathServer:
         if responded is None:
             responded = set()
         refire: list = []
-        for qi, (tok, k, term_ids, filt, essd) in enumerate(items):
-            if tok in bad_set:
-                self._respond_empty(tok, reg)
+        with host_span("fastpath.respond"):
+            for qi, (tok, k, term_ids, filt, essd) in enumerate(items):
+                if tok in bad_set:
+                    self._respond_empty(tok, reg)
+                    responded.add(tok)
+                    continue
+                ok = int(out[qi, 2 * k_static:][0])
+                if not ok:
+                    refire.append((tok, k, term_ids, filt, essd))
+                    continue
+                vals = out[qi, :k_static]
+                ids = _unpack_ids(out[qi, k_static:2 * k_static])
+                nhit = int(min(k, np.isfinite(vals).sum()))
+                v = np.ascontiguousarray(vals[:nhit])
+                d = np.ascontiguousarray(ids[:nhit])
+                if h is None:
+                    return
+                self.lib.es_fast_respond(
+                    h, tok, idx_b,
+                    d.ctypes.data_as(ctypes.c_void_p),
+                    v.ctypes.data_as(ctypes.c_void_p),
+                    nhit, essd[5], b"eq",
+                    (t_done - arrived[tok]) // 1_000_000)
                 responded.add(tok)
-                continue
-            ok = int(out[qi, 2 * k_static:][0])
-            if not ok:
-                refire.append((tok, k, term_ids, filt, essd))
-                continue
-            vals = out[qi, :k_static]
-            ids = _unpack_ids(out[qi, k_static:2 * k_static])
-            nhit = int(min(k, np.isfinite(vals).sum()))
-            v = np.ascontiguousarray(vals[:nhit])
-            d = np.ascontiguousarray(ids[:nhit])
-            if h is None:
-                return
-            self.lib.es_fast_respond(
-                h, tok, idx_b,
-                d.ctypes.data_as(ctypes.c_void_p),
-                v.ctypes.data_as(ctypes.c_void_p),
-                nhit, essd[5], b"eq", took_ms)
-            responded.add(tok)
         self.stats["fast_queries"] += len(items) - len(refire)
         if refire:
             for tok, k, term_ids, filt, _essd in refire:
                 if len(reg["ess_bad"]) < 100_000:
                     reg["ess_bad"].add((tuple(term_ids), filt, k))
-            self._refire_full(reg, refire, t_arrive, stack,
-                              rows)
+            self._refire_full(reg, refire, arrived, stack, rows)
             for tok, *_ in refire:
                 responded.add(tok)
 
@@ -1617,46 +1625,45 @@ class FastPathServer:
                 mb.replicated(rmesh, stack),
                 mb.shard_rows(rmesh, mask_ids))
 
-    def _launch_group_inner(self, reg, bucket, items, t_arrive,
-                            stack, rows):
+    def _launch_group(self, reg, bucket, items, arrived, stack, rows,
+                      first=True):
         from elasticsearch_tpu.ops.fastpath import bm25_topk_total_batch
         dp = reg["dp"]
         q = len(items)
-        sel = np.full((self.q_batch, bucket), dp.zero_block,
-                      np.int32)
-        ws = np.zeros((self.q_batch, bucket), self._weight_dtype())
-        mask_ids = np.zeros(self.q_batch, np.int32)
-        starts, nbs, idf = reg["starts"], reg["nb"], reg["idf"]
-        no_match: list = []
-        for qi, (tok, k, term_ids, filt) in enumerate(items):
-            pos = 0
-            for t in term_ids:
-                if t < 0:
-                    continue
-                cnt = int(nbs[t])
-                s = int(starts[t])
-                sel[qi, pos:pos + cnt] = np.arange(s, s + cnt,
-                                                   dtype=np.int32)
-                ws[qi, pos:pos + cnt] = idf[t]
-                pos += cnt
-            if filt:
-                row = rows.get(filt)
-                if row is None:          # unknown filter term ⇒ no hits
-                    no_match.append(tok)
-                    sel[qi, :] = dp.zero_block
-                    ws[qi, :] = 0.0
-                    continue
-                mask_ids[qi] = row
+        with host_span("fastpath.pack"):
+            sel = np.full((self.q_batch, bucket), dp.zero_block,
+                          np.int32)
+            ws = np.zeros((self.q_batch, bucket), self._weight_dtype())
+            mask_ids = np.zeros(self.q_batch, np.int32)
+            starts, nbs, idf = reg["starts"], reg["nb"], reg["idf"]
+            no_match: list = []
+            for qi, (tok, k, term_ids, filt) in enumerate(items):
+                pos = 0
+                for t in term_ids:
+                    if t < 0:
+                        continue
+                    cnt = int(nbs[t])
+                    s = int(starts[t])
+                    sel[qi, pos:pos + cnt] = np.arange(s, s + cnt,
+                                                       dtype=np.int32)
+                    ws[qi, pos:pos + cnt] = idf[t]
+                    pos += cnt
+                if filt:
+                    row = rows.get(filt)
+                    if row is None:          # unknown filter term ⇒ no hits
+                        no_match.append(tok)
+                        sel[qi, :] = dp.zero_block
+                        ws[qi, :] = 0.0
+                        continue
+                    mask_ids[qi] = row
         k_static = self.max_k
         bd, bt, sel_m, ws_m, dl, mk, mi = self._v1_inputs(
             reg, sel, ws, stack, mask_ids)
-        packed = bm25_topk_total_batch(
-            bd, bt, sel_m, ws_m, dl, mk, mi,
+        out, t_done = self._launch_cohort(
+            "search.fastpath.v1_cohort", items, arrived, first,
+            bm25_topk_total_batch, bd, bt, sel_m, ws_m, dl, mk, mi,
             self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
             k_static)
-        # ONE device→host sync per cohort, through the tracked funnel
-        out = _readback("search.fastpath.v1_cohort", packed)
-        took_ms = int((time.time() - t_arrive) * 1000)
         self.stats["cohorts"] += 1
         if self._mesh_active(reg):
             self.stats["mesh_cohorts"] = \
@@ -1664,19 +1671,22 @@ class FastPathServer:
             self.mesh_backend._dispatch("replica", q)
         self.stats["fast_queries"] += q
         no_match_set = set(no_match)
-        for qi, (tok, k, term_ids, filt) in enumerate(items):
-            if tok in no_match_set:
-                self._respond_empty(tok, reg)
-                continue
-            vals = out[qi, :k_static]
-            ids = _unpack_ids(out[qi, k_static:2 * k_static])
-            total = int(out[qi, 2 * k_static:][0])
-            nhit = int(min(k, np.isfinite(vals).sum()))
-            v = vals[:nhit]
-            d = ids[:nhit]
-            # ES tie order: equal scores rank by docid ascending (the
-            # device top_k's tie order is arbitrary)
-            order = np.lexsort((d, -v))
-            self._respond_hits(reg, tok, np.ascontiguousarray(v[order]),
-                               np.ascontiguousarray(d[order]),
-                               k, total, took_ms, term_ids, filt)
+        with host_span("fastpath.respond"):
+            for qi, (tok, k, term_ids, filt) in enumerate(items):
+                if tok in no_match_set:
+                    self._respond_empty(tok, reg)
+                    continue
+                vals = out[qi, :k_static]
+                ids = _unpack_ids(out[qi, k_static:2 * k_static])
+                total = int(out[qi, 2 * k_static:][0])
+                nhit = int(min(k, np.isfinite(vals).sum()))
+                v = vals[:nhit]
+                d = ids[:nhit]
+                # ES tie order: equal scores rank by docid ascending (the
+                # device top_k's tie order is arbitrary)
+                order = np.lexsort((d, -v))
+                took_ms = (t_done - arrived[tok]) // 1_000_000
+                self._respond_hits(reg, tok,
+                                   np.ascontiguousarray(v[order]),
+                                   np.ascontiguousarray(d[order]),
+                                   k, total, took_ms, term_ids, filt)

@@ -50,6 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 # recorder via profile.note_kernel
 from elasticsearch_tpu.search import profile as _profile
 from elasticsearch_tpu.telemetry import flightrecorder as _flight
+from elasticsearch_tpu.telemetry.tracing import host_span
 
 _prof_tls = _profile._tls
 _flight_tls = _flight._tls
@@ -448,6 +449,9 @@ def tracked_jit(name: Optional[str] = None, *,
         jitted = jax.jit(fn, static_argnames=static_argnames,
                          **jit_kwargs)
         kname = name or fn.__name__.lstrip("_")
+        # the host span around every tracked call: dispatch, plus the
+        # compile on a shape's first execution
+        span_name = "launch:" + kname
         try:
             params: List[str] = list(inspect.signature(fn).parameters)
         except (TypeError, ValueError):
@@ -477,7 +481,8 @@ def tracked_jit(name: Optional[str] = None, *,
             fr = getattr(_flight_tls, "rec", None)
             if not TRACKER.on_call(kname, key):
                 tfr = fr.clock() if fr is not None else 0.0
-                out = jitted(*args, **kwargs)
+                with host_span(span_name):
+                    out = jitted(*args, **kwargs)
                 if fr is not None:
                     info = getattr(_flight_tls, "launch_info", None) or {}
                     fr.record_launch(
@@ -492,7 +497,8 @@ def tracked_jit(name: Optional[str] = None, *,
                 return out
             t0 = time.perf_counter()
             try:
-                out = jitted(*args, **kwargs)
+                with host_span(span_name):
+                    out = jitted(*args, **kwargs)
             except BaseException:
                 TRACKER.on_error(kname, key)
                 raise

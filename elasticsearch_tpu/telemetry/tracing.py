@@ -21,6 +21,12 @@ Design for the deterministic harness:
   paging — long-running nodes can't grow trace memory without limit;
 - open spans are tracked so the test harness can fail a test that
   starts a span and never finishes it (tests/conftest.py leak guard).
+
+``host_span(name)`` is the other half: a span on the profiler's own
+clock (``jax.profiler.TraceAnnotation``), so the serving path's host
+work and waits land in a device trace next to the kernels they feed,
+nested per thread. Its names are stable and dotted (COMPONENTS.md
+"Observability" lists them).
 """
 
 from __future__ import annotations
@@ -37,6 +43,20 @@ _TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
 
 def all_tracers() -> List["Tracer"]:
     return list(_TRACERS)
+
+
+_TraceAnnotation = None
+
+
+def host_span(name: str):
+    """A context manager that records ``name`` as a host span in the
+    profiler's trace while a profile is being taken. Without one it
+    costs the construction of the annotation: no lock, no clock read."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
 
 
 def open_span_keys() -> set:
