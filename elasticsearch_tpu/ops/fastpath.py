@@ -61,6 +61,14 @@ def _score_dtype():
     return jnp.float64 if jax.config.jax_enable_x64 else jnp.float32
 
 
+def merge_payload() -> str:
+    """What the v2m kernel's merge carries beside the docid keys:
+    "contrib", the float32 contributions themselves, or on the f64 rail
+    "lane", the int32 lane index they are gathered back through (the
+    Pallas merge is Mosaic, which has no real f64)."""
+    return "contrib" if _score_dtype() == jnp.float32 else "lane"
+
+
 def _doubling_scan(keys, vals, steps=_SCAN_STEPS):
     """Segmented inclusive sums over contiguous key-runs along the LAST
     axis (Hillis-Steele with the key-equality carry; run length must be
@@ -412,11 +420,13 @@ def bm25_topk_total_merge_batch(
         avg_len, n_slots: int, k1: float, b: float, k: int):
     """The v1 exact kernel with ONE substitution: the monolithic
     O(P·logP) ``lax.sort`` becomes the linear-work bitonic merge of the
-    per-term sorted runs (ops/merge.py), carrying the rail-dtype
-    contributions through the merge. Everything downstream — doubling
-    segmented scan, exact totals, stable lowest-docid top-k — is the v1
-    code verbatim, so output equivalence is by construction (same
-    packing: [values (k) | docids (k) | total], float32 [Q, 2k+1])."""
+    per-term sorted runs (ops/merge.py). The merge's payload is the
+    float32 contributions themselves, or on the f64 rail the lane index
+    they are gathered back through (``merge_payload``). Everything
+    downstream — doubling segmented scan, exact totals, stable
+    lowest-docid top-k — is the v1 code verbatim, so output equivalence
+    is by construction (same packing: [values (k) | docids (k) |
+    total], float32 [Q, 2k+1])."""
     from elasticsearch_tpu.ops.merge import merge_sorted_slots
     Q, NB = sel_blocks.shape
     B = block_docids.shape[1]
@@ -437,15 +447,20 @@ def bm25_topk_total_merge_batch(
         return key.reshape(-1), contrib.reshape(-1)
 
     keys, cons = jax.vmap(gather_one)(sel_blocks, sel_weights, mask_ids)
-    # the merge carries the LANE INDEX as payload (all-int32 — the
-    # pallas chunk kernels must never see the rail dtype: Mosaic has no
-    # real f64 and silently loses the rail's precision); the rail-dtype
-    # contributions are gathered through the merged permutation at XLA
-    # level, where f64 is exact
-    lane = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :],
-                            (Q, P)).reshape(Q, n_slots, L)
-    mk, midx = merge_sorted_slots(keys.reshape(Q, n_slots, L), lane)
-    x = jnp.take_along_axis(cons, midx, axis=1)
+    keys = keys.reshape(Q, n_slots, L)
+    if merge_payload() == "contrib":
+        # float32 rail: the contributions ride the merge themselves (the
+        # network swaps on keys alone, so they land where a gather
+        # through the merged lane order would put them, bit for bit)
+        mk, x = merge_sorted_slots(keys, cons.reshape(Q, n_slots, L))
+    else:
+        # f64 rail: Mosaic has no real f64 and would silently lose the
+        # rail's precision, so the merge carries the int32 lane index
+        # and the contributions are gathered through it at XLA level
+        lane = jnp.broadcast_to(jnp.arange(P, dtype=jnp.int32)[None, :],
+                                (Q, P)).reshape(Q, n_slots, L)
+        mk, midx = merge_sorted_slots(keys, lane)
+        x = jnp.take_along_axis(cons, midx, axis=1)
     # runs <= N_SLOTS=16 term instances: 4 steps cover them; the
     # default 5th would be a wasted full-width pass per launch
     x = _doubling_scan(mk, x, steps=(1, 2, 4, 8))

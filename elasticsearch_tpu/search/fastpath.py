@@ -236,6 +236,7 @@ class FastPathServer:
         """Routing/dispatch telemetry of the serving front: per-lane ×
         nb-bucket dispatch counts, cohort-width histogram, padding
         waste, warm-up seconds, and the truncated-lane counters."""
+        from elasticsearch_tpu.ops.fastpath import merge_payload
         padded = self.pad_rows + self.used_rows
         return {
             "dispatch": dict(self.dispatch),
@@ -247,6 +248,8 @@ class FastPathServer:
             "nb_buckets": list(self.nb_buckets),
             "ess_buckets": list(self.ess_buckets),
             "impact_mode": self.impact_mode,
+            # what the v2m merge carries on this rail: "contrib" or "lane"
+            "merge_payload": merge_payload(),
             "counters": {k: v for k, v in self.stats.items()
                          if isinstance(v, (int, float))},
         }

@@ -57,6 +57,31 @@ def test_v2m_merge_kernel_compiles_to_a_tpu_custom_call(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_v2m_carries_contributions_through_the_merge(one_chip,
+                                                     monkeypatch):
+    """At NB 2048 the compiler counts ~94.9e9 bytes accessed when the
+    merge carries lane indices and the contributions are gathered back
+    through them, ~54.6e9 when the float32 contributions ride the merge.
+    The bound sits between the two; the temp bound keeps the merge's
+    inputs out of batch-minor (4x padded) layouts (~0.57e9 bytes there,
+    ~0.17e9 without)."""
+    from elasticsearch_tpu.ops import fastpath, merge
+    monkeypatch.setattr(merge, "_interpret", lambda: False)
+    assert fastpath.merge_payload() == "contrib"
+    S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
+    nb = 2048
+    compiled = fastpath.bm25_topk_total_merge_batch.__wrapped_jit__.lower(
+        S((TB, B), jnp.int32), S((TB, B), jnp.float32),
+        S((Q, nb), jnp.int32), S((Q, nb), jnp.float32),
+        S((ND,), jnp.float32), S((fastpath.F_SLOTS, ND), jnp.bool_),
+        S((Q,), jnp.int32), S((), jnp.float32),
+        n_slots=16, k1=1.2, b=0.75, k=K).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] < 75e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 3e8
+
+
 def test_knn_nominate_compiles_over_a_bf16_slab(one_chip):
     from elasticsearch_tpu.ops import vector
     S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731

@@ -115,6 +115,23 @@ def run_both(seg, queries, n_docs=2000, k=50,
     return out1, out2
 
 
+def run_v2m(seg, queries, n_docs, k=50, n_slots=8, nb_bucket=64):
+    """The v2m kernel over the slotted layout of ``queries``."""
+    import jax
+    wd = np.float64 if jax.config.jax_enable_x64 else np.float32
+    idf = np.log1p(n_docs / (seg["nb"] * BLOCK))
+    sel = np.zeros((len(queries), nb_bucket), np.int32)
+    ws = np.zeros((len(queries), nb_bucket), wd)
+    for qi, terms in enumerate(queries):
+        s, w, *_ = slotted_sel(seg, terms, idf, n_slots, nb_bucket)
+        sel[qi], ws[qi] = s, w
+    return np.asarray(fp.bm25_topk_total_merge_batch(
+        seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws),
+        seg["lens"], jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
+        jnp.asarray(np.zeros(len(queries), np.int32)), wd(seg["avg"]),
+        n_slots, 1.2, 0.75, k))
+
+
 def unpack1(row, k):
     return row[:k], unpack_ids(row[k:2 * k]), int(row[2 * k])
 
@@ -168,6 +185,37 @@ def test_v2_duplicate_term_instances():
         nv2, nd2 = _norm_hits(v2, d2, k)
         np.testing.assert_array_equal(nd1, nd2)
         np.testing.assert_allclose(nv1, nv2, rtol=1e-6)
+
+
+def _v2m_case(case):
+    if case == "dup_terms":
+        rng = np.random.default_rng(3)
+        return 1000, build_segment(rng, 1000, n_terms=6), \
+            [[2, 2, 5], [0, 1, 2, 3, 4, 5]]
+    rng = np.random.default_rng(case)
+    seg = build_segment(rng, 2000, n_terms=12)
+    queries = [list(rng.choice(12, size=int(rng.integers(1, 6)),
+                               replace=False))
+               for _ in range(4)]
+    return 2000, seg, queries
+
+
+@pytest.mark.parametrize("case", [0, 1, 2, "dup_terms"])
+def test_v2m_matches_v1(case):
+    """The merge kernel (v2m) answers as the monolithic-sort kernel (v1):
+    ids and exact totals equal, scores to rtol 1e-6 in contract order."""
+    n_docs, seg, queries = _v2m_case(case)
+    k = 50
+    out1, _ = run_both(seg, queries, n_docs=n_docs, k=k)
+    outm = run_v2m(seg, queries, n_docs, k=k)
+    for qi in range(len(queries)):
+        v1, d1, t1 = unpack1(out1[qi], k)
+        vm, dm, tm = unpack1(outm[qi], k)
+        assert t1 == tm, (qi, t1, tm)
+        nv1, nd1 = _norm_hits(v1, d1, k)
+        nvm, ndm = _norm_hits(vm, dm, k)
+        np.testing.assert_array_equal(nd1, ndm)
+        np.testing.assert_allclose(nv1, nvm, rtol=1e-6)
 
 
 def test_v2_bucket_slot_fit_routing():

@@ -57,6 +57,38 @@ def test_merge_network_matches_sort(n_slots, P, chunk):
         assert a == b
 
 
+@pytest.mark.parametrize("n_slots,P,chunk,n_docs", [
+    (4, 1 << 12, 1 << 10, 1100),    # few docs: keys repeat across slots
+    (8, 1 << 13, 1 << 11, 1200),
+    (16, 1 << 14, 1 << 12, 40_000),
+    (8, 1 << 13, 1 << 9, 1100),     # many XLA stages
+])
+def test_merge_permutation_is_payload_independent(n_slots, P, chunk,
+                                                  n_docs):
+    """Merging (keys, lane index) and (keys, float32 values) applies one
+    permutation: the values carried through the network are, bit for
+    bit, the values gathered back through the merged lane index — also
+    where one doc's key sits in several slots (one doc in several term
+    runs), whose order the segmented sum depends on."""
+    Q = 2
+    keys, vals = make_inputs(Q, P, n_slots, seed=n_slots + P,
+                             n_docs=n_docs)
+    flat = keys.reshape(Q, P)
+    assert any(len(np.unique(r[r != SENT])) < int(np.sum(r != SENT))
+               for r in flat), "no key repeats across slots"
+    lane = np.broadcast_to(np.arange(P, dtype=np.int32),
+                           (Q, P)).reshape(keys.shape)
+    lk, perm = merge_sorted_slots(jnp.asarray(keys), jnp.asarray(lane),
+                                  chunk=chunk, force_pallas=True)
+    vk, mv = merge_sorted_slots(jnp.asarray(keys), jnp.asarray(vals),
+                                chunk=chunk, force_pallas=True)
+    np.testing.assert_array_equal(np.asarray(lk), np.asarray(vk))
+    gathered = np.take_along_axis(vals.reshape(Q, P), np.asarray(perm),
+                                  axis=1)
+    np.testing.assert_array_equal(gathered.view(np.uint32),
+                                  np.asarray(mv).view(np.uint32))
+
+
 def test_merge_all_sentinel_slots():
     Q, n_slots, L = 1, 4, 512
     keys = np.full((Q, n_slots, L), SENT, np.int32)

@@ -437,6 +437,8 @@ def test_impact_truncated_lane_serves_oversize(tmp_path):
         kern = req(port, "GET", "/_kernels")
         assert "serving" in kern
         assert kern["serving"]["impact_mode"] == "always"
+        # float32 rail: the v2m merge carries the contributions
+        assert kern["serving"]["merge_payload"] == "contrib"
         assert any(k.startswith("trunc:")
                    for k in kern["serving"]["dispatch"])
         # truncated hits are real matches: every returned id appears in
