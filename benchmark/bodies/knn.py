@@ -11,8 +11,18 @@ import numpy as np
 
 from benchmark import oracle
 
+
+def vectors(data, params: dict) -> np.ndarray:
+    """The field's vectors. The reference scores one shard; a sharded
+    configuration is refused."""
+    if len(data) != 1:
+        raise ValueError(f"knn bodies serve a single-shard configuration; "
+                         f"this one has {len(data)} shards")
+    return data[0][params["field"]]
+
+
 def queries(data, params: dict, rng, n: int) -> np.ndarray:
-    dims = data[params["field"]].shape[1]
+    dims = vectors(data, params).shape[1]
     v = rng.standard_normal((n, dims), dtype=np.float32)
     return np.round(v * 256.0) / 256.0
 
@@ -27,13 +37,13 @@ def encode(q, params: dict) -> bytes:
 
 def reference(data, qs, params: dict):
     """Exact cosine scores (1 + cos) / 2 in float64, one array per query."""
-    return oracle.cosine_exact(data[params["field"]],
+    return oracle.cosine_exact(vectors(data, params),
                                np.asarray(qs, np.float32))
 
 
 def control(data, qs, params: dict):
     """The same scores computed in bfloat16."""
-    return oracle.cosine_bf16(data[params["field"]],
+    return oracle.cosine_bf16(vectors(data, params),
                               np.asarray(qs, np.float32))
 
 

@@ -10,7 +10,7 @@ postings and imports nothing of the program.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Sequence
 
 import numpy as np
 
@@ -76,18 +76,24 @@ def build_corpus(rng, n_docs: int, vocab: int, avg_len: int = 40,
                 n_postings=n_postings)
 
 
-def make_queries(rng, df: np.ndarray, n_docs: int, n_queries: int,
+def make_queries(rng, shards: Sequence[dict], n_queries: int,
                  max_blocks: int = 4096) -> List[List[int]]:
-    """``n_queries`` queries of 1-8 distinct terms drawn across df bands
-    (rare → common), each trimmed of its most common terms until its
-    postings fit ``max_blocks`` blocks."""
+    """Up to ``n_queries`` queries of 1-8 distinct terms drawn across the
+    df bands (rare → common) of the index that the corpora ``shards``
+    make up, one per primary shard. Each is trimmed of its most common
+    terms until no shard needs more than ``max_blocks`` postings blocks
+    for it; one left with a single term that alone needs more (a stop
+    word) is dropped."""
+    df = sum(c["df"] for c in shards)
+    n_docs = sum(len(c["lens"]) for c in shards)
+    nb = np.stack([c["nb"] for c in shards])
+    most = nb.max(axis=0)
     bands = [
         np.nonzero((df > 200) & (df <= n_docs // 100))[0],
         np.nonzero((df > n_docs // 100) & (df <= n_docs // 20))[0],
         np.nonzero(df > n_docs // 20)[0],
     ]
     bands = [b for b in bands if len(b) > 0]
-    nb = (df + BLOCK - 1) // BLOCK
     queries = []
     for _ in range(n_queries):
         n_terms = int(rng.integers(1, 9))
@@ -97,9 +103,10 @@ def make_queries(rng, df: np.ndarray, n_docs: int, n_queries: int,
                              len(bands) - 1)]
             terms.append(int(rng.choice(band)))
         q = sorted(set(terms))
-        while len(q) > 1 and sum(int(nb[t]) for t in q) > max_blocks:
-            q.remove(max(q, key=lambda t: int(nb[t])))
-        queries.append(q)
+        while len(q) > 1 and int(nb[:, q].sum(axis=1).max()) > max_blocks:
+            q.remove(max(q, key=lambda t: int(most[t])))
+        if int(nb[:, q].sum(axis=1).max()) <= max_blocks:
+            queries.append(q)
     return queries
 
 
@@ -107,24 +114,17 @@ def term_name(t: int) -> str:
     return f"t{t:06d}"
 
 
-def bm25_exact(corpus, terms: Sequence[int],
-               n_docs: Optional[int] = None) -> np.ndarray:
-    """float64 BM25 of every doc for a disjunction of ``terms`` — over
-    the whole corpus, or over its first ``n_docs`` docs taken as an
-    index of their own (their own df, doc count and average length)."""
+def bm25_exact(corpus, terms: Sequence[int]) -> np.ndarray:
+    """float64 BM25 of every doc for a disjunction of ``terms``."""
     lens = corpus["lens"].astype(np.float64)
     gs, d_all, tf_all = (corpus["group_start"], corpus["doc_ids"],
                          corpus["tf"])
-    n = len(lens) if n_docs is None else int(n_docs)
-    lens = lens[:n]
+    n = len(lens)
     norm = K1 * (1.0 - B + B * lens / lens.mean())
     scores = np.zeros(n, np.float64)
     for t in terms:
         d = d_all[gs[t]:gs[t + 1]]
         f = tf_all[gs[t]:gs[t + 1]].astype(np.float64)
-        if n < len(corpus["lens"]):
-            keep = d < n
-            d, f = d[keep], f[keep]
         df = len(d)
         if df == 0:
             continue

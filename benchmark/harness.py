@@ -18,6 +18,12 @@ process, mount the data (mount.py), warm up, then drive ``POST
 generator for the window. After the window: read the counters and the
 device's peak memory, free the node, compare a sample of the answers
 with the reference, and reduce the metrics.
+
+A configuration's ``settings.number_of_shards`` N splits its ``docs``
+into N equal primary shards, each made from streams of its own and
+mounted as one segment; shard ``s``'s doc ``d`` has the ``_id``
+``s * docs / N + d``. The data is a list of N dicts, field -> what the
+field's builder made.
 """
 
 from __future__ import annotations
@@ -64,18 +70,28 @@ def load_json(*parts) -> dict:
 def cell(name: str, overrides: Optional[dict] = None) -> tuple:
     """(workload entry, configuration file, traffic file, metric entries
     that apply to this cell). ``overrides`` replace keys of the
-    configuration or the traffic file (the CPU rehearsal's sizes)."""
+    configuration, the workload entry (``chips``) or the traffic file
+    (the CPU rehearsal's sizes)."""
     spec = load_json(ROOT, "BENCHMARK.json")
     by_name = {w["name"]: w for w in spec["workloads"]}
     if name not in by_name:
         raise SystemExit(f"unknown workload {name!r}; BENCHMARK.json has "
                          f"{sorted(by_name)}")
-    w = by_name[name]
+    w = dict(by_name[name])
     conf_entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     config = load_json(ROOT, conf_entry["file"])
     traffic = load_json(HERE, "traffic", w["traffic"] + ".json")
     for key, val in (overrides or {}).items():
-        (config if key in config else traffic)[key] = val
+        (config if key in config else w if key in w else traffic)[key] = val
+    n = shards(config)
+    if n > w["chips"]:
+        raise SystemExit(f"{name}: {w['config']} has {n} primary shards, "
+                         f"more than the cell's {w['chips']} chip(s); "
+                         "each shard needs a chip of its own")
+    if n > 1 and config["fast_path"]:
+        raise SystemExit(f"{name}: the fast path serves single-shard "
+                         f"indices only; {w['config']} has {n} shards, so "
+                         "its fast_path must be false")
 
     def applies(m):
         return "workloads" not in m or name in m["workloads"]
@@ -113,8 +129,15 @@ def http(port: int, method: str, path: str, body=None) -> dict:
         return json.loads(resp.read())
 
 
+#: histograms of ``GET /_nodes/stats`` ``telemetry.metrics`` whose count
+#: and sum (ms) the counters carry
+HISTOGRAMS = ("fastpath.queue_wait", "fastpath.inflight",
+              "http.fallback.queue_wait", "knn.queue_wait", "knn.rerank")
+
+
 def counters(node, port: int) -> dict:
-    """Public counters (``GET /_kernels``) and the in-process ones."""
+    """Public counters (``GET /_kernels``, ``GET /_nodes/stats``) and the
+    in-process ones. A key the node does not serve reads 0."""
     from benchmark import mount
     k = http(port, "GET", "/_kernels")
     t = k["totals"]
@@ -122,6 +145,19 @@ def counters(node, port: int) -> dict:
     out = {"first_executions": t["count"] + t["cache_hits"]}
     for key in ("cohorts", "fast_queries", "bounced", "errors"):
         out[key] = serving.get(key, 0)
+    mesh = k.get("mesh") or {}
+    mc = mesh.get("counters") or {}
+    out["mesh_searches"] = mesh.get("mesh_searches", 0)
+    out["mesh.dispatch.shard"] = mc.get("dispatch.shard", 0)
+    out["mesh.fallback"] = sum(v for key, v in mc.items()
+                               if key.startswith("fallback."))
+    node_stats = next(iter(http(port, "GET", "/_nodes/stats")["nodes"]
+                           .values()))
+    hists = node_stats.get("telemetry", {}).get("metrics", {})
+    for name in HISTOGRAMS:
+        h = hists.get(name, {})
+        out[name + ".count"] = h.get("count", 0)
+        out[name + ".sum"] = h.get("sum", 0.0)
     out.update(mount.counters(node))
     return out
 
@@ -139,24 +175,41 @@ def use_compile_cache() -> None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 
 
-def make_data(config: dict, seed: int) -> dict:
+def shards(config: dict) -> int:
+    return int(config["settings"].get("number_of_shards", 1))
+
+
+def shard_docs(config: dict) -> int:
+    """Docs of each primary shard: ``docs`` split evenly."""
+    n, s = int(config["docs"]), shards(config)
+    if n % s:
+        raise ValueError(f"{n} docs do not split evenly into {s} shards")
+    return n // s
+
+
+def make_data(config: dict, seed: int) -> list:
+    """One dict per primary shard, field -> what its builder made from
+    the stream ``field:<f>`` (shard 0) or ``field:<f>:shard<s>``."""
     t = time.monotonic()
-    n = int(config["docs"])
-    data = {f: module("fields", spec["type"]).build(
-                rng(seed, f"field:{f}"), n, spec)
-            for f, spec in config["fields"].items()}
-    log(f"data: {n} docs, fields {sorted(data)} in "
+    n = shard_docs(config)
+    data = [{f: module("fields", spec["type"]).build(
+                 rng(seed, f"field:{f}" + (f":shard{s}" if s else "")),
+                 n, spec)
+             for f, spec in config["fields"].items()}
+            for s in range(shards(config))]
+    log(f"data: {len(data)} x {n} docs, fields {sorted(data[0])} in "
         f"{time.monotonic() - t:.1f} s")
     return data
 
 
 @contextlib.contextmanager
-def serving(config: dict, data: dict, body, params: dict):
+def serving(config: dict, data: list, body, params: dict):
     """A default ``Node`` in this process serving the configuration's
     index over loopback HTTP, mounted and warm; yields (node, port)."""
     from elasticsearch_tpu.node import Node
     from benchmark import mount
     index = config["index"]
+    n = shard_docs(config)
     with tempfile.TemporaryDirectory() as tmp:
         node = Node(data_path=tmp)
         try:
@@ -167,8 +220,10 @@ def serving(config: dict, data: dict, body, params: dict):
                     f: module("fields", spec["type"]).mapping(spec)
                     for f, spec in config["fields"].items()}}})
             t = time.monotonic()
-            mount.mount(node, index, mount.segment(
-                index + "0", int(config["docs"]), config["fields"], data))
+            mount.mount(node, index, [
+                mount.segment(f"{index}{s}", n, config["fields"], d,
+                              id_base=s * n)
+                for s, d in enumerate(data)])
             if config["fast_path"]:
                 mount.wait_fast_path(node, index, REGISTER_TIMEOUT_S)
             if hasattr(body, "warm"):
@@ -179,13 +234,15 @@ def serving(config: dict, data: dict, body, params: dict):
             node.close()
 
 
-def device_info() -> dict:
+def device_info(chips: int) -> dict:
+    """The device as JAX reports it; ``memory_peak_bytes`` is the peak of
+    the fullest of the cell's ``chips`` devices."""
     import jax
-    dev = jax.devices()[0]
-    mem = dev.memory_stats() or {}
-    return {"platform": dev.platform, "kind": dev.device_kind,
-            "count": len(jax.devices()),
-            "memory_peak_bytes": int(mem.get("peak_bytes_in_use", 0))}
+    devs = jax.devices()
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devs[:chips]]
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs), "memory_peak_bytes": max(peaks)}
 
 
 # -------------------------------------------------------------- the load
@@ -263,7 +320,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             res = drive(port, index, body, params, qs, traffic, seconds,
                         keep, due)
         after = counters(node, port)
-        device = device_info()
+        device = device_info(w["chips"])
     log(f"window: {res.sent} sent, "
         f"{int(np.sum(res.status == 200))} answered 200")
 
@@ -312,16 +369,18 @@ def context(res, due, seconds, setup_s, before, after, red, data, params,
                     seconds if due is None else due[-1]))
     start = due[idx] if due is not None else res.send_s[idx]
     done = np.where(ok, res.done_s[idx], end)    # a failure: missing
+    completed = np.nonzero(res.shaped & (res.done_s >= 0)
+                           & (res.done_s <= seconds))[0]
     ctx = types.SimpleNamespace(
         loop="open" if due is not None else "closed",
         seconds=seconds, setup_s=setup_s, attempted=int(len(idx)),
         failed=int(np.sum(~ok)), latency_s=done - start,
         late_s=(res.send_s[idx] - due[idx]) if due is not None else None,
-        completed=int(np.sum(res.shaped & (res.done_s >= 0)
-                             & (res.done_s <= seconds))),
+        completed=int(len(completed)),
         before=before, after=after, trace=red, data=data, params=params,
         body=body, device_kind=device_kind, workload=workload,
-        queries_done=[qs[i] for i in idx[ok]])
+        queries_done=[qs[i] for i in idx[ok]],
+        queries_completed=[qs[i] for i in completed])
     ctx.delta = lambda key: ctx.after[key] - ctx.before[key]
     return ctx
 

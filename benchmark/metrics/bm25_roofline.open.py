@@ -2,12 +2,8 @@
 (work/bm25.py: the real postings of their terms) over the device time
 of the fast path's scoring programs in the trace, in %."""
 
-from benchmark.readers import roofline
-from benchmark.work import bm25
+from benchmark.readers import bm25_roofline
 
 
 def read(ctx):
-    qs = ctx.queries_done
-    postings = sum(ctx.body.postings(ctx.data, ctx.params, q) for q in qs)
-    flops, nbytes = bm25.work(postings, len(qs), ctx.params["size"])
-    return roofline(ctx, bm25, flops, nbytes)
+    return bm25_roofline(ctx, ctx.queries_done)

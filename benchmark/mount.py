@@ -90,10 +90,11 @@ def _keyword(field: str, values: List[str], ords: np.ndarray):
 
 
 def segment(name: str, n: int, fields: Dict[str, dict],
-            data: Dict[str, object]):
+            data: Dict[str, object], id_base: int):
     """One ``Segment`` of ``n`` docs holding every field of the
     configuration (``fields``: name -> spec, ``data``: name -> what the
-    field's builder made), with ids "0".."n-1" and no stored source."""
+    field's builder made), with ids ``id_base`` .. ``id_base + n - 1``
+    and no stored source."""
     from elasticsearch_tpu.index.segment import (Segment, StoredFields,
                                                  VectorValues)
     from benchmark.fields import keyword
@@ -111,17 +112,21 @@ def segment(name: str, n: int, fields: Dict[str, dict],
         else:
             raise ValueError(f"no mount for field type {kind!r}")
     stored = StoredFields(offsets=np.zeros(n + 1, np.int64), data=b"",
-                          ids=[str(i) for i in range(n)])
+                          ids=[str(id_base + i) for i in range(n)])
     return Segment(name, n, postings=postings, numerics={},
                    keywords=keywords, vectors=vectors, stored=stored)
 
 
-def mount(node, index: str, seg) -> None:
-    """Swap the index's only shard onto ``seg``."""
-    eng = node.indices_service.get(index).shards[0]
-    with eng._lock:
-        eng._segments = [seg]
-        eng._epoch += 1
+def mount(node, index: str, segs: List) -> None:
+    """Swap each primary shard ``s`` of the index onto ``segs[s]``."""
+    shards = node.indices_service.get(index).shards
+    if len(shards) != len(segs):
+        raise ValueError(f"{index!r} has {len(shards)} shards, "
+                         f"{len(segs)} segments to mount")
+    for eng, seg in zip(shards, segs):
+        with eng._lock:
+            eng._segments = [seg]
+            eng._epoch += 1
 
 
 def fast_path(node):

@@ -8,6 +8,7 @@ import numpy as np
 
 from benchmark import peaks
 from benchmark import trace as trace_mod
+from benchmark.work import bm25
 
 
 def percentile_ms(values, q: float):
@@ -19,6 +20,12 @@ def percentile_ms(values, q: float):
 def ratio(ctx, num: str, den: str):
     d = ctx.delta(den)
     return ctx.delta(num) / d if d > 0 else None
+
+
+def mean_ms(ctx, histogram: str):
+    """Mean of a node histogram's observations (ms) in the window:
+    delta sum / delta count (harness.HISTOGRAMS)."""
+    return ratio(ctx, histogram + ".sum", histogram + ".count")
 
 
 def device_idle(ctx):
@@ -40,3 +47,11 @@ def roofline(ctx, work_mod, flops: float, nbytes: float):
         return None
     return 100.0 * peaks.least_seconds(flops, nbytes,
                                        ctx.device_kind) / spent
+
+
+def bm25_roofline(ctx, qs):
+    """The BM25 work of the queries ``qs`` (the real postings of their
+    terms) against the BM25 programs' device time in the window."""
+    postings = sum(ctx.body.postings(ctx.data, ctx.params, q) for q in qs)
+    flops, nbytes = bm25.work(postings, len(qs), ctx.params["size"])
+    return roofline(ctx, bm25, flops, nbytes)

@@ -4,11 +4,11 @@ every cell's comparison, and the reference in its own place passes it."""
 import pytest
 
 from benchmark import control
-from benchmark.tests.tiny import CLOSED, TINY
+from benchmark.tests.tiny import SHARDED, TINY
 
 
 @pytest.mark.parametrize("cell,overrides", [(c, TINY[c]) for c in sorted(
-    TINY)] + [CLOSED], ids=sorted(TINY) + ["closed-loop"])
+    TINY)] + [SHARDED], ids=sorted(TINY) + ["sharded"])
 def test_control_fails_and_reference_passes(cell, overrides):
     r = control.readings(cell, 424242424242, 2.0, dict(overrides))
     assert r["answers"] > 0

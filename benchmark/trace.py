@@ -112,7 +112,9 @@ def program_name(name: str) -> str:
 def reduce(planes: List[dict], lo: int, hi: int,
            top: int = 10) -> Optional[dict]:
     """Device metrics over the window [lo, hi) in trace nanoseconds.
-    None when the trace holds no device plane."""
+    None when the trace holds no device plane. Busy time is averaged
+    over every device plane and program time summed over them; the idle
+    gaps are those of the first device plane."""
     devices = [p for p in planes if p["name"].startswith(DEVICE_PREFIX)]
     if not devices:
         return None
