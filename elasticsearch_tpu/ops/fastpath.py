@@ -40,6 +40,10 @@ from elasticsearch_tpu.telemetry.engine import tracked_jit
 # bench (28 distinct filter pairs from an 8-filter pool) fragmented
 # cohorts to ~8-10 queries under the old 7-distinct-set launch budget —
 # the dominant share of its 31.7-qps collapse (VERDICT r3 item 2).
+# Row 0 may be skipped: the fast path registers only a segment with no
+# deletions and never rewrites row 0, so row 0 is False only on padded
+# docids, which no posting with tf > 0 holds. A cohort whose mask ids
+# are all 0 launches with masks=None, mask_ids=None (``_accept``).
 F_SLOTS = 32
 
 # covers docid-runs up to 2^5 = 32 postings — a query has ≤16 tokens
@@ -95,6 +99,18 @@ def _run_last_candidates(mk, x):
     return jnp.where(real_last, x, -jnp.inf), totals
 
 
+def _accept(tf, live_col, d):
+    """Lanes that score: tf > 0 and, where the cohort carries a mask,
+    the doc's entry in the query's mask row (None: no row to read)."""
+    keep = tf > 0.0
+    return keep if live_col is None else keep & jnp.take(live_col, d)
+
+
+def _live_col(masks, mid):
+    """The query's mask row, or None on an unmasked launch."""
+    return None if masks is None else jnp.take(masks, mid, axis=0)
+
+
 def _topk_total(block_docids, block_tfs, sel_blocks, sel_weights,
                 doc_lens, live_col, avg_len, k1: float, b: float, k: int):
     """Single query: (values [k], docids [k], total []) — sort by docid,
@@ -111,7 +127,7 @@ def _topk_total(block_docids, block_tfs, sel_blocks, sel_weights,
 
     dflat = d.reshape(-1)
     cflat = contrib.reshape(-1)
-    valid = (tf.reshape(-1) > 0.0) & jnp.take(live_col, dflat)
+    valid = _accept(tf.reshape(-1), live_col, dflat)
     dkey = jnp.where(valid, dflat, _SENTINEL)
     cflat = jnp.where(valid, cflat, jnp.asarray(0.0, dt))
 
@@ -415,8 +431,8 @@ def bm25_topk_total_merge_batch(
                         #   boundaries; slot = NB // n_slots blocks)
         sel_weights,    # rail-dtype [Q, NB]
         doc_lens,       # float32 [ND]
-        masks,          # bool [F_SLOTS, ND]
-        mask_ids,       # int32 [Q]
+        masks,          # bool [F_SLOTS, ND], or None (see F_SLOTS)
+        mask_ids,       # int32 [Q], or None with masks
         avg_len, n_slots: int, k1: float, b: float, k: int):
     """The v1 exact kernel with ONE substitution: the monolithic
     O(P·logP) ``lax.sort`` becomes the linear-work bitonic merge of the
@@ -435,13 +451,13 @@ def bm25_topk_total_merge_batch(
     dt = _score_dtype()
 
     def gather_one(s, w, mid):
-        live_col = jnp.take(masks, mid, axis=0)
+        live_col = _live_col(masks, mid)
         d = jnp.take(block_docids, s, axis=0)
         tf = jnp.take(block_tfs, s, axis=0).astype(dt)
         dl = jnp.take(doc_lens, d).astype(dt)
         contrib = bm25_contrib(w.astype(dt), tf, dl,
                                jnp.asarray(avg_len, dt), k1, b)
-        contrib = jnp.where((tf > 0.0) & jnp.take(live_col, d),
+        contrib = jnp.where(_accept(tf, live_col, d),
                             contrib, jnp.asarray(0.0, dt))
         key = jnp.where(tf > 0.0, d, _SENTINEL)
         return key.reshape(-1), contrib.reshape(-1)
@@ -606,16 +622,16 @@ def bm25_topk_total_batch(block_docids,   # int32 [TB, B]
                           sel_blocks,     # int32 [Q, NB]
                           sel_weights,    # float32 [Q, NB]
                           doc_lens,       # float32 [ND]
-                          masks,          # bool [F_SLOTS, ND]
+                          masks,          # bool [F_SLOTS, ND], or None
                           mask_ids,       # int32 [Q] row into masks
                           avg_len, k1: float, b: float, k: int):
     """Cohort launch → ONE packed float32 [Q, 2k+1]:
     ``row = [values (k) | docids bitcast to f32 (k) | total bitcast (1)]``.
-    Ints ride as float CASTS (exact < 2^24 — see ops/plan.pack_result)."""
+    Ints ride as float CASTS (exact < 2^24 — see ops/plan.pack_result).
+    ``masks=None, mask_ids=None`` reads no mask row (see F_SLOTS)."""
     def one(s, w, mid):
-        live_col = jnp.take(masks, mid, axis=0)
         return _topk_total(block_docids, block_tfs, s, w, doc_lens,
-                           live_col, avg_len, k1, b, k)
+                           _live_col(masks, mid), avg_len, k1, b, k)
 
     vals, ids, totals = jax.vmap(one)(sel_blocks, sel_weights, mask_ids)
     ids_f = ids.astype(jnp.float32)

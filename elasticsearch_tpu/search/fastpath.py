@@ -126,6 +126,15 @@ def enable_compile_cache():
             PersistentKernelCache(os.path.join(cur, "keys")))
 
 
+def _mask_args(stack, mask_ids):
+    """(masks, mask_ids) for a v2m or v1 launch: (None, None) when no
+    query of the cohort reads a filter row, so the kernel skips the
+    per-posting mask gather (ops/fastpath.py F_SLOTS)."""
+    if mask_ids.any():
+        return stack, mask_ids
+    return None, None
+
+
 class FastPathServer:
     # v2 kernel term-slot count (= MAX_TERMS: every instance gets >= 1
     # slot); a bucket's slot width is bucket // N_SLOTS blocks
@@ -190,8 +199,10 @@ class FastPathServer:
         # errors: launches, warm compiles, registrations and drain
         # passes that raised (bounces for an error land here; plain
         # `bounced` also counts by-design handoffs to the Python path)
-        self.stats = {"cohorts": 0, "fast_queries": 0, "bounced": 0,
-                      "errors": 0,
+        # unmasked_cohorts: launches whose cohort carried no filter row,
+        # so the kernel read no mask (ops/fastpath.py F_SLOTS)
+        self.stats = {"cohorts": 0, "unmasked_cohorts": 0,
+                      "fast_queries": 0, "bounced": 0, "errors": 0,
                       # θ-cache (essential-lane admission) counters —
                       # the engine-stats `caches.theta` surface
                       "theta_hits": 0, "theta_misses": 0,
@@ -231,6 +242,11 @@ class FastPathServer:
         self.cohort_hist[b] = self.cohort_hist.get(b, 0) + 1
         self.pad_rows += b - n
         self.used_rows += n
+
+    def _count_launch(self, masks):
+        self.stats["cohorts"] += 1
+        if masks is None:
+            self.stats["unmasked_cohorts"] += 1
 
     def serving_stats(self) -> dict:
         """Routing/dispatch telemetry of the serving front: per-lane ×
@@ -553,6 +569,8 @@ class FastPathServer:
         (XLA parallelizes across shapes — 4 serving shapes compile in
         the wall time of the slowest one) and land in the persistent
         compile cache, so a warm machine pays seconds, not minutes."""
+        from functools import partial
+
         import jax.numpy as jnp
 
         from elasticsearch_tpu.ops.fastpath import (
@@ -561,8 +579,8 @@ class FastPathServer:
             bm25_topk_total_batch, bm25_topk_total_merge_batch)
         dp, dev = reg["dp"], reg["dev"]
         masks = jnp.stack([dev.live] * F_SLOTS)
-        # cache the all-plain stack: the common no-filter cohort reuses
-        # it instead of re-stacking the live columns per launch
+        # the persistent stack's first state (_resolve_mask_rows): row 0
+        # is the live column and stays so; filter sets take rows 1..
         reg["plain_masks"] = masks
         mask_ids = np.zeros(self.q_batch, np.int32)
         wd = self._weight_dtype()
@@ -570,17 +588,19 @@ class FastPathServer:
                       if self.kernel_mode not in ("v2", "v2m")
                       else self.nb_buckets[-1:])
 
-        def warm_v2(nb):
+        def warm_v2(nb, plain=False):
             if not self._running:
                 return "skipped (stopping)"
             sel = np.full((self.q_batch, nb), dp.zero_block, np.int32)
             if self.kernel_mode == "v2m":
                 ws = np.zeros((self.q_batch, nb), wd)
+                mk, mi = (None, None) if plain else (masks, mask_ids)
                 bm25_topk_total_merge_batch(
                     dp.block_docids, dp.block_tfs, sel, ws,
-                    dp.doc_lens, masks, mask_ids, wd(dp.avg_len),
+                    dp.doc_lens, mk, mi, wd(dp.avg_len),
                     self.N_SLOTS, reg["k1"], reg["b"],
                     self.max_k).block_until_ready()
+                return f"v2m NB={nb}" + (" unmasked" if plain else "")
             else:
                 ws32 = np.zeros((self.q_batch, nb), np.float32)
                 bm25_candidates_rerank_batch(
@@ -594,17 +614,18 @@ class FastPathServer:
                     self.max_k).block_until_ready()
             return f"{self.kernel_mode} NB={nb}"
 
-        def warm_v1(nb):
+        def warm_v1(nb, plain=False):
             if not self._running:
                 return "skipped (stopping)"
             sel = np.full((self.q_batch, nb), dp.zero_block, np.int32)
             ws = np.zeros((self.q_batch, nb), wd)
             bd, bt, s_, w_, dl, mk, mi = self._v1_inputs(
-                reg, sel, ws, masks, mask_ids)
+                reg, sel, ws, *((None, None) if plain
+                                else (masks, mask_ids)))
             bm25_topk_total_batch(
                 bd, bt, s_, w_, dl, mk, mi, wd(dp.avg_len), reg["k1"],
                 reg["b"], self.max_k).block_until_ready()
-            return f"v1 NB={nb}" + (
+            return f"v1 NB={nb}" + (" unmasked" if plain else "") + (
                 " (mesh)" if reg.get("rmesh") is not None else "")
 
         def warm_ess_dense(nb):
@@ -638,12 +659,18 @@ class FastPathServer:
                 self.max_k).block_until_ready()
             return f"ess NB={nb}"
 
+        # v2m and v1 each warm an unmasked program beside the masked
+        # one: a cohort with no filter row launches without the mask
+        # (_mask_args), one with a filter row with it
         jobs = []
         for nb in (self.nb_buckets if self.kernel_mode in ("v2", "v2m")
                    else ()):
             jobs.append((warm_v2, nb))
+            if self.kernel_mode == "v2m":
+                jobs.append((partial(warm_v2, plain=True), nb))
         for nb in v1_buckets:
             jobs.append((warm_v1, nb))
+            jobs.append((partial(warm_v1, plain=True), nb))
         # warm EXACTLY the essential kernels the router can reach
         # (warming fewer reintroduces the round-2 serve-time compile
         # stall; warming more burns startup on dead code):
@@ -979,14 +1006,15 @@ class FastPathServer:
                         tl[qi, :] = 0
                         continue
                     mask_ids[qi] = row
-        masks = stack
         k_static = self.max_k
         if v2m:
+            masks, mids = _mask_args(stack, mask_ids)
             kernel, args = bm25_topk_total_merge_batch, (
                 dp.block_docids, dp.block_tfs, sel, ws, dp.doc_lens,
-                masks, mask_ids, self._weight_dtype()(dp.avg_len),
+                masks, mids, self._weight_dtype()(dp.avg_len),
                 self.N_SLOTS, reg["k1"], reg["b"], k_static)
         else:
+            masks = stack
             kernel, args = bm25_candidates_rerank_batch, (
                 dp.block_docids, dp.block_tfs, reg["flat_docids"],
                 reg["flat_tfs"], sel, ws, dp.doc_lens, masks, mask_ids,
@@ -995,7 +1023,7 @@ class FastPathServer:
         out, t_done = self._launch_cohort(
             "search.fastpath.v2_cohort", items, arrived, True, kernel,
             *args)
-        self.stats["cohorts"] += 1
+        self._count_launch(masks)
         self.stats["v2_queries"] = self.stats.get("v2_queries", 0) + q
         no_match_set = set(no_match)
         refire: list = []
@@ -1137,13 +1165,13 @@ class FastPathServer:
                     mask_ids[qi] = row
         k_static = self.max_k
         bd, bt, sel_m, ws_m, dl, mk, mi = self._v1_inputs(
-            reg, sel, ws, stack, mask_ids)
+            reg, sel, ws, *_mask_args(stack, mask_ids))
         out, t_done = self._launch_cohort(
             "search.fastpath.truncated_cohort", items, arrived, True,
             bm25_topk_total_batch, bd, bt, sel_m, ws_m, dl, mk, mi,
             self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
             k_static)
-        self.stats["cohorts"] += 1
+        self._count_launch(mk)
         if self._mesh_active(reg):
             self.stats["mesh_cohorts"] = \
                 self.stats.get("mesh_cohorts", 0) + 1
@@ -1459,7 +1487,7 @@ class FastPathServer:
             kernel, *args)
         idx_b = reg["index"].encode()
         h = self.front.h
-        self.stats["cohorts"] += 1
+        self._count_launch(masks)
         self.stats["ess_queries"] = self.stats.get("ess_queries", 0) \
             + len(items)
         bad_set = set(bad)
@@ -1613,7 +1641,9 @@ class FastPathServer:
         replicated handles (cached by identity — the mask stack
         re-replicates only when a filter row actually changed), the
         per-query rows shard P("replica"). ONE compile signature per
-        bucket either way (warm and serve both come through here)."""
+        bucket and mask variant either way (warm and serve both come
+        through here); an unmasked launch passes stack and mask_ids as
+        None (``_mask_args``)."""
         dp = reg["dp"]
         rmesh = reg.get("rmesh")
         mb = self.mesh_backend
@@ -1625,8 +1655,9 @@ class FastPathServer:
                 mb.shard_rows(rmesh, sel),
                 mb.shard_rows(rmesh, ws),
                 mb.replicated(rmesh, dp.doc_lens),
-                mb.replicated(rmesh, stack),
-                mb.shard_rows(rmesh, mask_ids))
+                None if stack is None else mb.replicated(rmesh, stack),
+                None if mask_ids is None else mb.shard_rows(rmesh,
+                                                            mask_ids))
 
     def _launch_group(self, reg, bucket, items, arrived, stack, rows,
                       first=True):
@@ -1661,13 +1692,13 @@ class FastPathServer:
                     mask_ids[qi] = row
         k_static = self.max_k
         bd, bt, sel_m, ws_m, dl, mk, mi = self._v1_inputs(
-            reg, sel, ws, stack, mask_ids)
+            reg, sel, ws, *_mask_args(stack, mask_ids))
         out, t_done = self._launch_cohort(
             "search.fastpath.v1_cohort", items, arrived, first,
             bm25_topk_total_batch, bd, bt, sel_m, ws_m, dl, mk, mi,
             self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
             k_static)
-        self.stats["cohorts"] += 1
+        self._count_launch(mk)
         if self._mesh_active(reg):
             self.stats["mesh_cohorts"] = \
                 self.stats.get("mesh_cohorts", 0) + 1

@@ -40,19 +40,39 @@ def _shape(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+_V2M = {}
+
+
+def _v2m(sharding, nb, masked=True):
+    """The v2m program for a cohort of Q at ``nb`` blocks, with the mask
+    stack or without it (a cohort with no filter row), compiled once per
+    module: each takes ~40 s here."""
+    from elasticsearch_tpu.ops import fastpath
+    if (nb, masked) not in _V2M:
+        S = lambda shape, dt: _shape(sharding, shape, dt)  # noqa: E731
+        mask = ((S((fastpath.F_SLOTS, ND), jnp.bool_), S((Q,), jnp.int32))
+                if masked else (None, None))
+        _V2M[nb, masked] = \
+            fastpath.bm25_topk_total_merge_batch.__wrapped_jit__.lower(
+                S((TB, B), jnp.int32), S((TB, B), jnp.float32),
+                S((Q, nb), jnp.int32), S((Q, nb), jnp.float32),
+                S((ND,), jnp.float32), *mask, S((), jnp.float32),
+                n_slots=16, k1=1.2, b=0.75, k=K).compile()
+    return _V2M[nb, masked]
+
+
+def _bytes_accessed(compiled):
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    return cost["bytes accessed"]
+
+
 def test_v2m_merge_kernel_compiles_to_a_tpu_custom_call(one_chip,
                                                         monkeypatch):
-    from elasticsearch_tpu.ops import fastpath, merge
+    from elasticsearch_tpu.ops import merge
     # _interpret() reads jax.devices(), which is the CPU here
     monkeypatch.setattr(merge, "_interpret", lambda: False)
-    S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
-    nb = 1024
-    compiled = fastpath.bm25_topk_total_merge_batch.__wrapped_jit__.lower(
-        S((TB, B), jnp.int32), S((TB, B), jnp.float32),
-        S((Q, nb), jnp.int32), S((Q, nb), jnp.float32),
-        S((ND,), jnp.float32), S((fastpath.F_SLOTS, ND), jnp.bool_),
-        S((Q,), jnp.int32), S((), jnp.float32),
-        n_slots=16, k1=1.2, b=0.75, k=K).compile()
+    compiled = _v2m(one_chip, 1024)
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
 
@@ -68,18 +88,27 @@ def test_v2m_carries_contributions_through_the_merge(one_chip,
     from elasticsearch_tpu.ops import fastpath, merge
     monkeypatch.setattr(merge, "_interpret", lambda: False)
     assert fastpath.merge_payload() == "contrib"
-    S = lambda shape, dt: _shape(one_chip, shape, dt)  # noqa: E731
-    nb = 2048
-    compiled = fastpath.bm25_topk_total_merge_batch.__wrapped_jit__.lower(
-        S((TB, B), jnp.int32), S((TB, B), jnp.float32),
-        S((Q, nb), jnp.int32), S((Q, nb), jnp.float32),
-        S((ND,), jnp.float32), S((fastpath.F_SLOTS, ND), jnp.bool_),
-        S((Q,), jnp.int32), S((), jnp.float32),
-        n_slots=16, k1=1.2, b=0.75, k=K).compile()
-    cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, list) else cost
-    assert cost["bytes accessed"] < 75e9
+    compiled = _v2m(one_chip, 2048)
+    assert _bytes_accessed(compiled) < 75e9
     assert compiled.memory_analysis().temp_size_in_bytes < 3e8
+
+
+def test_v2m_without_a_filter_row_reads_no_mask(one_chip, monkeypatch):
+    """A cohort with no filter row launches v2m with masks=None: the
+    program takes no pred[F_SLOTS, ND] stack and gathers no pred at all.
+    At NB 2048 the compiler counts ~54.6e9 bytes accessed with the mask
+    (the stack's rows copied out per query, then gathered per posting)
+    and ~40.4e9 without it: the mask accounts for ~14.2e9. The bound
+    asks for 10e9 of that drop."""
+    from elasticsearch_tpu.ops import merge
+    monkeypatch.setattr(merge, "_interpret", lambda: False)
+    masked, plain = _v2m(one_chip, 2048), _v2m(one_chip, 2048, False)
+    assert "pred[32,2000896]" in masked.as_text()
+    text = plain.as_text()
+    assert "pred[32,2000896]" not in text
+    assert not [ln for ln in text.splitlines()
+                if " gather(" in ln and "= pred[" in ln]
+    assert _bytes_accessed(plain) < _bytes_accessed(masked) - 10e9
 
 
 def test_knn_nominate_compiles_over_a_bf16_slab(one_chip):

@@ -199,3 +199,65 @@ def test_profile_breakdown_stages():
             assert shard["fetch"]["time_in_nanos"] > 0
         finally:
             node.close()
+
+
+def _padded_corpus(seed):
+    """Block postings over N_DOCS real docs in an ND-wide padded doc
+    space, as a registered segment holds them: ``live`` is False only
+    on the padded docids, and the only lanes that point there carry
+    tf 0 (block tails)."""
+    n_docs = ND - 96
+    rng = np.random.default_rng(seed)
+    bd = np.sort(rng.integers(0, n_docs, (TB, B)).astype(np.int32), axis=1)
+    bt = rng.integers(1, 4, (TB, B)).astype(np.float32)
+    tail = rng.random(TB) < 0.3
+    bd[tail, -2:] = ND - 1
+    bt[tail, -2:] = 0.0
+    lens = rng.integers(5, 60, ND).astype(np.float32)
+    live = np.arange(ND) < n_docs
+    return bd, bt, lens, live
+
+
+def _plain_cohort(seed):
+    """Four queries drawn from ``seed``, the second selecting one block
+    twice (a duplicated term), then a padded row and a row the host
+    zeroed for an unknown filter term: both all zero block (TB)."""
+    rng = np.random.default_rng(100 + seed)
+    sels, wss = [], []
+    for qi in range(4):
+        nsel = int(rng.integers(2, 12))
+        sel = np.full(16, TB, np.int32)
+        ws = np.zeros(16, np.float32)
+        sel[:nsel] = rng.choice(TB, nsel, replace=False)
+        ws[:nsel] = rng.uniform(0.3, 2.5, nsel).astype(np.float32)
+        if qi == 1:
+            sel[nsel], ws[nsel] = sel[0], ws[0]
+        sels.append(sel)
+        wss.append(ws)
+    for _ in range(2):
+        sels.append(np.full(16, TB, np.int32))
+        wss.append(np.zeros(16, np.float32))
+    return np.stack(sels), np.stack(wss)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_unmasked_launch_matches_the_plain_stack_bit_for_bit(seed):
+    """masks=None, mask_ids=None (a cohort with no filter row) gives
+    the packed result of the masked program over a stack of live
+    columns, bit for bit: every lane the mask would drop already has
+    tf 0."""
+    bd, bt, lens, live = _padded_corpus(seed)
+    sels, wss = _plain_cohort(seed)
+    bd = np.concatenate([bd, np.zeros((1, B), np.int32)])   # zero block
+    bt = np.concatenate([bt, np.zeros((1, B), np.float32)])
+    masks = jnp.stack([jnp.asarray(live)] * F_SLOTS)
+    args = (bd, bt, sels, wss, lens)
+    masked = np.asarray(bm25_topk_total_batch(
+        *args, masks, np.zeros(len(sels), np.int32), np.float32(30.0),
+        1.2, 0.75, K))
+    plain = np.asarray(bm25_topk_total_batch(
+        *args, None, None, np.float32(30.0), 1.2, 0.75, K))
+    assert np.array_equal(masked.view(np.uint32), plain.view(np.uint32))
+    # the dropped rows answer nothing, the others something
+    totals = unpack_ids(plain[:, 2 * K])
+    assert (totals[:4] > 0).all() and (totals[4:] == 0).all()

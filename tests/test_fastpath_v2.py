@@ -280,3 +280,146 @@ def test_v2_mass_ties_refuse_certificate():
     k = 50
     ok = int(np.asarray(out2[0][2 * k + 1], np.float32).astype(np.int32))
     assert ok == 0
+
+
+PAD_DOCS = 96     # padded docids past the segment's last real doc
+
+
+def _padded(seg, n_docs):
+    """Doc lengths and the live column over a padded doc space: live is
+    False only on the padded docids, which no posting with tf > 0 holds
+    (a registered segment has no deletions)."""
+    lens = np.concatenate([seg["lens"], np.ones(PAD_DOCS, np.float32)])
+    return lens, np.arange(n_docs + PAD_DOCS) < n_docs
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_v2m_unmasked_launch_matches_the_plain_stack_bit_for_bit(seed):
+    """masks=None, mask_ids=None (a cohort with no filter row) gives
+    the packed result of the masked v2m program over a stack of live
+    columns, bit for bit. The cohort holds the seed's queries, one with
+    a duplicated term, a padded row and a row the host zeroed for an
+    unknown filter term."""
+    import jax
+    n_docs, seg, queries = _v2m_case(seed)
+    queries = queries + [[queries[0][0]] * 2 + [queries[1][0]]]
+    wd = np.float64 if jax.config.jax_enable_x64 else np.float32
+    idf = np.log1p(n_docs / (seg["nb"] * BLOCK))
+    q_n, nb_bucket, n_slots = len(queries) + 2, 64, 8
+    sel = np.full((q_n, nb_bucket), seg["zero_block"], np.int32)
+    ws = np.zeros((q_n, nb_bucket), wd)
+    for qi, terms in enumerate(queries):
+        s, w, *_ = slotted_sel(seg, terms, idf, n_slots, nb_bucket)
+        sel[qi], ws[qi] = s, w
+    lens, live = _padded(seg, n_docs)
+    args = (seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws), lens)
+    tail = (wd(seg["avg"]), n_slots, 1.2, 0.75, 50)
+    masked = np.asarray(fp.bm25_topk_total_merge_batch(
+        *args, jnp.stack([jnp.asarray(live)] * fp.F_SLOTS),
+        jnp.zeros(q_n, jnp.int32), *tail))
+    plain = np.asarray(fp.bm25_topk_total_merge_batch(
+        *args, None, None, *tail))
+    assert np.array_equal(masked.view(np.uint32), plain.view(np.uint32))
+    totals = plain[:, 100].astype(np.int64)
+    assert (totals[:-2] > 0).all() and (totals[-2:] == 0).all()
+
+
+def _f64_topk(seg, terms, idf, lens, avg, keep, k):
+    """Exact float64 BM25 top-k of one query (score desc, docid asc)
+    over the docs ``keep`` admits, and its total."""
+    scores = np.zeros(len(lens), np.float64)
+    for t in terms:
+        s, n = int(seg["tbs"][t]), int(seg["nb"][t])
+        d = seg["bd"][s:s + n].reshape(-1)
+        tf = seg["bt"][s:s + n].reshape(-1).astype(np.float64)
+        hit = tf > 0
+        norm = 1.2 * (1 - 0.75 + 0.75 * lens[d[hit]].astype(np.float64)
+                      / avg)
+        np.add.at(scores, d[hit],
+                  float(idf[t]) * tf[hit] / (tf[hit] + norm))
+    scores[~keep] = 0.0
+    matched = np.nonzero(scores > 0)[0]
+    order = matched[np.lexsort((matched, -scores[matched]))][:k]
+    return order, scores[order], len(matched)
+
+
+def _registration(seg, n_docs):
+    """A FastPathServer and the registration state its v2m and v1
+    launch sites read, over ``seg``; answers land in ``answers``."""
+    from types import SimpleNamespace
+
+    from elasticsearch_tpu.search.fastpath import FastPathServer
+    srv = FastPathServer(None, SimpleNamespace(lib=None, h=None),
+                         nb_buckets=(64,), q_batch=8, max_k=50)
+    lens, live = _padded(seg, n_docs)
+    idf = np.log1p(n_docs / (seg["nb"] * BLOCK)).astype(np.float32)
+    dp = SimpleNamespace(block_docids=jnp.asarray(seg["bd"]),
+                         block_tfs=jnp.asarray(seg["bt"]),
+                         doc_lens=jnp.asarray(lens),
+                         avg_len=np.float32(seg["avg"]),
+                         zero_block=seg["zero_block"])
+    reg = {"dp": dp, "k1": 1.2, "b": 0.75, "idf": idf, "idf32": idf,
+           "nb": seg["nb"].astype(np.int64),
+           "starts": seg["tbs"].astype(np.int64),
+           "post_start": (seg["tbs"] * BLOCK).astype(np.int32),
+           "post_len": np.zeros(len(idf), np.int32),
+           "plain_masks": jnp.stack([jnp.asarray(live)] * fp.F_SLOTS),
+           "filter_live": {}, "rmesh": None}
+    answers = {}
+
+    def respond(reg, tok, v, d, k, total, took_ms, *rest):
+        answers[tok] = (v, d, total)
+    srv._respond_hits = respond
+    return srv, reg, answers, lens, live, idf
+
+
+@pytest.mark.parametrize("lane", ["v2m", "v1"])
+def test_plain_cohorts_launch_unmasked_and_filtered_ones_masked(
+        lane, monkeypatch):
+    """At the v2m and v1 launch sites a cohort with no filter row runs
+    the kernel with masks=None and counts in ``unmasked_cohorts``; a
+    cohort holding one filtered query among plain ones runs the masked
+    program with that query's row. Every answer is the float64
+    reference's."""
+    import time
+    rng = np.random.default_rng(4)
+    n_docs = 2000
+    seg = build_segment(rng, n_docs, n_terms=12)
+    srv, reg, answers, lens, live, idf = _registration(seg, n_docs)
+    launched = []
+    for name in ("bm25_topk_total_merge_batch", "bm25_topk_total_batch"):
+        def spy(*args, _real=getattr(fp, name), _name=name):
+            launched.append((_name, args[5], args[6]))
+            return _real(*args)
+        monkeypatch.setattr(fp, name, spy)
+    filt = (7,)
+    keep = live & (np.arange(len(live)) % 2 == 0)
+    reg["filter_live"][filt] = jnp.asarray(keep)
+    queries = [list(rng.choice(12, size=int(rng.integers(1, 5)),
+                               replace=False)) for _ in range(4)]
+    plain = [(tok, 50, q, ()) for tok, q in enumerate(queries)]
+    mixed = [(10 + tok, 50, q, filt if tok == 2 else ())
+             for tok, q in enumerate(queries)]
+    arrived = {it[0]: time.monotonic_ns() for it in plain + mixed}
+    launch = (srv._launch_group_v2 if lane == "v2m"
+              else srv._launch_group)
+    for cohort in (plain, mixed):
+        stack, rows = srv._resolve_mask_rows(reg, {it[3] for it in cohort})
+        launch(reg, 64, cohort, arrived, stack, rows)
+    kernel = ("bm25_topk_total_merge_batch" if lane == "v2m"
+              else "bm25_topk_total_batch")
+    (k0, m0, ids0), (k1, m1, ids1) = launched
+    assert k0 == k1 == kernel
+    assert m0 is None and ids0 is None
+    assert m1 is reg["mask_stack"]
+    assert np.asarray(ids1).tolist() == [0, 0, 1] + [0] * 5
+    assert srv.stats["cohorts"] == 2
+    assert srv.stats["unmasked_cohorts"] == 1
+    for tok, k, terms, f in plain + mixed:
+        want_ids, want_v, want_total = _f64_topk(
+            seg, terms, idf, lens, float(seg["avg"]),
+            keep if f else live, k)
+        v, d, total = answers[tok]
+        assert total == want_total, (tok, total, want_total)
+        np.testing.assert_array_equal(d, want_ids)
+        np.testing.assert_allclose(v, want_v, rtol=2e-6)

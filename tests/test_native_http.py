@@ -331,6 +331,61 @@ def test_delete_unregisters_fastpath(served):
     assert_equivalent(resp, dispatch(node, body))
 
 
+def test_unmasked_cohorts_rest_on_a_segment_without_deletions(served):
+    """A cohort with no filter row launches without the mask stack
+    (ops/fastpath.py F_SLOTS). That rests on the registered segment
+    having no deletions and on row 0 staying the live column: a
+    filtered cohort takes the masked program and leaves row 0 alone, a
+    delete and refresh drop the registration at the next check, and a
+    filter column is ANDed with the live column."""
+    node, port = served
+    fp = node._http.fastpath
+    reg = fp._reg
+    n = reg["segment"].n_docs
+    live = np.asarray(reg["dev"].live)
+    assert live[:n].all() and not live[n:].any()
+    plain = {"query": {"match": {"title": "fox gamma"}}, "size": 20,
+             "_source": False}
+    filtered = {"query": {"bool": {
+        "must": [{"match": {"title": "fox gamma"}}],
+        "filter": [{"match": {"title": "dog"}}]}},
+        "size": 20, "_source": False}
+    for body, unmasked in ((plain, 1), (filtered, 0)):
+        cohorts = fp.stats["cohorts"]
+        skipped = fp.stats["unmasked_cohorts"]
+        before = fast_count(node)
+        req(port, "POST", "/books/_search", body)
+        assert fast_count(node) == before + 1
+        assert fp.stats["cohorts"] == cohorts + 1
+        assert fp.stats["unmasked_cohorts"] == skipped + unmasked
+    assert np.array_equal(np.asarray(reg["mask_stack"][0]), live)
+    serving = node.rest_controller.dispatch("GET", "/_kernels", {},
+                                            None)[1]["serving"]
+    assert serving["counters"]["unmasked_cohorts"] == \
+        fp.stats["unmasked_cohorts"]
+
+    # doc "0" and a term it holds, as a filter that admits it
+    docid = list(reg["segment"].stored.ids).index("0")
+    flat = np.asarray(reg["dp"].host.block_docids).reshape(-1)
+    term = next(t for t in range(len(reg["post_len"]))
+                if docid in flat[reg["post_start"][t]:
+                                 reg["post_start"][t]
+                                 + reg["post_len"][t]])
+    req(port, "DELETE", "/books/_doc/0")
+    req(port, "POST", "/books/_refresh")
+    fp.refresh_registration()
+    assert fp._reg is None
+    idx = node.indices_service.indices["books"]
+    dev = idx.device_cache.get(idx.shards[0].segments[0])
+    assert not np.asarray(dev.live)[docid]
+    col = fp._filter_col(dict(reg, dev=dev, filter_live={}), (term,))
+    mask, _host = dev.composed_filter_mask(
+        [(reg["field"], (reg["dp"].host.terms[term],), False)])
+    assert np.asarray(mask)[docid] and not np.asarray(col)[docid]
+    assert np.array_equal(np.asarray(col),
+                          np.asarray(mask) & np.asarray(dev.live))
+
+
 def test_theta_cached_essential_lane(tmp_path):
     """Second run of an identical query takes the θ-cached essential
     MaxScore lane (small sort + per-candidate patching) and returns
