@@ -42,13 +42,9 @@ FUNNEL_MODULE = "ops/device.py"
 _NP_READBACK_CALLS = {"asarray", "array"}
 
 # Named allowlist: (path, enclosing function or None, rule id, reason).
-# Warmup and probe code synchronizes DELIBERATELY and discards the
-# result — there is no serving-path readback to attribute, and timing
-# the sync IS the point.
+# Warmup code synchronizes DELIBERATELY and discards the result —
+# there is no serving-path readback to attribute.
 READBACK_ALLOWLIST: List[Tuple[str, Optional[str], str, str]] = [
-    ("search/fastpath.py", "probe_regime", "ESTPU-RB01",
-     "one-shot attached-vs-slow-launch probe at boot; result "
-     "discarded"),
     ("search/fastpath.py", None, "ESTPU-RB02",
      "warmup compiles sync on purpose (block_until_ready measures "
      "readiness, results discarded); the serving loop reads back "

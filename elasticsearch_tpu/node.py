@@ -421,13 +421,6 @@ class Node:
                         n_streams=fast_streams, max_k=fast_max_k,
                         q_batch=int(self.settings.get(
                             "http.native.fast_q_batch", 32)),
-                        # "auto" times a trivial launch once
-                        # (slow_launch vs attached) and picks the
-                        # kernel/bucket ladder for it
-                        kernel_mode=str(self.settings.get(
-                            "http.native.fast_kernel", "auto")),
-                        dense_mb=int(self.settings.get(
-                            "http.native.fast_dense_mb", 1024)),
                         # oversize queries: impact-ordered truncation
                         # ("certified" | "always" | "off")
                         impact_mode=str(self.settings.get(

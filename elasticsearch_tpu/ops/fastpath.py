@@ -171,9 +171,7 @@ def _essential_phase1(block_docids, block_tfs, sel_blocks, sel_weights,
                       k1: float, b: float):
     """Exact scores over the ESSENTIAL union (the full kernel's sorted
     segmented-reduction at a smaller NB) → top-C candidates plus the
-    overflow bound. Shared by BOTH patch lanes (binary-search and
-    dense-table) so the exactness-critical candidate extraction has one
-    definition. Returns (cand_ids [C], ess [C], overflow_bound [])."""
+    overflow bound. Returns (cand_ids [C], ess [C], overflow_bound [])."""
     check_packed_id_limit(doc_lens.shape[0], "fastpath essential lane")
     dt = _score_dtype()
     d = jnp.take(block_docids, sel_blocks, axis=0)
@@ -203,7 +201,7 @@ def _essential_phase1(block_docids, block_tfs, sel_blocks, sel_weights,
 
 def _essential_epilogue(patched, cand_ids, overflow_bound, k: int):
     """Exact ordering over the candidate set + the on-device exactness
-    certificate — ONE definition for both patch lanes. Rank by the
+    certificate. Rank by the
     REPORTED float32 score with docid-ascending ties (the full kernel's
     contract), certify kth (full precision, min over the selected k so
     f32 rounding can't certify upward) STRICTLY beats the overflow
@@ -302,127 +300,6 @@ def bm25_essential_topk_batch(block_docids, block_tfs,
     return jnp.concatenate([vals, ids_f, ok_f[:, None]], axis=1)
 
 
-# ---------------------------------------------------------------------------
-# Dense-patch essential lane: the θ-warm fast lane for the slow-launch
-# regime (opportunistic on attached hardware — cohorts upgrade
-# to it when every NE term has a dense row, else the binary lane
-# below serves them).
-#
-# The binary-search patch phase above costs NE_SLOTS×21 DEPENDENT
-# gathers over the 47M-lane flat postings — fine when a gather is ~µs
-# on attached hardware, catastrophic in the slow-launch regime
-# where every dependent device op pays a sync (measured 862 ms/launch
-# vs 151 ms for the plain nb-256 kernel at 2M docs). But the
-# non-essential terms are BY CONSTRUCTION the high-df ones (MaxScore
-# splits on max contribution ≈ ascending idf), so a dense [H, ND]
-# tf table over the ~hundred hottest terms is small (f16, tf counts
-# are exact integers < 2048) and turns the whole patch into ONE flat
-# gather per NE slot: dense_tf[row*ND + cand_id]. Same certificate,
-# same exactness contract, ~20 ops instead of ~170 dependent gathers.
-# ---------------------------------------------------------------------------
-
-
-def _essential_dense_one(block_docids, block_tfs, dense_tf, sel_blocks,
-                         sel_weights, doc_lens, live_col,
-                         ne_row, ne_idf, ne_bound,
-                         avg_len, k1: float, b: float, k: int):
-    dt = _score_dtype()
-    nd = doc_lens.shape[0]
-    cand_ids, ess, overflow_bound = _essential_phase1(
-        block_docids, block_tfs, sel_blocks, sel_weights, doc_lens,
-        live_col, ne_bound, avg_len, k1, b)
-
-    # ---- phase 2: dense-table patch — one gather per NE slot
-    safe_ids = jnp.clip(cand_ids, 0, nd - 1)
-    cdl = jnp.take(doc_lens, safe_ids).astype(dt)
-    cnorm = k1 * (1.0 - b + b * cdl / jnp.asarray(avg_len, dt))
-    patched = jnp.where(jnp.isfinite(ess), ess,
-                        jnp.asarray(-jnp.inf, dt))
-    flat_dense = dense_tf.reshape(-1)
-    # flat-index dtype: int64 only exists under x64; with x64 off the
-    # BUILDER's h cap (search/fastpath.py _build_dense_hot) is the sole
-    # guarantee that rows*docs stays under 2^31 — keep it if you touch
-    # either side
-    idt = jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
-    for ti in range(NE_SLOTS):
-        row = ne_row[ti]                       # -1 ⇒ slot unused
-        srow = jnp.maximum(row, 0).astype(idt)
-        idx = srow * nd + safe_ids.astype(idt)
-        ptf = jnp.take(flat_dense, idx).astype(dt)
-        ptf = jnp.where(row >= 0, ptf, 0.0)
-        add = jnp.where(ptf > 0.0,
-                        ne_idf[ti].astype(dt) * ptf / (ptf + cnorm),
-                        0.0)
-        patched = jnp.where(jnp.isfinite(patched), patched + add,
-                            patched)
-
-    return _essential_epilogue(patched, cand_ids, overflow_bound, k)
-
-
-@tracked_jit(static_argnames=("k1", "b", "k"))
-def bm25_essential_dense_topk_batch(block_docids, block_tfs,
-                                    dense_tf,      # f16 [H, ND] hot-term tf
-                                    sel_blocks,    # int32 [Q, NBe]
-                                    sel_weights,   # rail [Q, NBe]
-                                    doc_lens, masks, mask_ids,
-                                    ne_row,        # int32 [Q, NE_SLOTS] row
-                                    ne_idf,        # rail [Q, NE_SLOTS]
-                                    ne_bound,      # rail [Q] Σ maxc_ne
-                                    avg_len, k1: float, b: float, k: int):
-    """θ-warm essential lane with the DENSE hot-term patch. Packing is
-    the binary-search lane's: float32 [Q, 2k+1] =
-    ``[values (k) | docids bitcast (k) | ok_flag bitcast (1)]``;
-    ok=0 rows refire on the full kernel."""
-    def one(s, w, mid, nr, ni, nb):
-        live_col = jnp.take(masks, mid, axis=0)
-        return _essential_dense_one(block_docids, block_tfs, dense_tf,
-                                    s, w, doc_lens, live_col,
-                                    nr, ni, nb, avg_len, k1, b, k)
-
-    vals, ids, ok = jax.vmap(one)(sel_blocks, sel_weights, mask_ids,
-                                  ne_row, ne_idf, ne_bound)
-    ids_f = ids.astype(jnp.float32)
-    ok_f = ok.astype(jnp.float32)
-    return jnp.concatenate([vals, ids_f, ok_f[:, None]], axis=1)
-
-
-# ---------------------------------------------------------------------------
-# v2 serving kernel: merge-based f32 candidates + exact f64 re-rank.
-#
-# Phase A replaces the monolithic O(P·logP) lax.sort with the
-# linear-work bitonic MERGE of per-term sorted runs (ops/merge.py,
-# measured 3.0x on chip) and runs entirely in float32 — sound because
-# phase A only nominates CANDIDATES. Phase B recomputes the top-C
-# candidates' scores EXACTLY in float64 (per-term binary search in the
-# flat postings — the essential-lane patch machinery generalized to all
-# terms) and re-ranks by (float32 score desc, docid asc), the same
-# contract as the v1 kernel. A device certificate proves no
-# non-candidate can reach the top k: every excluded doc's f32 score is
-# <= the (C+1)th candidate value, and the f32 pipeline's relative error
-# vs f64 is bounded by _F32_SLACK; failures (mass score-ties wider than
-# C — degenerate corpora) refire on the exact v1 kernel.
-# ---------------------------------------------------------------------------
-
-CAND_V2 = 4096      # candidates re-ranked exactly per query
-MAX_T = 16          # term-instance slots for the re-rank binary search
-# bound on the f32 phase-A pipeline's relative error vs exact f64:
-# ~5 ops per contribution + a <=4-level doubling-scan sum of <=16
-# positive terms keeps it well under 32*2^-24; 128*2^-24 adds margin
-_F32_SLACK = 128.0 * 2.0 ** -24
-
-
-def _stable_top_c(cand, mk, c):
-    """[Q, P] -> (ids [Q, c], bound [Q]): the c candidates with docid-
-    ascending tie order at the boundary (cand is docid-ordered so
-    cumulative tie rank = docid rank), plus the (c+1)th value — the
-    certificate's exclusion bound."""
-    def one(cand_q, mk_q):
-        _vals, ids, bound = _stable_topk(cand_q, mk_q, c,
-                                         bound_slot=True)
-        return ids, bound
-    return jax.vmap(one)(cand, mk)
-
-
 @tracked_jit(static_argnames=("n_slots", "k1", "b", "k"))
 def bm25_topk_total_merge_batch(
         block_docids,   # int32 [TB, B]
@@ -490,130 +367,6 @@ def bm25_topk_total_merge_batch(
     ids_f = ids.astype(jnp.float32)
     tot_f = totals.astype(jnp.float32)
     return jnp.concatenate([vals, ids_f, tot_f[:, None]], axis=1)
-
-
-@tracked_jit(static_argnames=("n_slots", "k1", "b", "k"))
-def bm25_candidates_rerank_batch(
-        block_docids,   # int32 [TB, B]
-        block_tfs,      # float32 [TB, B]
-        flat_docids,    # int32 [TB*B] block layout (re-rank search)
-        flat_tfs,       # float32 [TB*B]
-        sel_blocks,     # int32 [Q, NB] SLOTTED: each term-instance run
-                        #   starts on a slot boundary (NB/n_slots blocks)
-        sel_weights,    # float32 [Q, NB]
-        doc_lens,       # float32 [ND]
-        masks,          # bool [F_SLOTS, ND]
-        mask_ids,       # int32 [Q]
-        term_start,     # int32 [Q, MAX_T] flat posting offsets
-        term_len,       # int32 [Q, MAX_T]
-        term_idf,       # f64 (f32 when x64 off) [Q, MAX_T]
-        avg_len,        # f64 scalar (f32 when x64 off)
-        n_slots: int, k1: float, b: float, k: int):
-    """Cohort launch → packed float32 [Q, 2k+2]:
-    ``row = [values (k) | docids bitcast (k) | total bitcast |
-    ok bitcast]``. ok=0 rows are UNCERTIFIED (score-tie mass wider than
-    CAND_V2 at the boundary) — the caller refires them on the exact v1
-    kernel."""
-    from elasticsearch_tpu.ops.merge import merge_sorted_slots
-    Q, NB = sel_blocks.shape
-    B = block_docids.shape[1]
-    P = NB * B
-    L = P // n_slots
-    nd = doc_lens.shape[0]
-    dt = _score_dtype()
-    avg32 = jnp.asarray(avg_len, jnp.float32)
-
-    # ---- phase A: gather + f32 contributions, slot layout
-    def gather_one(s, w, mid):
-        live_col = jnp.take(masks, mid, axis=0)
-        d = jnp.take(block_docids, s, axis=0)          # [NB, B]
-        tf = jnp.take(block_tfs, s, axis=0)
-        dl = jnp.take(doc_lens, d)
-        norm = k1 * (1.0 - b + b * dl / avg32)
-        contrib = w[:, None] * jnp.where(tf > 0.0, tf / (tf + norm),
-                                         0.0)
-        # filtered/dead docs keep their KEY (slot stays sorted) but
-        # contribute 0 — the scan's x>0 drops them
-        contrib = jnp.where(jnp.take(live_col, d), contrib, 0.0)
-        key = jnp.where(tf > 0.0, d, _SENTINEL)
-        return key.reshape(-1), contrib.reshape(-1)
-
-    keys, cons = jax.vmap(gather_one)(sel_blocks, sel_weights, mask_ids)
-    mk, mv = merge_sorted_slots(keys.reshape(Q, n_slots, L),
-                                cons.reshape(Q, n_slots, L))
-
-    # ---- segmented sums (runs <= MAX_T=16 instances: 4 steps)
-    x = _doubling_scan(mk, mv, steps=(1, 2, 4, 8))
-    cand, totals = _run_last_candidates(mk, x)
-    cids, bound = _stable_top_c(cand, mk, CAND_V2)
-
-    # ---- phase B: exact f64 re-rank of the candidates
-    n_flat = flat_docids.shape[0]
-
-    # halving steps resolving any per-term posting range: df <= ND, so
-    # ceil(log2(ND))+1 steps always close the search (static in ND —
-    # tiny test corpora compile ~11 steps, the 2M bench 22)
-    n_steps = max(1, (nd - 1).bit_length()) + 1
-
-    def rerank_one(cq, mid, ts, tl, ti):
-        live_col = jnp.take(masks, mid, axis=0)
-        safe = jnp.clip(cq, 0, nd - 1)
-        dl = jnp.take(doc_lens, safe).astype(dt)
-        cnorm = k1 * (1.0 - b + b * dl / jnp.asarray(avg_len, dt))
-        score = jnp.zeros(CAND_V2, dt)
-        for t in range(MAX_T):
-            lo0 = ts[t]
-            ln = tl[t]
-            lo = jnp.full((CAND_V2,), lo0, jnp.int32)
-            hi = jnp.full((CAND_V2,), lo0 + ln, jnp.int32)
-            for _ in range(n_steps):
-                mid_ = (lo + hi) // 2
-                vdoc = jnp.take(flat_docids,
-                                jnp.clip(mid_, 0, n_flat - 1))
-                go_right = vdoc < cq
-                lo = jnp.where(go_right, mid_ + 1, lo)
-                hi = jnp.where(go_right, hi, mid_)
-            in_range = (lo < lo0 + ln) & (ln > 0)
-            at = jnp.clip(lo, 0, n_flat - 1)
-            found = in_range & (jnp.take(flat_docids, at) == cq)
-            ptf = jnp.where(found, jnp.take(flat_tfs, at).astype(dt),
-                            0.0)
-            score = score + jnp.where(
-                ptf > 0.0, ti[t].astype(dt) * ptf / (ptf + cnorm), 0.0)
-        valid = (cq != _SENTINEL) & jnp.take(live_col, safe) \
-            & (score > 0.0)
-        score = jnp.where(valid, score, jnp.asarray(-jnp.inf, dt))
-        disp = score.astype(jnp.float32)
-        neg = jnp.where(jnp.isfinite(disp), -disp,
-                        jnp.asarray(jnp.inf, jnp.float32))
-        tie = jnp.where(jnp.isfinite(disp), cq, _SENTINEL)
-        _n, sids, svals, sdt = jax.lax.sort(
-            (neg, tie, disp, score), num_keys=2)
-        out_vals = svals[:k]
-        out_ids = jnp.where(jnp.isfinite(out_vals), sids[:k],
-                            _SENTINEL)
-        kth = jnp.min(jnp.where(jnp.isfinite(out_vals), sdt[:k],
-                                jnp.asarray(jnp.inf, dt)))
-        kth = jnp.where(jnp.isfinite(out_vals[k - 1]), kth,
-                        jnp.asarray(-jnp.inf, dt))
-        return out_vals, out_ids, kth
-
-    vals, ids, kth = jax.vmap(rerank_one)(cids, mask_ids, term_start,
-                                          term_len, term_idf)
-
-    # certificate: every excluded doc's true score <= bound*(1+slack);
-    # also trivially certified when fewer than C+1 docs matched, or
-    # when the result has fewer than k hits (then ALL matches are
-    # candidates and bound is -inf)
-    bound_up = jnp.where(jnp.isfinite(bound),
-                         bound.astype(dt) * (1.0 + _F32_SLACK),
-                         jnp.asarray(-jnp.inf, dt))
-    ok = (bound_up < kth) | ~jnp.isfinite(bound)
-    ids_f = ids.astype(jnp.float32)
-    tot_f = totals.astype(jnp.float32)
-    ok_f = ok.astype(jnp.float32)
-    return jnp.concatenate([vals, ids_f, tot_f[:, None], ok_f[:, None]],
-                           axis=1)
 
 
 @tracked_jit(static_argnames=("k1", "b", "k"))

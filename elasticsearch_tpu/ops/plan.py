@@ -116,14 +116,13 @@ def _segsum(x: jax.Array, is_start: jax.Array) -> jax.Array:
             + (err - jnp.take(_prev(err, 0.0), start)))
 
 
-def _stable_topk(cand, keys, k: int, bound_slot: bool = False):
+def _stable_topk(cand, keys, k: int):
     """STABLE top-k of ``cand`` [P] with the exactness contract's tie
     order: ``cand`` is key-ascending-ordered, so keeping the FIRST ties
     at the kth value takes the LOWEST keys (Lucene/CPU-baseline
     semantics — TPU top_k alone breaks ties arbitrarily). Returns
-    (vals, ids) in cand's dtype; with ``bound_slot`` also the (k+1)th
-    value (the v2 certificate's exclusion bound)."""
-    vals1 = jax.lax.top_k(cand, k + 1 if bound_slot else k)[0]
+    (vals, ids) in cand's dtype."""
+    vals1 = jax.lax.top_k(cand, k)[0]
     kth = vals1[k - 1]
     gt = cand > kth
     eq = cand == kth
@@ -133,8 +132,6 @@ def _stable_topk(cand, keys, k: int, bound_slot: bool = False):
     vals, pos = jax.lax.top_k(cand2, k)
     ids = jnp.take(keys, pos)
     ids = jnp.where(jnp.isfinite(vals), ids, _SENTINEL)
-    if bound_slot:
-        return vals, ids, vals1[k]
     return vals, ids
 
 

@@ -287,16 +287,12 @@ KERNEL_ATTRIBUTION: Dict[str, str] = {
     # ops/fastpath.py — the native serving front's batched kernels
     "bm25_topk_total_batch": "launch",
     "bm25_essential_topk_batch": "launch",
-    "bm25_essential_dense_topk_batch": "launch",
     "bm25_topk_total_merge_batch": "launch",
-    "bm25_candidates_rerank_batch": "launch",
     # ops/vector.py
     "dot_scores": "score",
     "cosine_scores": "score",
     "l2_scores": "score",
     "knn_nominate_batch": "launch",
-    # ops/pallas_bm25.py
-    "bm25_contrib_pallas": "launch",
     # parallel/mesh_executor.py — mesh kNN SPMD programs
     "mesh_knn_nominate": "launch",
     "mesh_knn_step": "launch",

@@ -1,12 +1,6 @@
-"""Dense-patch essential lane (ops/fastpath.bm25_essential_dense_topk_batch):
-identical certified outputs to the binary-search patch lane and to the
-full exact v1 kernel, honest ok=0 when the certificate can't close.
-
-The dense lane exists for the slow-launch serving regime, where the
-binary-search patch's ~170 dependent gathers cost more than the full
-kernel they replace; its contract is the
-binary lane's, so the tests drive both through the same splits.
-"""
+"""θ-cached essential lane (ops/fastpath.bm25_essential_topk_batch):
+certified outputs identical to the full exact v1 kernel over all of the
+query's terms, honest ok=0 when the certificate can't close."""
 
 import numpy as np
 import pytest
@@ -61,16 +55,6 @@ def idf_of(seg, t):
     return float(np.log1p((n - df + 0.5) / (df + 0.5)))
 
 
-def dense_table(seg):
-    """[H, ND] exact tf rows for the hot terms (float16: counts < 2048)."""
-    h = seg["n_hot"]
-    dense = np.zeros((h, seg["n_docs"]), np.float16)
-    for t in range(h):
-        docs, tfs = seg["terms"][t]
-        dense[t, docs] = tfs
-    return dense
-
-
 def _bucket_for(seg, terms):
     need = int(sum(seg["nb"][t] for t in terms))
     nbk = 64
@@ -104,8 +88,8 @@ def full_v1(seg, ess_and_ne, k, masks=None, mask_id=0):
     return vals[order], ids[order], int(out[0, 2 * k:].astype(np.int32)[0])
 
 
-def run_lanes(seg, ess, ne, ne_bound, k, masks=None, mask_id=0):
-    """(binary_out, dense_out) for the same essential/NE split."""
+def run_essential(seg, ess, ne, ne_bound, k, masks=None, mask_id=0):
+    """The essential lane's packed output for one essential/NE split."""
     q = 1
     nbk = _bucket_for(seg, ess)
     sel = np.full((q, nbk), seg["zero_block"], np.int32)
@@ -119,26 +103,19 @@ def run_lanes(seg, ess, ne, ne_bound, k, masks=None, mask_id=0):
         pos += cnt
     ne_start = np.zeros((q, fp.NE_SLOTS), np.int32)
     ne_len = np.zeros((q, fp.NE_SLOTS), np.int32)
-    ne_row = np.full((q, fp.NE_SLOTS), -1, np.int32)
     ne_idf = np.zeros((q, fp.NE_SLOTS), np.float64)
     for i, t in enumerate(ne):
         ne_start[0, i] = int(seg["tbs"][t]) * BLOCK
         ne_len[0, i] = int(seg["dfs"][t])
-        ne_row[0, i] = t            # dense rows are the hot-term index
         ne_idf[0, i] = idf_of(seg, t)
     nbound = np.full(q, ne_bound, np.float64)
     if masks is None:
         masks = np.ones((fp.F_SLOTS, seg["n_docs"]), bool)
     mids = np.full(q, mask_id, np.int32)
-    binary = np.asarray(fp.bm25_essential_topk_batch(
+    return np.asarray(fp.bm25_essential_topk_batch(
         seg["bd"], seg["bt"], seg["flat_d"], seg["flat_t"], sel, ws,
         seg["lens"], masks, mids, ne_start, ne_len, ne_idf, nbound,
         np.float64(seg["avg"]), K1, B, k))
-    dense = np.asarray(fp.bm25_essential_dense_topk_batch(
-        seg["bd"], seg["bt"], dense_table(seg), sel, ws,
-        seg["lens"], masks, mids, ne_row, ne_idf, nbound,
-        np.float64(seg["avg"]), K1, B, k))
-    return binary, dense
 
 
 def unpack(out, k):
@@ -149,7 +126,7 @@ def unpack(out, k):
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
-def test_dense_matches_binary_and_full(seed):
+def test_essential_matches_full(seed):
     rng = np.random.default_rng(seed)
     seg = build_segment(rng)
     k = 10
@@ -161,47 +138,45 @@ def test_dense_matches_binary_and_full(seed):
     bound = idf_of(seg, 0) * float(
         (tfs / (tfs + norm_min)).max()) + 1e-9
     fv, fi, _ftot = full_v1(seg, query, k)
-    binary, dense = run_lanes(seg, ess, ne, bound, k)
-    bv, bi, bok = unpack(binary, k)
-    dv, di, dok = unpack(dense, k)
-    assert bok == dok
-    np.testing.assert_array_equal(bi, di)
-    np.testing.assert_allclose(bv, dv, rtol=0, atol=0)
-    if dok:
-        np.testing.assert_array_equal(di, fi)
-        np.testing.assert_allclose(dv, fv, rtol=0, atol=0)
+    ev, ei, eok = unpack(run_essential(seg, ess, ne, bound, k), k)
+    assert eok == 1, "the certificate closes on a small essential union"
+    np.testing.assert_array_equal(ei, fi)
+    np.testing.assert_allclose(ev, fv, rtol=0, atol=0)
 
 
-def test_dense_unused_slots_are_inert():
+def test_essential_unused_slots_are_inert():
     rng = np.random.default_rng(7)
     seg = build_segment(rng)
     k = 5
-    # no NE terms at all: both lanes degenerate to the essential union
-    binary, dense = run_lanes(seg, [2, 3], [], 0.0, k)
-    np.testing.assert_array_equal(binary, dense)
+    # no NE terms at all: the lane degenerates to the essential union,
+    # which is the full kernel over the same terms
+    ev, ei, eok = unpack(run_essential(seg, [2, 3], [], 0.0, k), k)
+    fv, fi, _ftot = full_v1(seg, [2, 3], k)
+    assert eok == 1
+    np.testing.assert_array_equal(ei, fi)
+    np.testing.assert_allclose(ev, fv, rtol=0, atol=0)
 
 
-def test_dense_respects_filter_mask():
+def test_essential_respects_filter_mask():
     rng = np.random.default_rng(11)
     seg = build_segment(rng)
     k = 5
     masks = np.ones((fp.F_SLOTS, seg["n_docs"]), bool)
     masks[3] = False
     masks[3, : seg["n_docs"] // 2] = True      # keep low half only
-    docs, tfs = seg["terms"][0]
     bound = idf_of(seg, 0) * 1.0 + 1e-9
-    binary, dense = run_lanes(seg, [2, 3], [0], bound, k,
-                              masks=masks, mask_id=3)
-    bv, bi, bok = unpack(binary, k)
-    dv, di, dok = unpack(dense, k)
-    assert bok == dok
-    np.testing.assert_array_equal(bi, di)
-    finite = np.isfinite(dv)
-    assert np.all(di[finite] < seg["n_docs"] // 2)
+    ev, ei, eok = unpack(run_essential(seg, [2, 3], [0], bound, k,
+                                       masks=masks, mask_id=3), k)
+    fv, fi, _ftot = full_v1(seg, [2, 3, 0], k, masks=masks, mask_id=3)
+    assert eok == 1
+    np.testing.assert_array_equal(ei, fi)
+    np.testing.assert_allclose(ev, fv, rtol=0, atol=0)
+    finite = np.isfinite(ev)
+    assert np.all(ei[finite] < seg["n_docs"] // 2)
 
 
-def test_dense_certificate_refuses_when_bound_wide():
-    """A huge Σ maxc_ne makes overflow_bound beat the kth — both lanes
+def test_essential_certificate_refuses_when_bound_wide():
+    """A huge Σ maxc_ne makes overflow_bound beat the kth — the lane
     must refuse (ok=0) instead of certifying a possibly-wrong top-k.
     The essential union must exceed CAND docs (otherwise every match is
     a candidate and the certificate closes trivially — correctly)."""
@@ -214,7 +189,5 @@ def test_dense_certificate_refuses_when_bound_wide():
         seg = build_segment(np.random.default_rng(
             int(rng.integers(1 << 30))), n_docs=nd, n_hot=2, n_rare=1)
     k = 10
-    binary, dense = run_lanes(seg, [0], [1], 1e6, k)
-    _bv, _bi, bok = unpack(binary, k)
-    _dv, _di, dok = unpack(dense, k)
-    assert bok == 0 and dok == 0
+    _ev, _ei, eok = unpack(run_essential(seg, [0], [1], 1e6, k), k)
+    assert eok == 0

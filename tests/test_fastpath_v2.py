@@ -1,5 +1,6 @@
-"""v2 serving kernel (merge candidates + f64 re-rank) vs the exact v1
-kernel: identical certified outputs, honest ok=0 on tie-mass corpora."""
+"""v2m serving kernel (the v1 exact kernel with its sort replaced by the
+bitonic merge of per-term slots) vs the exact v1 kernel, and the v2m and
+v1 launch sites of the fast path."""
 
 import numpy as np
 import pytest
@@ -16,7 +17,6 @@ def build_segment(rng, n_docs, n_terms, df_range=(40, 400)):
     """Block-layout postings like index/segment.py builds them."""
     tbs, nb = [], []
     blocks_d, blocks_t = [], []
-    flat_d, flat_t = [], []
     next_block = 0
     for t in range(n_terms):
         df = int(rng.integers(*df_range))
@@ -40,7 +40,6 @@ def build_segment(rng, n_docs, n_terms, df_range=(40, 400)):
     lens = rng.integers(5, 80, size=n_docs).astype(np.float32)
     return dict(bd=bd, bt=bt, tbs=np.asarray(tbs), nb=np.asarray(nb),
                 zero_block=bd.shape[0] - 1, lens=lens,
-                flat_d=bd.reshape(-1), flat_t=bt.reshape(-1),
                 avg=float(lens.mean()))
 
 
@@ -49,11 +48,8 @@ def slotted_sel(seg, term_ids, idf, n_slots, nb_bucket):
     slot_blocks = nb_bucket // n_slots
     sel = np.full(nb_bucket, seg["zero_block"], np.int32)
     ws = np.zeros(nb_bucket, np.float32)
-    ts = np.zeros(fp.MAX_T, np.int32)
-    tl = np.zeros(fp.MAX_T, np.int32)
-    ti = np.zeros(fp.MAX_T, np.float64)
     pos = 0
-    for i, t in enumerate(term_ids):
+    for t in term_ids:
         cnt = int(seg["nb"][t])
         start = int(seg["tbs"][t])
         need = -(-cnt // slot_blocks) * slot_blocks
@@ -61,10 +57,7 @@ def slotted_sel(seg, term_ids, idf, n_slots, nb_bucket):
         sel[pos:pos + cnt] = np.arange(start, start + cnt)
         ws[pos:pos + cnt] = np.float32(idf[t])
         pos += need
-        ts[i] = start * BLOCK
-        tl[i] = int((seg["bt"][start:start + cnt] > 0).sum())
-        ti[i] = idf[t]
-    return sel, ws, ts, tl, ti
+    return sel, ws
 
 
 def flat_sel(seg, term_ids, idf, nb_bucket):
@@ -80,39 +73,20 @@ def flat_sel(seg, term_ids, idf, nb_bucket):
     return sel, ws
 
 
-def run_both(seg, queries, n_docs=2000, k=50,
-             n_slots=8, nb_bucket=64):
-    q_n = len(queries)
-    idf = np.log1p(n_docs / (seg["nb"] * BLOCK))
-    masks = np.ones((fp.F_SLOTS, n_docs), bool)
-    mask_ids = np.zeros(q_n, np.int32)
-    sel2 = np.zeros((q_n, nb_bucket), np.int32)
-    ws2 = np.zeros((q_n, nb_bucket), np.float32)
-    ts2 = np.zeros((q_n, fp.MAX_T), np.int32)
-    tl2 = np.zeros((q_n, fp.MAX_T), np.int32)
-    ti2 = np.zeros((q_n, fp.MAX_T), np.float64)
-    sel1 = np.zeros((q_n, nb_bucket), np.int32)
-    ws1 = np.zeros((q_n, nb_bucket), np.float64)
-    for qi, terms in enumerate(queries):
-        s, w, ts, tl, ti = slotted_sel(seg, terms, idf, n_slots,
-                                       nb_bucket)
-        sel2[qi], ws2[qi], ts2[qi], tl2[qi], ti2[qi] = s, w, ts, tl, ti
-        s1, w1 = flat_sel(seg, terms, idf, nb_bucket)
-        sel1[qi], ws1[qi] = s1, w1
+def run_v1(seg, queries, n_docs, k=50, nb_bucket=64):
+    """The exact v1 kernel over the flat layout of ``queries``."""
     import jax
     wd = np.float64 if jax.config.jax_enable_x64 else np.float32
-    out1 = np.asarray(fp.bm25_topk_total_batch(
-        seg["bd"], seg["bt"], jnp.asarray(sel1), jnp.asarray(
-            ws1.astype(wd)),
-        seg["lens"], jnp.asarray(masks), jnp.asarray(mask_ids),
-        wd(seg["avg"]), 1.2, 0.75, k))
-    out2 = np.asarray(fp.bm25_candidates_rerank_batch(
-        seg["bd"], seg["bt"], seg["flat_d"], seg["flat_t"],
-        jnp.asarray(sel2), jnp.asarray(ws2), seg["lens"],
-        jnp.asarray(masks), jnp.asarray(mask_ids), jnp.asarray(ts2),
-        jnp.asarray(tl2), jnp.asarray(ti2.astype(wd)), wd(seg["avg"]),
-        n_slots, 1.2, 0.75, k))
-    return out1, out2
+    idf = np.log1p(n_docs / (seg["nb"] * BLOCK))
+    sel = np.zeros((len(queries), nb_bucket), np.int32)
+    ws = np.zeros((len(queries), nb_bucket), np.float64)
+    for qi, terms in enumerate(queries):
+        sel[qi], ws[qi] = flat_sel(seg, terms, idf, nb_bucket)
+    return np.asarray(fp.bm25_topk_total_batch(
+        seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws.astype(wd)),
+        seg["lens"], jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
+        jnp.asarray(np.zeros(len(queries), np.int32)), wd(seg["avg"]),
+        1.2, 0.75, k))
 
 
 def run_v2m(seg, queries, n_docs, k=50, n_slots=8, nb_bucket=64):
@@ -123,8 +97,8 @@ def run_v2m(seg, queries, n_docs, k=50, n_slots=8, nb_bucket=64):
     sel = np.zeros((len(queries), nb_bucket), np.int32)
     ws = np.zeros((len(queries), nb_bucket), wd)
     for qi, terms in enumerate(queries):
-        s, w, *_ = slotted_sel(seg, terms, idf, n_slots, nb_bucket)
-        sel[qi], ws[qi] = s, w
+        sel[qi], ws[qi] = slotted_sel(seg, terms, idf, n_slots,
+                                      nb_bucket)
     return np.asarray(fp.bm25_topk_total_merge_batch(
         seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws),
         seg["lens"], jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
@@ -138,56 +112,31 @@ def unpack1(row, k):
 
 def _norm_hits(vals, ids, k):
     """Canonical (score desc, docid asc) order for comparison — v1
-    leaves device tie order arbitrary (host re-sorts); v2 is already
-    contract-ordered."""
+    and v2m leave device tie order arbitrary (the host re-sorts)."""
     fin = np.isfinite(vals)
     v, d = vals[fin], ids[fin]
     order = np.lexsort((d, -v))
     return v[order], d[order]
 
 
-@pytest.mark.parametrize("seed", [0, 1, 2])
-def test_v2_matches_v1(seed):
-    rng = np.random.default_rng(seed)
-    n_docs = 2000
-    seg = build_segment(rng, n_docs, n_terms=12)
-    queries = [list(rng.choice(12, size=int(rng.integers(1, 6)),
-                               replace=False))
-               for _ in range(4)]
-    out1, out2 = run_both(seg, queries, n_docs=n_docs)
-    k = 50
-    for qi in range(len(queries)):
-        v1, d1, t1 = unpack1(out1[qi], k)
-        v2 = out2[qi][:k]
-        d2 = unpack_ids(out2[qi][k:2 * k])
-        t2 = int(out2[qi][2 * k])
-        ok = int(np.asarray(out2[qi][2 * k + 1],
-                            np.float32).astype(np.int32))
-        assert ok == 1, f"q{qi} uncertified on a benign corpus"
-        assert t1 == t2, (qi, t1, t2)
-        nv1, nd1 = _norm_hits(v1, d1, k)
-        nv2, nd2 = _norm_hits(v2, d2, k)
-        np.testing.assert_array_equal(nd1, nd2)
-        np.testing.assert_allclose(nv1, nv2, rtol=1e-6)
-
-
-def test_v2_duplicate_term_instances():
-    rng = np.random.default_rng(3)
-    seg = build_segment(rng, 1000, n_terms=6)
-    out1, out2 = run_both(seg, [[2, 2, 5], [0, 1, 2, 3, 4, 5]],
-                          n_docs=1000)
-    k = 50
-    for qi in range(2):
-        v1, d1, _ = unpack1(out1[qi], k)
-        v2 = out2[qi][:k]
-        d2 = unpack_ids(out2[qi][k:2 * k])
-        nv1, nd1 = _norm_hits(v1, d1, k)
-        nv2, nd2 = _norm_hits(v2, d2, k)
-        np.testing.assert_array_equal(nd1, nd2)
-        np.testing.assert_allclose(nv1, nv2, rtol=1e-6)
+def _mass_ties_segment():
+    """Degenerate corpus: one term matches every doc with tf 1 over a
+    uniform doc length, so every doc ties at the same score."""
+    n_docs = 8192
+    docs = np.arange(n_docs, dtype=np.int32)
+    nblk = n_docs // BLOCK
+    bd = np.concatenate([docs.reshape(nblk, BLOCK),
+                         np.zeros((1, BLOCK), np.int32)])
+    bt = np.concatenate([np.ones((nblk, BLOCK), np.float32),
+                         np.zeros((1, BLOCK), np.float32)])
+    return dict(bd=bd, bt=bt, tbs=np.asarray([0]), nb=np.asarray([nblk]),
+                zero_block=nblk, lens=np.full(n_docs, 10.0, np.float32),
+                avg=10.0)
 
 
 def _v2m_case(case):
+    if case == "mass_ties":
+        return 8192, _mass_ties_segment(), [[0]]
     if case == "dup_terms":
         rng = np.random.default_rng(3)
         return 1000, build_segment(rng, 1000, n_terms=6), \
@@ -200,13 +149,15 @@ def _v2m_case(case):
     return 2000, seg, queries
 
 
-@pytest.mark.parametrize("case", [0, 1, 2, "dup_terms"])
+@pytest.mark.parametrize("case", [0, 1, 2, "dup_terms", "mass_ties"])
 def test_v2m_matches_v1(case):
     """The merge kernel (v2m) answers as the monolithic-sort kernel (v1):
-    ids and exact totals equal, scores to rtol 1e-6 in contract order."""
+    ids and exact totals equal, scores to rtol 1e-6 in contract order.
+    On the mass-ties corpus both return the lowest docids and the exact
+    total."""
     n_docs, seg, queries = _v2m_case(case)
     k = 50
-    out1, _ = run_both(seg, queries, n_docs=n_docs, k=k)
+    out1 = run_v1(seg, queries, n_docs, k=k)
     outm = run_v2m(seg, queries, n_docs, k=k)
     for qi in range(len(queries)):
         v1, d1, t1 = unpack1(out1[qi], k)
@@ -216,6 +167,11 @@ def test_v2m_matches_v1(case):
         nvm, ndm = _norm_hits(vm, dm, k)
         np.testing.assert_array_equal(nd1, ndm)
         np.testing.assert_allclose(nv1, nvm, rtol=1e-6)
+    if case == "mass_ties":
+        _, dm, tm = unpack1(outm[0], k)
+        assert tm == n_docs
+        np.testing.assert_array_equal(_norm_hits(outm[0][:k], dm, k)[1],
+                                      np.arange(k))
 
 
 def test_v2_bucket_slot_fit_routing():
@@ -253,33 +209,12 @@ def test_v2_slotted_assembly_runs_stay_sorted():
     seg = build_segment(rng, 1500, n_terms=5, df_range=(100, 500))
     idf = np.log1p(1500 / (seg["nb"] * BLOCK))
     n_slots, nb_bucket = 8, 64
-    sel, ws, *_ = slotted_sel(seg, [0, 3, 4], idf, n_slots, nb_bucket)
+    sel, ws = slotted_sel(seg, [0, 3, 4], idf, n_slots, nb_bucket)
     d = seg["bd"][sel]              # [NB, B]
     tf = seg["bt"][sel]
     keys = np.where(tf > 0, d, 0x7FFFFFFF).reshape(n_slots, -1)
     for s in range(n_slots):
         assert np.all(np.diff(keys[s].astype(np.int64)) >= 0), s
-
-
-def test_v2_mass_ties_refuse_certificate():
-    """Degenerate corpus: every matching doc scores identically and the
-    tie class is far wider than CAND_V2 — v2 must set ok=0 (refire),
-    never emit a possibly-wrong certified result."""
-    n_docs = 8192
-    # one term matching EVERY doc with tf=1, uniform doc length
-    docs = np.arange(n_docs, dtype=np.int32)
-    nblk = n_docs // BLOCK
-    bd = np.concatenate([docs.reshape(nblk, BLOCK),
-                         np.zeros((1, BLOCK), np.int32)])
-    bt = np.concatenate([np.ones((nblk, BLOCK), np.float32),
-                         np.zeros((1, BLOCK), np.float32)])
-    seg = dict(bd=bd, bt=bt, tbs=np.asarray([0]), nb=np.asarray([nblk]),
-               zero_block=nblk, lens=np.full(n_docs, 10.0, np.float32),
-               flat_d=bd.reshape(-1), flat_t=bt.reshape(-1), avg=10.0)
-    out1, out2 = run_both(seg, [[0]], n_docs=n_docs, nb_bucket=64)
-    k = 50
-    ok = int(np.asarray(out2[0][2 * k + 1], np.float32).astype(np.int32))
-    assert ok == 0
 
 
 PAD_DOCS = 96     # padded docids past the segment's last real doc
@@ -309,8 +244,8 @@ def test_v2m_unmasked_launch_matches_the_plain_stack_bit_for_bit(seed):
     sel = np.full((q_n, nb_bucket), seg["zero_block"], np.int32)
     ws = np.zeros((q_n, nb_bucket), wd)
     for qi, terms in enumerate(queries):
-        s, w, *_ = slotted_sel(seg, terms, idf, n_slots, nb_bucket)
-        sel[qi], ws[qi] = s, w
+        sel[qi], ws[qi] = slotted_sel(seg, terms, idf, n_slots,
+                                      nb_bucket)
     lens, live = _padded(seg, n_docs)
     args = (seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws), lens)
     tail = (wd(seg["avg"]), n_slots, 1.2, 0.75, 50)
@@ -358,7 +293,7 @@ def _registration(seg, n_docs):
                          doc_lens=jnp.asarray(lens),
                          avg_len=np.float32(seg["avg"]),
                          zero_block=seg["zero_block"])
-    reg = {"dp": dp, "k1": 1.2, "b": 0.75, "idf": idf, "idf32": idf,
+    reg = {"dp": dp, "k1": 1.2, "b": 0.75, "idf": idf,
            "nb": seg["nb"].astype(np.int64),
            "starts": seg["tbs"].astype(np.int64),
            "post_start": (seg["tbs"] * BLOCK).astype(np.int32),

@@ -297,7 +297,10 @@ def test_replica_mesh_sizing(node):
 def test_fastpath_mesh_cohorts(tmp_path, monkeypatch):
     """ESTPU_FASTPATH_MESH=1: the native front's v1 cohorts launch
     replica-sharded over the mesh — responses match the Python path
-    (the native-front parity contract) and the dispatch counters tick."""
+    (the native-front parity contract) and the dispatch counters tick.
+    The query reaches v1 as a slot misfit: nine words in every one of
+    1,200 docs make 9 terms x 10 blocks, which fit bucket 128 but need
+    18 of its 16 slots."""
     import json
     import urllib.request
 
@@ -307,7 +310,6 @@ def test_fastpath_mesh_cohorts(tmp_path, monkeypatch):
     monkeypatch.setenv("ESTPU_FASTPATH_MESH", "1")
     n = Node(settings=Settings.from_dict({
         "http": {"native": {"fast_nb_buckets": "64,128",
-                            "fast_kernel": "v1",
                             "fast_max_k": 200}},
     }), data_path=str(tmp_path / "data"))
     try:
@@ -315,12 +317,13 @@ def test_fastpath_mesh_cohorts(tmp_path, monkeypatch):
         if not isinstance(n._http, native_http.NativeHttpFront):
             pytest.skip("native front slot unavailable")
         rng = np.random.default_rng(42)
+        common = VOCAB[:9]
         lines = []
-        for i in range(200):
+        for i in range(1200):
             lines.append(json.dumps({"index": {"_index": "books",
                                                "_id": str(i)}}))
             lines.append(json.dumps({"title": " ".join(
-                rng.choice(VOCAB, rng.integers(3, 10)))}))
+                common + list(rng.choice(VOCAB, rng.integers(3, 10))))}))
         data = ("\n".join(lines) + "\n").encode()
         r = urllib.request.Request(
             f"http://127.0.0.1:{port}/_bulk", data=data, method="POST",
@@ -335,7 +338,7 @@ def test_fastpath_mesh_cohorts(tmp_path, monkeypatch):
         assert fp.mesh_backend is n.search_service.mesh_executor
         assert fp._reg["rmesh"] is not None, \
             "registration must bind a replica mesh"
-        body = {"query": {"match": {"title": "amber dune"}},
+        body = {"query": {"match": {"title": " ".join(common)}},
                 "size": 20, "_source": False}
         before = n.search_service.mesh_executor.counters.get(
             "dispatch.replica", 0)

@@ -386,6 +386,65 @@ def test_unmasked_cohorts_rest_on_a_segment_without_deletions(served):
                           np.asarray(mask) & np.asarray(dev.live))
 
 
+def test_warm_ladder_compiles_exactly_the_routable_programs(
+        tmp_path, monkeypatch):
+    """start() compiles nothing; registering an index warms exactly the
+    programs the router can reach: v2m masked and unmasked at every nb
+    bucket, v1 masked and unmasked at the largest bucket (slot misfits,
+    θ-lane refires and the truncated lane run there) and the essential
+    lane at every ess bucket."""
+    from types import SimpleNamespace
+
+    from jax import monitoring
+
+    from elasticsearch_tpu.ops import fastpath as kernels
+    from elasticsearch_tpu.search.fastpath import FastPathServer
+    warmed = []
+    # (kernel, lane name, position of sel_blocks, position of masks)
+    for name, lane, i_sel, i_mask in (
+            ("bm25_topk_total_merge_batch", "v2m", 2, 5),
+            ("bm25_topk_total_batch", "v1", 2, 5),
+            ("bm25_essential_topk_batch", "ess", 4, 7)):
+        def spy(*args, _lane=lane, _s=i_sel, _m=i_mask):
+            warmed.append((_lane, args[_s].shape[1],
+                           "unmasked" if args[_m] is None else "masked"))
+            return SimpleNamespace(block_until_ready=lambda: None)
+        monkeypatch.setattr(kernels, name, spy)
+    compiles = []
+
+    def on_event(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    node = Node(settings=Settings.from_dict({
+        "http": {"native": {"fast_nb_buckets": "64,128"}},
+    }), data_path=str(tmp_path / "data"))
+    monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        port = node.start(0)
+    finally:
+        monitoring.unregister_event_duration_listener(on_event)
+    try:
+        assert compiles == [], "start() compiled before registration"
+        assert warmed == []
+        lines = []
+        for i in range(40):
+            lines.append(json.dumps({"index": {"_index": "books",
+                                               "_id": str(i)}}))
+            lines.append(json.dumps({"title": " ".join(WORDS[i % 5:])}))
+        req(port, "POST", "/_bulk", "\n".join(lines) + "\n", ndjson=True)
+        req(port, "POST", "/books/_refresh")
+        fp = node._http.fastpath
+        fp.refresh_registration()
+        assert fp._reg is not None
+        want = [("v2m", nb, m) for nb in (64, 128)
+                for m in ("masked", "unmasked")]
+        want += [("v1", 128, "masked"), ("v1", 128, "unmasked")]
+        want += [("ess", nb, "masked") for nb in FastPathServer.ESS_BUCKETS]
+        assert sorted(warmed) == sorted(want)
+    finally:
+        node.close()
+
+
 def test_theta_cached_essential_lane(tmp_path):
     """Second run of an identical query takes the θ-cached essential
     MaxScore lane (small sort + per-candidate patching) and returns
