@@ -43,7 +43,8 @@ FILTER_MASK_CACHE_MAX = 64
 # device-resident array of a DeviceSegment belongs to exactly one class,
 # so `sum(hbm_bytes_by_class().values()) == hbm_bytes()` by construction.
 HBM_SLAB_CLASSES = ("postings", "norms", "live_mask", "vectors",
-                    "doc_values", "ordinals", "filter_masks")
+                    "doc_values", "ordinals", "filter_masks",
+                    "posting_lens")
 
 # readback site -> its span name ("readback:<site>"), built once per site
 _READBACK_SPANS: Dict[str, str] = {}
@@ -204,8 +205,8 @@ class DeviceSegment:
                               f"DeviceSegment[{segment.name}]")
         self._device = device
         # backpressure sink (search/context.py DeviceSegmentCache):
-        # filter-mask builds charge the hbm breaker through it; None
-        # for standalone DeviceSegments outside a cache
+        # filter-mask and posting-length builds charge the hbm breaker
+        # through it; None for standalone DeviceSegments outside a cache
         self.hbm_sink = None
         # LRU filter-mask cache — the analogue of Lucene's LRUQueryCache
         # for filter clauses (ref: search/LRUQueryCache.java via
@@ -215,6 +216,9 @@ class DeviceSegment:
         # replace whole DeviceSegments) keeps entries valid for the
         # segment's lifetime.
         self._filter_masks: "OrderedDict[tuple, tuple]" = OrderedDict()
+        # field -> its postings' document lengths in block layout, built
+        # on first use (``posting_lens``)
+        self._posting_lens: Dict[str, jax.Array] = {}
         # BoundPlan cache (search/searcher.py): repeated queries reuse
         # their device-resident selection arrays — skipping bind_plan AND
         # the per-launch host→device uploads of the selections
@@ -307,14 +311,14 @@ class DeviceSegment:
         # same nbytes) — a trip here surfaces as a typed per-shard
         # circuit_breaking_exception the coordinator fails over, and
         # nothing lands in device memory past the limit
-        self._account_mask(int(mask.nbytes))
+        self._account_hbm(int(mask.nbytes))
         dev_mask = jax.device_put(mask, device=self._device)
         entry = (dev_mask, mask)
         self._filter_masks[key] = entry
         while len(self._filter_masks) > FILTER_MASK_CACHE_MAX:
             _k, (evicted, _h) = self._filter_masks.popitem(last=False)
             self.filter_mask_evictions += 1
-            self._account_mask(-int(evicted.nbytes))
+            self._account_hbm(-int(evicted.nbytes))
         return entry
 
     def composed_filter_mask(self, conversions) -> Tuple[jax.Array,
@@ -339,22 +343,43 @@ class DeviceSegment:
             _, hm = self.filter_mask(fname, terms)
             hm = ~hm if negate else hm
             host = hm.copy() if host is None else (host & hm)
-        self._account_mask(int(host.nbytes))
+        self._account_hbm(int(host.nbytes))
         dev_mask = jax.device_put(host, device=self._device)
         entry = (dev_mask, host)
         self._filter_masks[key] = entry
         while len(self._filter_masks) > FILTER_MASK_CACHE_MAX:
             _k, (evicted, _h) = self._filter_masks.popitem(last=False)
             self.filter_mask_evictions += 1
-            self._account_mask(-int(evicted.nbytes))
+            self._account_hbm(-int(evicted.nbytes))
         return entry
 
-    def _account_mask(self, delta: int) -> None:
-        """Charge/release device filter-mask bytes against the owning
-        cache's hbm breaker (no-op for standalone segments)."""
+    def posting_lens(self, field: str) -> jax.Array:
+        """float32 [TB + 1, B]: the document length of each posting of
+        ``field``, laid out as its block arrays (``[r, j]`` is the
+        length of doc ``block_docids[r, j]``), so a kernel reads the
+        lengths of its selected blocks as block rows beside their tfs
+        (ops/fastpath.py ``_postings``). Padding lanes hold the length
+        of the doc they point at, which no score reads (tf 0). Built on
+        the device once per immutable segment, on first use — the fast
+        path's registration, the one reader, so paths that never read
+        it never hold it — and charged like a filter mask."""
+        table = self._posting_lens.get(field)
+        if table is None:
+            dp = self.postings[field]
+            self._account_hbm(
+                dp.block_docids.size * dp.doc_lens.dtype.itemsize,
+                "posting_lens")
+            table = jnp.take(dp.doc_lens, dp.block_docids, mode="clip")
+            self._posting_lens[field] = table
+        return table
+
+    def _account_hbm(self, delta: int, label: str = "filter_mask") -> None:
+        """Charge/release device bytes built after admission (filter
+        masks, posting lengths) against the owning cache's hbm breaker
+        (no-op for standalone segments)."""
         sink = self.hbm_sink
         if sink is not None:
-            sink.account_filter_mask(self.name, delta)
+            sink.account_hbm(self.name, delta, label)
 
     def update_live(self, live: np.ndarray) -> None:
         """Re-upload only the live mask (deletes don't touch postings)."""
@@ -370,7 +395,9 @@ class DeviceSegment:
         columns (the analogue of Lucene's norms); ``doc_values`` the
         numeric columns + their missing masks; ``ordinals`` the lazy
         keyword ord-major permutations; ``filter_masks`` the LRU-cached
-        device filter columns (so eviction visibly RETURNS bytes)."""
+        device filter columns (so eviction visibly RETURNS bytes);
+        ``posting_lens`` the per-posting document lengths the fast path
+        registers (``posting_lens()``)."""
         out = dict.fromkeys(HBM_SLAB_CLASSES, 0)
         out["live_mask"] = int(self.live.nbytes)
         for dp in self.postings.values():
@@ -392,6 +419,8 @@ class DeviceSegment:
                 out["ordinals"] += int(entry[0].nbytes)
         for dev_mask, _host in self._filter_masks.values():
             out["filter_masks"] += int(dev_mask.nbytes)
+        for table in self._posting_lens.values():
+            out["posting_lens"] += int(table.nbytes)
         return out
 
     def hbm_bytes(self) -> int:

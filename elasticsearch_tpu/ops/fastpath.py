@@ -111,17 +111,41 @@ def _live_col(masks, mid):
     return None if masks is None else jnp.take(masks, mid, axis=0)
 
 
-def _topk_total(block_docids, block_tfs, sel_blocks, sel_weights,
-                doc_lens, live_col, avg_len, k1: float, b: float, k: int):
-    """Single query: (values [k], docids [k], total []) — sort by docid,
-    doubling segmented sum, top-k at run-last positions."""
-    # trace-time guard (shapes are static under jit): every serving
-    # kernel reads ids back float-packed, which is exact only < 2^24
-    check_packed_id_limit(doc_lens.shape[0], "fastpath kernel")
+def _postings(block_docids, block_tfs, block_lens, sel_blocks):
+    """One query's selected postings as block rows [NB, B]: (docids,
+    tfs, document lengths), the scores' inputs in the rail dtype.
+    ``block_lens`` is laid out as ``block_tfs`` (ops/device.py
+    ``DeviceSegment.posting_lens``), so the lengths come in the same
+    contiguous row read as the tfs: a gather of the per-doc lengths by
+    docid would be a random read per lane, the costliest op of a
+    launch."""
+    if block_lens.shape != block_tfs.shape:
+        raise ValueError(f"block_lens {block_lens.shape} is not laid out "
+                         f"as block_tfs {block_tfs.shape}")
     dt = _score_dtype()
-    d = jnp.take(block_docids, sel_blocks, axis=0)       # [NB, B]
+    d = jnp.take(block_docids, sel_blocks, axis=0)
     tf = jnp.take(block_tfs, sel_blocks, axis=0).astype(dt)
-    dl = jnp.take(doc_lens, d).astype(dt)
+    dl = jnp.take(block_lens, sel_blocks, axis=0).astype(dt)
+    return d, tf, dl
+
+
+def _pack(vals, ids, last):
+    """A cohort's packed readback, float32 [Q, 2k+1]: ``[values (k) |
+    docids (k) | last (1)]``, ints as float CASTS (exact < 2^24 — see
+    ops/plan.pack_result)."""
+    return jnp.concatenate([vals, ids.astype(jnp.float32),
+                            last.astype(jnp.float32)[:, None]], axis=1)
+
+
+def _topk_total(postings, sel_weights, live_col, avg_len, k1: float,
+                b: float, k: int):
+    """Single query over its selected ``postings`` (``_postings``):
+    (values [k], docids [k], total []) — sort by docid, doubling
+    segmented sum, top-k at run-last positions. The docids' float-pack
+    limit (< 2^24) is enforced where the corpus is built and registered
+    (ops/device.py DeviceSegment, search/fastpath.py)."""
+    dt = _score_dtype()
+    d, tf, dl = postings
     contrib = bm25_contrib(sel_weights.astype(dt), tf, dl,
                            jnp.asarray(avg_len, dt), k1, b)
 
@@ -166,17 +190,14 @@ NE_SLOTS = 8          # non-essential term slots (pad with len 0)
 CAND = 16384
 
 
-def _essential_phase1(block_docids, block_tfs, sel_blocks, sel_weights,
-                      doc_lens, live_col, ne_bound, avg_len,
-                      k1: float, b: float):
-    """Exact scores over the ESSENTIAL union (the full kernel's sorted
-    segmented-reduction at a smaller NB) → top-C candidates plus the
-    overflow bound. Returns (cand_ids [C], ess [C], overflow_bound [])."""
-    check_packed_id_limit(doc_lens.shape[0], "fastpath essential lane")
+def _essential_phase1(postings, sel_weights, live_col, ne_bound,
+                      avg_len, k1: float, b: float):
+    """Exact scores over the ESSENTIAL union's ``postings`` (the full
+    kernel's sorted segmented-reduction at a smaller NB) → top-C
+    candidates plus the overflow bound. Returns (cand_ids [C], ess [C],
+    overflow_bound [])."""
     dt = _score_dtype()
-    d = jnp.take(block_docids, sel_blocks, axis=0)
-    tf = jnp.take(block_tfs, sel_blocks, axis=0).astype(dt)
-    dl = jnp.take(doc_lens, d).astype(dt)
+    d, tf, dl = postings
     contrib = bm25_contrib(sel_weights.astype(dt), tf, dl,
                            jnp.asarray(avg_len, dt), k1, b)
     dflat = d.reshape(-1)
@@ -228,13 +249,16 @@ def _essential_epilogue(patched, cand_ids, overflow_bound, k: int):
 
 
 def _essential_one(block_docids, block_tfs, flat_docids, flat_tfs,
-                   sel_blocks, sel_weights, doc_lens, live_col,
+                   sel_blocks, sel_weights, block_lens, doc_lens, live_col,
                    ne_start, ne_len, ne_idf, ne_bound,
                    avg_len, k1: float, b: float, k: int):
+    # trace-time guard (shapes are static under jit): the lane reads
+    # ids back float-packed, which is exact only < 2^24
+    check_packed_id_limit(doc_lens.shape[0], "fastpath essential lane")
     dt = _score_dtype()
     cand_ids, ess, overflow_bound = _essential_phase1(
-        block_docids, block_tfs, sel_blocks, sel_weights, doc_lens,
-        live_col, ne_bound, avg_len, k1, b)
+        _postings(block_docids, block_tfs, block_lens, sel_blocks),
+        sel_weights, live_col, ne_bound, avg_len, k1, b)
 
     # ---- phase 2: patch non-essential contributions per candidate
     safe_ids = jnp.clip(cand_ids, 0, doc_lens.shape[0] - 1)
@@ -277,7 +301,9 @@ def bm25_essential_topk_batch(block_docids, block_tfs,
                               flat_tfs,      # float32 [TB*B]
                               sel_blocks,    # int32 [Q, NBe] essential
                               sel_weights,   # float32 [Q, NBe]
-                              doc_lens, masks, mask_ids,
+                              block_lens,    # float32 [TB, B]
+                              doc_lens,      # float32 [ND]
+                              masks, mask_ids,
                               ne_start,      # int32 [Q, NE_SLOTS]
                               ne_len,        # int32 [Q, NE_SLOTS]
                               ne_idf,        # float32 [Q, NE_SLOTS]
@@ -290,56 +316,34 @@ def bm25_essential_topk_batch(block_docids, block_tfs,
     def one(s, w, mid, ns, nl, ni, nb):
         live_col = jnp.take(masks, mid, axis=0)
         return _essential_one(block_docids, block_tfs, flat_docids,
-                              flat_tfs, s, w, doc_lens, live_col,
-                              ns, nl, ni, nb, avg_len, k1, b, k)
+                              flat_tfs, s, w, block_lens, doc_lens,
+                              live_col, ns, nl, ni, nb, avg_len, k1, b, k)
 
-    vals, ids, ok = jax.vmap(one)(sel_blocks, sel_weights, mask_ids,
-                                  ne_start, ne_len, ne_idf, ne_bound)
-    ids_f = ids.astype(jnp.float32)
-    ok_f = ok.astype(jnp.float32)
-    return jnp.concatenate([vals, ids_f, ok_f[:, None]], axis=1)
+    return _pack(*jax.vmap(one)(sel_blocks, sel_weights, mask_ids,
+                                ne_start, ne_len, ne_idf, ne_bound))
 
 
-@tracked_jit(static_argnames=("n_slots", "k1", "b", "k"))
-def bm25_topk_total_merge_batch(
-        block_docids,   # int32 [TB, B]
-        block_tfs,      # float32 [TB, B]
-        sel_blocks,     # int32 [Q, NB] SLOTTED (term runs on slot
-                        #   boundaries; slot = NB // n_slots blocks)
-        sel_weights,    # rail-dtype [Q, NB]
-        doc_lens,       # float32 [ND]
-        masks,          # bool [F_SLOTS, ND], or None (see F_SLOTS)
-        mask_ids,       # int32 [Q], or None with masks
-        avg_len, n_slots: int, k1: float, b: float, k: int):
-    """The v1 exact kernel with ONE substitution: the monolithic
-    O(P·logP) ``lax.sort`` becomes the linear-work bitonic merge of the
-    per-term sorted runs (ops/merge.py). The merge's payload is the
-    float32 contributions themselves, or on the f64 rail the lane index
-    they are gathered back through (``merge_payload``). Everything
-    downstream — doubling segmented scan, exact totals, stable
-    lowest-docid top-k — is the v1 code verbatim, so output equivalence
-    is by construction (same packing: [values (k) | docids (k) |
-    total], float32 [Q, 2k+1])."""
-    from elasticsearch_tpu.ops.merge import merge_sorted_slots
-    Q, NB = sel_blocks.shape
-    B = block_docids.shape[1]
-    P = NB * B
-    L = P // n_slots
+def _keyed_contribs(postings, sel_weights, live_col, avg_len, k1: float,
+                    b: float):
+    """One query's merge inputs from its selected ``postings``, flat
+    [NB·B]: docid keys (SENTINEL on tf-0 lanes, so each slot's run stays
+    ascending) and contributions (0 where a lane does not score)."""
     dt = _score_dtype()
+    d, tf, dl = postings
+    contrib = bm25_contrib(sel_weights.astype(dt), tf, dl,
+                           jnp.asarray(avg_len, dt), k1, b)
+    contrib = jnp.where(_accept(tf, live_col, d),
+                        contrib, jnp.asarray(0.0, dt))
+    key = jnp.where(tf > 0.0, d, _SENTINEL)
+    return key.reshape(-1), contrib.reshape(-1)
 
-    def gather_one(s, w, mid):
-        live_col = _live_col(masks, mid)
-        d = jnp.take(block_docids, s, axis=0)
-        tf = jnp.take(block_tfs, s, axis=0).astype(dt)
-        dl = jnp.take(doc_lens, d).astype(dt)
-        contrib = bm25_contrib(w.astype(dt), tf, dl,
-                               jnp.asarray(avg_len, dt), k1, b)
-        contrib = jnp.where(_accept(tf, live_col, d),
-                            contrib, jnp.asarray(0.0, dt))
-        key = jnp.where(tf > 0.0, d, _SENTINEL)
-        return key.reshape(-1), contrib.reshape(-1)
 
-    keys, cons = jax.vmap(gather_one)(sel_blocks, sel_weights, mask_ids)
+def _merge_topk_total(keys, cons, n_slots: int, k: int):
+    """v2m's cohort tail: [Q, P] keys and contributions whose per-term
+    runs sit sorted in ``n_slots`` slots → the packed [Q, 2k+1]."""
+    from elasticsearch_tpu.ops.merge import merge_sorted_slots
+    Q, P = keys.shape
+    L = P // n_slots
     keys = keys.reshape(Q, n_slots, L)
     if merge_payload() == "contrib":
         # float32 rail: the contributions ride the merge themselves (the
@@ -364,9 +368,36 @@ def bm25_topk_total_merge_batch(
         return vals.astype(jnp.float32), ids
 
     vals, ids = jax.vmap(topk_one)(cand, mk)
-    ids_f = ids.astype(jnp.float32)
-    tot_f = totals.astype(jnp.float32)
-    return jnp.concatenate([vals, ids_f, tot_f[:, None]], axis=1)
+    return _pack(vals, ids, totals)
+
+
+@tracked_jit(static_argnames=("n_slots", "k1", "b", "k"))
+def bm25_topk_total_merge_batch(
+        block_docids,   # int32 [TB, B]
+        block_tfs,      # float32 [TB, B]
+        sel_blocks,     # int32 [Q, NB] SLOTTED (term runs on slot
+                        #   boundaries; slot = NB // n_slots blocks)
+        sel_weights,    # rail-dtype [Q, NB]
+        block_lens,     # float32 [TB, B]: each posting's doc length
+        masks,          # bool [F_SLOTS, ND], or None (see F_SLOTS)
+        mask_ids,       # int32 [Q], or None with masks
+        avg_len, n_slots: int, k1: float, b: float, k: int):
+    """The v1 exact kernel with ONE substitution: the monolithic
+    O(P·logP) ``lax.sort`` becomes the linear-work bitonic merge of the
+    per-term sorted runs (ops/merge.py). The merge's payload is the
+    float32 contributions themselves, or on the f64 rail the lane index
+    they are gathered back through (``merge_payload``). Everything
+    downstream — doubling segmented scan, exact totals, stable
+    lowest-docid top-k — is the v1 code verbatim, so output equivalence
+    is by construction (same packing: [values (k) | docids (k) |
+    total], float32 [Q, 2k+1])."""
+    def gather_one(s, w, mid):
+        return _keyed_contribs(
+            _postings(block_docids, block_tfs, block_lens, s), w,
+            _live_col(masks, mid), avg_len, k1, b)
+
+    keys, cons = jax.vmap(gather_one)(sel_blocks, sel_weights, mask_ids)
+    return _merge_topk_total(keys, cons, n_slots, k)
 
 
 @tracked_jit(static_argnames=("k1", "b", "k"))
@@ -374,7 +405,7 @@ def bm25_topk_total_batch(block_docids,   # int32 [TB, B]
                           block_tfs,      # float32 [TB, B]
                           sel_blocks,     # int32 [Q, NB]
                           sel_weights,    # float32 [Q, NB]
-                          doc_lens,       # float32 [ND]
+                          block_lens,     # float32 [TB, B]
                           masks,          # bool [F_SLOTS, ND], or None
                           mask_ids,       # int32 [Q] row into masks
                           avg_len, k1: float, b: float, k: int):
@@ -383,10 +414,8 @@ def bm25_topk_total_batch(block_docids,   # int32 [TB, B]
     Ints ride as float CASTS (exact < 2^24 — see ops/plan.pack_result).
     ``masks=None, mask_ids=None`` reads no mask row (see F_SLOTS)."""
     def one(s, w, mid):
-        return _topk_total(block_docids, block_tfs, s, w, doc_lens,
-                           _live_col(masks, mid), avg_len, k1, b, k)
+        return _topk_total(
+            _postings(block_docids, block_tfs, block_lens, s), w,
+            _live_col(masks, mid), avg_len, k1, b, k)
 
-    vals, ids, totals = jax.vmap(one)(sel_blocks, sel_weights, mask_ids)
-    ids_f = ids.astype(jnp.float32)
-    tot_f = totals.astype(jnp.float32)
-    return jnp.concatenate([vals, ids_f, tot_f[:, None]], axis=1)
+    return _pack(*jax.vmap(one)(sel_blocks, sel_weights, mask_ids))

@@ -198,9 +198,10 @@ class DeviceSegmentCache:
         else:
             self._charged.pop(name, None)
 
-    def account_filter_mask(self, name: str, delta: int,
-                            label: str = "filter_mask") -> None:
-        """Filter-mask admission/release for a resident DeviceSegment
+    def account_hbm(self, name: str, delta: int,
+                    label: str = "filter_mask") -> None:
+        """Admission/release of device bytes a resident DeviceSegment
+        builds after its own admission — filter masks, posting lengths
         (called by ops/device.py). Positive deltas go through the same
         eviction-pressure admission as segment builds; negative deltas
         (mask LRU eviction) release. Orphan segments (already evicted

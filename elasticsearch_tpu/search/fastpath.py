@@ -145,8 +145,11 @@ class FastPathServer:
         # passes that raised (bounces for an error land here; plain
         # `bounced` also counts by-design handoffs to the Python path)
         # unmasked_cohorts: launches whose cohort carried no filter row,
-        # so the kernel read no mask (ops/fastpath.py F_SLOTS)
+        # so the kernel read no mask (ops/fastpath.py F_SLOTS);
+        # posting_len_cohorts: launches that read each posting's doc
+        # length as a block row (the registration's ``block_lens``)
         self.stats = {"cohorts": 0, "unmasked_cohorts": 0,
+                      "posting_len_cohorts": 0,
                       "fast_queries": 0, "bounced": 0, "errors": 0,
                       # θ-cache (essential-lane admission) counters —
                       # the engine-stats `caches.theta` surface
@@ -192,6 +195,8 @@ class FastPathServer:
         self.stats["cohorts"] += 1
         if masks is None:
             self.stats["unmasked_cohorts"] += 1
+        # every fast-path kernel reads the lengths as block rows
+        self.stats["posting_len_cohorts"] += 1
 
     def serving_stats(self) -> dict:
         """Routing/dispatch telemetry of the serving front: per-lane ×
@@ -199,6 +204,7 @@ class FastPathServer:
         waste, warm-up seconds, and the truncated-lane counters."""
         from elasticsearch_tpu.ops.fastpath import merge_payload
         padded = self.pad_rows + self.used_rows
+        reg = self._reg
         return {
             "dispatch": dict(self.dispatch),
             "cohort_hist": {str(k): v
@@ -211,6 +217,9 @@ class FastPathServer:
             "impact_mode": self.impact_mode,
             # what the v2m merge carries on this rail: "contrib" or "lane"
             "merge_payload": merge_payload(),
+            # device bytes of the registration's per-posting lengths
+            "posting_len_bytes": (int(reg["block_lens"].nbytes)
+                                  if reg is not None else 0),
             "counters": {k: v for k, v in self.stats.items()
                          if isinstance(v, (int, float))},
         }
@@ -372,6 +381,10 @@ class FastPathServer:
         reg["post_len"] = dp.doc_freq.astype(np.int32)
         reg["flat_docids"] = dp.block_docids.reshape(-1)
         reg["flat_tfs"] = dp.block_tfs.reshape(-1)
+        # each posting's document length as a block row beside its tf,
+        # the kernels' one read of lengths (HBM-accounted by the
+        # device segment: its `posting_lens` slab class)
+        reg["block_lens"] = dev.posting_lens(field)
         reg["theta"] = {}    # (tids, filt, k) -> (θ, exact_total)
         # replica mesh for this registration's v1 cohorts: bound once so
         # warm + serve share ONE (sharded) compile signature per bucket
@@ -441,7 +454,7 @@ class FastPathServer:
             mk, mi = (None, None) if plain else (masks, mask_ids)
             bm25_topk_total_merge_batch(
                 dp.block_docids, dp.block_tfs, sel, ws,
-                dp.doc_lens, mk, mi, wd(dp.avg_len),
+                reg["block_lens"], mk, mi, wd(dp.avg_len),
                 self.N_SLOTS, reg["k1"], reg["b"],
                 self.max_k).block_until_ready()
             return f"v2m NB={nb}" + (" unmasked" if plain else "")
@@ -451,11 +464,11 @@ class FastPathServer:
                 return "skipped (stopping)"
             sel = np.full((self.q_batch, nb), dp.zero_block, np.int32)
             ws = np.zeros((self.q_batch, nb), wd)
-            bd, bt, s_, w_, dl, mk, mi = self._v1_inputs(
+            bd, bt, s_, w_, bl, mk, mi = self._v1_inputs(
                 reg, sel, ws, *((None, None) if plain
                                 else (masks, mask_ids)))
             bm25_topk_total_batch(
-                bd, bt, s_, w_, dl, mk, mi, wd(dp.avg_len), reg["k1"],
+                bd, bt, s_, w_, bl, mk, mi, wd(dp.avg_len), reg["k1"],
                 reg["b"], self.max_k).block_until_ready()
             return f"v1 NB={nb}" + (" unmasked" if plain else "") + (
                 " (mesh)" if reg.get("rmesh") is not None else "")
@@ -467,7 +480,8 @@ class FastPathServer:
             ws = np.zeros((self.q_batch, nb), wd)
             bm25_essential_topk_batch(
                 dp.block_docids, dp.block_tfs, reg["flat_docids"],
-                reg["flat_tfs"], sel, ws, dp.doc_lens, masks, mask_ids,
+                reg["flat_tfs"], sel, ws, reg["block_lens"], dp.doc_lens,
+                masks, mask_ids,
                 np.zeros((self.q_batch, NE_SLOTS), np.int32),
                 np.zeros((self.q_batch, NE_SLOTS), np.int32),
                 np.zeros((self.q_batch, NE_SLOTS), wd),
@@ -786,7 +800,7 @@ class FastPathServer:
         out, t_done = self._launch_cohort(
             "search.fastpath.v2_cohort", items, arrived, True,
             bm25_topk_total_merge_batch, dp.block_docids, dp.block_tfs,
-            sel, ws, dp.doc_lens, masks, mids,
+            sel, ws, reg["block_lens"], masks, mids,
             self._weight_dtype()(dp.avg_len), self.N_SLOTS, reg["k1"],
             reg["b"], k_static)
         self._count_launch(masks)
@@ -916,11 +930,11 @@ class FastPathServer:
                         continue
                     mask_ids[qi] = row
         k_static = self.max_k
-        bd, bt, sel_m, ws_m, dl, mk, mi = self._v1_inputs(
+        bd, bt, sel_m, ws_m, bl, mk, mi = self._v1_inputs(
             reg, sel, ws, *_mask_args(stack, mask_ids))
         out, t_done = self._launch_cohort(
             "search.fastpath.truncated_cohort", items, arrived, True,
-            bm25_topk_total_batch, bd, bt, sel_m, ws_m, dl, mk, mi,
+            bm25_topk_total_batch, bd, bt, sel_m, ws_m, bl, mk, mi,
             self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
             k_static)
         self._count_launch(mk)
@@ -1183,8 +1197,9 @@ class FastPathServer:
         out, t_done = self._launch_cohort(
             "search.fastpath.essential_cohort", items, arrived, True,
             bm25_essential_topk_batch, dp.block_docids, dp.block_tfs,
-            reg["flat_docids"], reg["flat_tfs"], sel, ws, dp.doc_lens,
-            masks, mask_ids, ne_start, ne_len, ne_idf, ne_bound,
+            reg["flat_docids"], reg["flat_tfs"], sel, ws, reg["block_lens"],
+            dp.doc_lens, masks, mask_ids, ne_start, ne_len, ne_idf,
+            ne_bound,
             self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
             k_static)
         idx_b = reg["index"].encode()
@@ -1350,12 +1365,12 @@ class FastPathServer:
         mb = self.mesh_backend
         if rmesh is None or mb is None or not self._mesh_active(reg):
             return (dp.block_docids, dp.block_tfs, sel, ws,
-                    dp.doc_lens, stack, mask_ids)
+                    reg["block_lens"], stack, mask_ids)
         return (mb.replicated(rmesh, dp.block_docids),
                 mb.replicated(rmesh, dp.block_tfs),
                 mb.shard_rows(rmesh, sel),
                 mb.shard_rows(rmesh, ws),
-                mb.replicated(rmesh, dp.doc_lens),
+                mb.replicated(rmesh, reg["block_lens"]),
                 None if stack is None else mb.replicated(rmesh, stack),
                 None if mask_ids is None else mb.shard_rows(rmesh,
                                                             mask_ids))
@@ -1392,11 +1407,11 @@ class FastPathServer:
                         continue
                     mask_ids[qi] = row
         k_static = self.max_k
-        bd, bt, sel_m, ws_m, dl, mk, mi = self._v1_inputs(
+        bd, bt, sel_m, ws_m, bl, mk, mi = self._v1_inputs(
             reg, sel, ws, *_mask_args(stack, mask_ids))
         out, t_done = self._launch_cohort(
             "search.fastpath.v1_cohort", items, arrived, first,
-            bm25_topk_total_batch, bd, bt, sel_m, ws_m, dl, mk, mi,
+            bm25_topk_total_batch, bd, bt, sel_m, ws_m, bl, mk, mi,
             self._weight_dtype()(dp.avg_len), reg["k1"], reg["b"],
             k_static)
         self._count_launch(mk)

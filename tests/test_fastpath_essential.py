@@ -79,7 +79,7 @@ def full_v1(seg, ess_and_ne, k, masks=None, mask_id=0):
     if masks is None:
         masks = np.ones((fp.F_SLOTS, seg["n_docs"]), bool)
     out = np.asarray(fp.bm25_topk_total_batch(
-        seg["bd"], seg["bt"], sel, ws, seg["lens"], masks,
+        seg["bd"], seg["bt"], sel, ws, seg["lens"][seg["bd"]], masks,
         np.full(q, mask_id, np.int32), np.float64(seg["avg"]),
         K1, B, k))
     vals = out[0, :k]
@@ -114,7 +114,8 @@ def run_essential(seg, ess, ne, ne_bound, k, masks=None, mask_id=0):
     mids = np.full(q, mask_id, np.int32)
     return np.asarray(fp.bm25_essential_topk_batch(
         seg["bd"], seg["bt"], seg["flat_d"], seg["flat_t"], sel, ws,
-        seg["lens"], masks, mids, ne_start, ne_len, ne_idf, nbound,
+        seg["lens"][seg["bd"]], seg["lens"], masks, mids, ne_start,
+        ne_len, ne_idf, nbound,
         np.float64(seg["avg"]), K1, B, k))
 
 

@@ -7,13 +7,19 @@ between the fast and dense paths; these tests allow none):
 2. stable tie-break — exact-score ties at the k boundary select the
    LOWEST docids (the Lucene / exact-truth contract; TPU top_k alone
    does not guarantee this),
-3. exact totals and mask-row isolation.
+3. exact totals and mask-row isolation,
+4. each posting's document length read as a block row (``block_lens``)
+   gives the packed result of gathering it by docid, bit for bit.
 """
 
+from functools import partial
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from elasticsearch_tpu.ops import fastpath as fp
 from elasticsearch_tpu.ops.bm25 import _SENTINEL, bm25_sorted_topk
 from elasticsearch_tpu.ops.fastpath import F_SLOTS, bm25_topk_total_batch
 from elasticsearch_tpu.ops.plan import unpack_ids
@@ -36,7 +42,7 @@ def data():
 
 def run_batch(bd, bt, sels, wss, lens, masks, mask_ids, k=K):
     packed = np.asarray(bm25_topk_total_batch(
-        bd, bt, np.stack(sels), np.stack(wss), lens, masks,
+        bd, bt, np.stack(sels), np.stack(wss), lens[bd], masks,
         np.asarray(mask_ids, np.int32), np.float32(30.0), 1.2, 0.75, k))
     out = []
     for q in range(len(sels)):
@@ -251,7 +257,7 @@ def test_unmasked_launch_matches_the_plain_stack_bit_for_bit(seed):
     bd = np.concatenate([bd, np.zeros((1, B), np.int32)])   # zero block
     bt = np.concatenate([bt, np.zeros((1, B), np.float32)])
     masks = jnp.stack([jnp.asarray(live)] * F_SLOTS)
-    args = (bd, bt, sels, wss, lens)
+    args = (bd, bt, sels, wss, lens[bd])
     masked = np.asarray(bm25_topk_total_batch(
         *args, masks, np.zeros(len(sels), np.int32), np.float32(30.0),
         1.2, 0.75, K))
@@ -261,3 +267,135 @@ def test_unmasked_launch_matches_the_plain_stack_bit_for_bit(seed):
     # the dropped rows answer nothing, the others something
     totals = unpack_ids(plain[:, 2 * K])
     assert (totals[:4] > 0).all() and (totals[4:] == 0).all()
+
+
+def _doc_len_gather(bd, bt, doc_lens, sel_blocks):
+    """A query's postings as the kernels read them before ``block_lens``:
+    docids and tfs as block rows, each posting's length gathered from
+    the per-doc ``doc_lens`` by its docid."""
+    dt = fp._score_dtype()
+    d = jnp.take(bd, sel_blocks, axis=0)
+    return (d, jnp.take(bt, sel_blocks, axis=0).astype(dt),
+            jnp.take(doc_lens, d).astype(dt))
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _v1_reference(bd, bt, sels, wss, doc_lens, masks, mask_ids, avg, k):
+    def one(s, w, mid):
+        return fp._topk_total(_doc_len_gather(bd, bt, doc_lens, s), w,
+                              fp._live_col(masks, mid), avg, 1.2, 0.75, k)
+    return fp._pack(*jax.vmap(one)(sels, wss, mask_ids))
+
+
+@partial(jax.jit, static_argnames=("n_slots", "k"))
+def _v2m_reference(bd, bt, sels, wss, doc_lens, masks, mask_ids, avg,
+                   n_slots, k):
+    def one(s, w, mid):
+        return fp._keyed_contribs(_doc_len_gather(bd, bt, doc_lens, s), w,
+                                  fp._live_col(masks, mid), avg, 1.2, 0.75)
+    keys, cons = jax.vmap(one)(sels, wss, mask_ids)
+    return fp._merge_topk_total(keys, cons, n_slots, k)
+
+
+def _slotted_cohort(seed, nb, n_slots=16):
+    """Six queries at bucket ``nb``: each used slot holds one random
+    block (ascending, its tf-0 tail keyed last) and then zero blocks,
+    so the slot layout holds for v2m as for v1; one query repeats a
+    block (a duplicated term), the last selects nothing."""
+    rng = np.random.default_rng(200 + seed)
+    slot = nb // n_slots
+    sels = np.full((6, nb), TB, np.int32)
+    wss = np.zeros((6, nb), np.float32)
+    for qi in range(5):
+        used = int(rng.integers(2, n_slots + 1))
+        blocks = rng.choice(TB, used, replace=False)
+        if qi == 1:
+            blocks[-1] = blocks[0]
+        sels[qi, ::slot][:used] = blocks
+        wss[qi, ::slot][:used] = rng.uniform(0.3, 2.5, used)
+    return sels, wss
+
+
+@pytest.mark.parametrize("rail", ["f32", "f64"])
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("nb", [16, 32])
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("kernel", ["v2m", "v1"])
+def test_block_lens_match_the_doc_length_gather_bit_for_bit(
+        kernel, masked, nb, seed, rail):
+    """v2m and v1 reading each posting's length as a block row of
+    ``block_lens = doc_lens[block_docids]`` pack the same bits as the
+    same kernel gathering ``doc_lens`` by docid: every lane sees the
+    same length and runs the same arithmetic. A masked cohort carries a
+    filter row for some queries."""
+    bd, bt, lens, live = _padded_corpus(seed)
+    bd = np.concatenate([bd, np.zeros((1, B), np.int32)])   # zero block
+    bt = np.concatenate([bt, np.zeros((1, B), np.float32)])
+    sels, wss = _slotted_cohort(seed, nb)
+    if masked:
+        rng = np.random.default_rng(300 + seed)
+        masks = np.stack([live] * F_SLOTS)
+        masks[1] &= rng.random(ND) < 0.5
+        masks, mids = jnp.asarray(masks), jnp.asarray([0, 1, 1, 0, 1, 0],
+                                                      jnp.int32)
+    else:
+        masks = mids = None
+    with jax.enable_x64(rail == "f64"):
+        wd = np.float64 if rail == "f64" else np.float32
+        args = (bd, bt, sels, wss.astype(wd))
+        tail = (masks, mids, wd(30.0))
+        if kernel == "v2m":
+            got = fp.bm25_topk_total_merge_batch(
+                *args, jnp.asarray(lens[bd]), *tail, 16, 1.2, 0.75, K)
+            want = _v2m_reference(*args, lens, *tail, n_slots=16, k=K)
+        else:
+            got = fp.bm25_topk_total_batch(
+                *args, jnp.asarray(lens[bd]), *tail, 1.2, 0.75, K)
+            want = _v1_reference(*args, lens, *tail, k=K)
+        got, want = np.asarray(got), np.asarray(want)
+    assert np.array_equal(got.view(np.uint32), want.view(np.uint32))
+    totals = unpack_ids(got[:, 2 * K])
+    assert (totals[:5] > 0).all() and totals[5] == 0
+
+
+@pytest.mark.parametrize("rail", ["f32", "f64"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_essential_phase1_block_lens_match_the_doc_length_gather(seed,
+                                                                 rail):
+    """The essential lane's phase 1 gives the same candidates, essential
+    scores and overflow bound, bit for bit, from block-row lengths as
+    from lengths gathered by docid."""
+    bd, bt, lens, live = _padded_corpus(seed)
+    bd = np.concatenate([bd, np.zeros((1, B), np.int32)])
+    bt = np.concatenate([bt, np.zeros((1, B), np.float32)])
+    sels, wss = _slotted_cohort(seed, 32)
+
+    def phase1(postings_of, sels, wss, bounds):
+        def one(s, w, nb):
+            return fp._essential_phase1(postings_of(s), w, jnp.asarray(live),
+                                        nb, 30.0, 1.2, 0.75)
+        return jax.jit(jax.vmap(one))(sels, wss, bounds)
+
+    with jax.enable_x64(rail == "f64"):
+        wd = np.float64 if rail == "f64" else np.float32
+        bounds = np.linspace(0.0, 2.0, len(sels)).astype(wd)
+        got = phase1(partial(fp._postings, bd, bt, jnp.asarray(lens[bd])),
+                     sels, wss.astype(wd), bounds)
+        want = phase1(partial(_doc_len_gather, bd, bt, lens),
+                      sels, wss.astype(wd), bounds)
+        got = [np.asarray(x) for x in got]
+        want = [np.asarray(x) for x in want]
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        assert np.array_equal(g.view(np.uint8), w.view(np.uint8))
+    assert np.isfinite(got[1]).any()
+
+
+def test_block_lens_must_be_laid_out_as_the_tfs():
+    """A per-doc length vector where the kernels take the block table
+    is refused at trace time, not broadcast into wrong scores."""
+    bd, bt, lens, _live = _padded_corpus(0)
+    sels, wss = _slotted_cohort(0, 16)
+    with pytest.raises(ValueError, match="block_lens"):
+        fp.bm25_topk_total_batch(bd, bt, sels, wss, lens, None, None,
+                                 np.float32(30.0), 1.2, 0.75, K)

@@ -84,7 +84,8 @@ def run_v1(seg, queries, n_docs, k=50, nb_bucket=64):
         sel[qi], ws[qi] = flat_sel(seg, terms, idf, nb_bucket)
     return np.asarray(fp.bm25_topk_total_batch(
         seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws.astype(wd)),
-        seg["lens"], jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
+        seg["lens"][seg["bd"]],
+        jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
         jnp.asarray(np.zeros(len(queries), np.int32)), wd(seg["avg"]),
         1.2, 0.75, k))
 
@@ -101,7 +102,8 @@ def run_v2m(seg, queries, n_docs, k=50, n_slots=8, nb_bucket=64):
                                       nb_bucket)
     return np.asarray(fp.bm25_topk_total_merge_batch(
         seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws),
-        seg["lens"], jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
+        seg["lens"][seg["bd"]],
+        jnp.asarray(np.ones((fp.F_SLOTS, n_docs), bool)),
         jnp.asarray(np.zeros(len(queries), np.int32)), wd(seg["avg"]),
         n_slots, 1.2, 0.75, k))
 
@@ -247,7 +249,8 @@ def test_v2m_unmasked_launch_matches_the_plain_stack_bit_for_bit(seed):
         sel[qi], ws[qi] = slotted_sel(seg, terms, idf, n_slots,
                                       nb_bucket)
     lens, live = _padded(seg, n_docs)
-    args = (seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws), lens)
+    args = (seg["bd"], seg["bt"], jnp.asarray(sel), jnp.asarray(ws),
+            lens[seg["bd"]])
     tail = (wd(seg["avg"]), n_slots, 1.2, 0.75, 50)
     masked = np.asarray(fp.bm25_topk_total_merge_batch(
         *args, jnp.stack([jnp.asarray(live)] * fp.F_SLOTS),
@@ -294,6 +297,7 @@ def _registration(seg, n_docs):
                          avg_len=np.float32(seg["avg"]),
                          zero_block=seg["zero_block"])
     reg = {"dp": dp, "k1": 1.2, "b": 0.75, "idf": idf,
+           "block_lens": jnp.asarray(lens[seg["bd"]]),
            "nb": seg["nb"].astype(np.int64),
            "starts": seg["tbs"].astype(np.int64),
            "post_start": (seg["tbs"] * BLOCK).astype(np.int32),

@@ -386,6 +386,51 @@ def test_unmasked_cohorts_rest_on_a_segment_without_deletions(served):
                           np.asarray(mask) & np.asarray(dev.live))
 
 
+def test_registration_lays_each_postings_length_beside_its_tf(served):
+    """Registration builds ``block_lens``, each posting's document
+    length laid out as the block tfs, once on the device: it holds
+    ``doc_lens[block_docids]`` on every lane with tf > 0, its bytes are
+    charged to the segment's HBM (slab class ``posting_lens``, the hbm
+    breaker) and reported as ``serving.posting_len_bytes``, and every
+    launch, plain or filtered, counts in ``posting_len_cohorts``."""
+    from elasticsearch_tpu.utils.breaker import CircuitBreaker
+    node, port = served
+    fp = node._http.fastpath
+    reg = fp._reg
+    dp, dev = reg["dp"], reg["dev"]
+    bl = np.asarray(reg["block_lens"])
+    bd, bt = np.asarray(dp.block_docids), np.asarray(dp.block_tfs)
+    assert bl.shape == bt.shape and bl.dtype == np.float32
+    hit = bt > 0
+    assert hit.any()
+    assert np.array_equal(bl[hit], np.asarray(dp.doc_lens)[bd[hit]])
+    assert dev.posting_lens(reg["field"]) is reg["block_lens"]
+    assert dev.hbm_bytes_by_class()["posting_lens"] == bl.nbytes
+    cache = node.indices_service.device_cache
+    assert node.breaker_service.get_breaker(CircuitBreaker.HBM).used == \
+        sum(d.hbm_bytes() for _v, d in cache._cache.values())
+
+    plain = {"query": {"match": {"title": "fox gamma"}}, "size": 20,
+             "_source": False}
+    filtered = {"query": {"bool": {
+        "must": [{"match": {"title": "fox gamma"}}],
+        "filter": [{"match": {"title": "dog"}}]}},
+        "size": 20, "_source": False}
+    for body in (plain, filtered):
+        cohorts = fp.stats["cohorts"]
+        reads = fp.stats["posting_len_cohorts"]
+        before = fast_count(node)
+        req(port, "POST", "/books/_search", body)
+        assert fast_count(node) == before + 1
+        assert fp.stats["cohorts"] == cohorts + 1
+        assert fp.stats["posting_len_cohorts"] == reads + 1
+    serving = node.rest_controller.dispatch("GET", "/_kernels", {},
+                                            None)[1]["serving"]
+    assert serving["counters"]["posting_len_cohorts"] == \
+        fp.stats["posting_len_cohorts"] == fp.stats["cohorts"]
+    assert serving["posting_len_bytes"] == bl.nbytes
+
+
 def test_warm_ladder_compiles_exactly_the_routable_programs(
         tmp_path, monkeypatch):
     """start() compiles nothing; registering an index warms exactly the
@@ -404,7 +449,7 @@ def test_warm_ladder_compiles_exactly_the_routable_programs(
     for name, lane, i_sel, i_mask in (
             ("bm25_topk_total_merge_batch", "v2m", 2, 5),
             ("bm25_topk_total_batch", "v1", 2, 5),
-            ("bm25_essential_topk_batch", "ess", 4, 7)):
+            ("bm25_essential_topk_batch", "ess", 4, 8)):
         def spy(*args, _lane=lane, _s=i_sel, _m=i_mask):
             warmed.append((_lane, args[_s].shape[1],
                            "unmasked" if args[_m] is None else "masked"))
